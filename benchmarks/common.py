@@ -8,10 +8,12 @@ from typing import Any, Callable, List, Tuple
 import jax
 
 from repro.ioutil import atomic_write_json
+from repro.launch.roofline import V5E_KIND, chip_peaks
 
-# TPU v5e constants (same as launch.dryrun)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+# TPU v5e roofline denominators, read from the one peak table
+_V5E = chip_peaks(V5E_KIND)
+PEAK_FLOPS = _V5E.bf16_flops
+HBM_BW = _V5E.hbm_bw
 
 Row = Tuple[str, float, str]      # (name, us_per_call, derived-info)
 

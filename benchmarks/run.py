@@ -36,6 +36,7 @@ TUNE_SHAPES_QUICK = {
 def run_tune(quick: bool, out_path: str) -> None:
     """Measure-tune every registered kernel and write the cache artifact."""
     from repro import tune
+    from repro.engine.platform import kernel_backend
 
     from .common import write_json
 
@@ -43,7 +44,7 @@ def run_tune(quick: bool, out_path: str) -> None:
     cache = tune.default_cache()
     print("name,us_per_call,derived")
     for kernel in tune.available_spaces():
-        fix = {"backend": "pallas_interpret"} \
+        fix = {"backend": kernel_backend()} \
             if kernel == "lanczos_reorth" else None
         for shape in shapes.get(kernel, ()):
             res = tune.tune(kernel, shape, "float32", fix=fix,

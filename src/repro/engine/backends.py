@@ -11,7 +11,8 @@ selected ONCE per engine (not per op, not per callsite):
 * ``pallas``           — same kernels compiled via Mosaic (TPU deployment).
 * ``pallas_vmap``      — vmap-of-scalar-kernel fallback: the pre-engine
                          batching scheme, kept for A/B benchmarking and as
-                         an escape hatch.
+                         an escape hatch (interpret mode derived from the
+                         platform, like ``kernels.ops``).
 
 Hook factories are lru-cached upstream, so ``make_hooks`` returns stable
 function identities — they are static jit arguments in ``core.lanczos``.
@@ -31,7 +32,7 @@ class Backend:
     """One way of executing the batched Lanczos inner steps."""
     name: str
     make_hooks: Callable[[int], BatchedLanczosHooks]   # expansion -> hooks
-    requires_padding: bool      # S and H must divide by the expansion factor
+    requires_padding: bool      # S, H padded by kernels.ops.padded_dims
     batched_launch: bool        # True: one kernel launch covers the batch
 
 
@@ -72,7 +73,7 @@ def _pallas_hooks(expansion: int) -> BatchedLanczosHooks:
 
 def _pallas_vmap_hooks(expansion: int) -> BatchedLanczosHooks:
     from ..kernels import ops
-    return ops.make_vmapped_pallas_hooks(expansion, interpret=True)
+    return ops.make_vmapped_pallas_hooks(expansion)
 
 
 register_backend(Backend("reference", _reference_hooks,

@@ -92,11 +92,10 @@ def _sharded_decompose(mesh, batch_spec: P, rank: int, iters: int, hooks,
                             z0=z0)
 
     if use_shard_map and dp is not None:
-        from jax.experimental.shard_map import shard_map
         in_specs = (P(dp, None, None), P())
         out_specs = LowRank(P(dp, None, None), P(dp, None), P(dp, None, None))
-        return jax.jit(shard_map(run, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
     x_sh = NamedSharding(mesh, P(dp, None, None))
     z_sh = NamedSharding(mesh, P())
     out_sh = LowRank(NamedSharding(mesh, P(dp, None, None)),

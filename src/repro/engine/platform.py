@@ -1,10 +1,8 @@
 """Single derivation point for the Pallas ``interpret`` flag.
 
-Every kernel used to default ``interpret=True`` (this container is
-CPU-only), which meant a real TPU deployment had to pass
-``interpret=False`` at every call site.  The flag is now derived ONCE from
-the platform: interpret mode everywhere except a real TPU, where the same
-BlockSpecs compile via Mosaic with no manual flags.
+The flag is derived ONCE from the platform: interpret mode everywhere
+except a real TPU, where the same BlockSpecs compile via Mosaic with no
+manual flags at call sites.
 
 Kernel modules resolve their ``interpret=None`` default through
 :func:`resolve_interpret`; ``kernels.ops`` seeds its module-level
@@ -28,3 +26,9 @@ def default_interpret() -> bool:
 def resolve_interpret(flag: Optional[bool]) -> bool:
     """None → the platform default; an explicit flag always wins."""
     return default_interpret() if flag is None else bool(flag)
+
+
+def kernel_backend() -> str:
+    """The engine backend that runs the Pallas kernels on this platform:
+    compiled ``pallas`` on a TPU, ``pallas_interpret`` elsewhere."""
+    return "pallas_interpret" if default_interpret() else "pallas"

@@ -1,4 +1,6 @@
-"""Pallas TPU kernels for the D-com decomposer (validated interpret=True).
+"""Pallas TPU kernels for the D-com decomposer (validated in interpret
+mode; ``lanczos_reorth`` also compiled for a TPU v5e by
+``tests/test_tpu_compile.py``).
 
 Kernels (one module each, ``ops`` wraps, ``ref`` is the jnp oracle):
 * ``lanczos_reorth``  — fused matvec+CGS2 re-orthogonalization (paper Fig. 9)

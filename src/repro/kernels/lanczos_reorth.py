@@ -11,26 +11,30 @@ the one long global reduction becomes ``f`` short local reductions plus a
 tiny global combine (Fig. 9c).
 
 TPU-native mapping (see DESIGN.md §2): the expansion factor ``f`` is the
-Pallas **grid size along the reduction dimension**.  Each grid step owns a
+Pallas **grid size along the output dimension**.  Each grid step owns a
 VMEM-resident tile (the paper's per-cluster buffer) and computes
 
   pass 0:  z_j   = (Aᵀu)_j            and accumulates p1 += Q_jᵀ z_j
   pass 1:  z'_j  = z_j − Q_j p1       and accumulates p2 += Q_jᵀ z'_j
   pass 2:  z''_j = z'_j − Q_j p2      and accumulates ‖z''‖² partials
 
-The p1/p2/nrm accumulators are tiny [1, k] / [1, 1] VMEM scratch — the
-paper's "small global memory for broadcast purposes".  The z intermediate
-lives in a full-length VMEM scratch so A is streamed from HBM exactly once
-per pass (3× total; the unfused chain reads A once but re-reads z/Q five
-times from HBM — at k ≥ 16 columns of Q the fused version moves less data,
-and all reductions are VMEM-local).
+The p1/p2 accumulators are tiny [1, k] VMEM scratch — the paper's "small
+global memory for broadcast purposes".  z lives in the output block, which
+stays resident in VMEM across all 3·f steps of one batch element (its block
+index only moves with the batch), so the intermediate never round-trips
+through HBM.
 
-Two symmetric variants:
+Two symmetric variants share one kernel body:
 * ``right``: z = CGS2(Aᵀu, V) — output over columns of A (length H),
 * ``left`` : w = CGS2(A v, U) — output over rows of A (length S).
 
-Both are validated against ``ref.py`` in interpret mode; on hardware the
-MXU handles the [blk, k] projections and the VPU the element-wise tail.
+Layout for the TPU compiler (Mosaic): every vector is a lane-major row
+[1, n], the products run on the MXU at full f32 precision, and z is stored
+as [f, 1, blk] so each grid step addresses its block by a leading-dim index.
+Compiled launches need the A block to tile: ``blk = H / f`` a multiple of
+128 lanes (:data:`LANE`) on the right step, ``blk = S / f`` a multiple of
+8 sublanes (:data:`SUBLANE`) on the left; ``kernels.ops.padded_dims``
+pads S and H accordingly.  Interpret mode accepts any divisor.
 """
 from __future__ import annotations
 
@@ -44,96 +48,34 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..engine.platform import resolve_interpret
 
+#: Lanes / sublanes of a TPU vector register: the compiled A block is a
+#: whole number of (SUBLANE, LANE) tiles.
+LANE = 128
+SUBLANE = 8
+#: Scoped-VMEM ceiling the compiled kernel may request (v5e has 128 MiB).
+_VMEM_CAP = 100 * 2 ** 20
+_VMEM_DEFAULT = 16 * 2 ** 20
 
-def _reorth_right_kernel(a_ref, u_ref, q_ref, z_out, nrm_out,
-                         z_buf, p1, p2, nrm, *, f: int, blk: int):
-    """grid = (3 passes, f column-blocks). A block (S, blk); Q block (blk, k)."""
-    p = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when((p == 0) & (j == 0))
-    def _init():
-        p1[...] = jnp.zeros_like(p1)
-        p2[...] = jnp.zeros_like(p2)
-        nrm[...] = jnp.zeros_like(nrm)
-
-    q = q_ref[...].astype(jnp.float32)            # (blk, k)
-
-    @pl.when(p == 0)
-    def _pass0():
-        a = a_ref[...].astype(jnp.float32)        # (S, blk)
-        u = u_ref[...].astype(jnp.float32)        # (S, 1)
-        z = jnp.sum(a * u, axis=0)[None, :]       # (1, blk) — local reduce
-        pl.store(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)), z)
-        p1[...] += jnp.dot(z, q, preferred_element_type=jnp.float32)
-
-    @pl.when(p == 1)
-    def _pass1():
-        z = pl.load(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)))
-        z = z - jnp.dot(p1[...], q.T, preferred_element_type=jnp.float32)
-        pl.store(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)), z)
-        p2[...] += jnp.dot(z, q, preferred_element_type=jnp.float32)
-
-    @pl.when(p == 2)
-    def _pass2():
-        z = pl.load(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)))
-        z = z - jnp.dot(p2[...], q.T, preferred_element_type=jnp.float32)
-        z_out[...] = z
-        nrm[...] += jnp.sum(z * z)
-
-    # nrm_out is revisited every step; the final write wins.
-    @pl.when((p == 2) & (j == f - 1))
-    def _fin():
-        nrm_out[...] = nrm[...]
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _reorth_left_kernel(a_ref, v_ref, q_ref, z_out, nrm_out,
-                        z_buf, p1, p2, nrm, *, f: int, blk: int):
-    """grid = (3 passes, f row-blocks). A block (blk, H); Q block (blk, k)."""
-    p = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when((p == 0) & (j == 0))
-    def _init():
-        p1[...] = jnp.zeros_like(p1)
-        p2[...] = jnp.zeros_like(p2)
-        nrm[...] = jnp.zeros_like(nrm)
-
-    q = q_ref[...].astype(jnp.float32)            # (blk, k)
-
-    @pl.when(p == 0)
-    def _pass0():
-        a = a_ref[...].astype(jnp.float32)        # (blk, H)
-        v = v_ref[...].astype(jnp.float32)        # (1, H)
-        z = jnp.sum(a * v, axis=1)[:, None]       # (blk, 1) — local reduce
-        pl.store(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)), z)
-        p1[...] += jnp.dot(z.T, q, preferred_element_type=jnp.float32)
-
-    @pl.when(p == 1)
-    def _pass1():
-        z = pl.load(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)))
-        z = z - jnp.dot(q, p1[...].T, preferred_element_type=jnp.float32)
-        pl.store(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)), z)
-        p2[...] += jnp.dot(z.T, q, preferred_element_type=jnp.float32)
-
-    @pl.when(p == 2)
-    def _pass2():
-        z = pl.load(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)))
-        z = z - jnp.dot(q, p2[...].T, preferred_element_type=jnp.float32)
-        z_out[...] = z
-        nrm[...] += jnp.sum(z * z)
-
-    @pl.when((p == 2) & (j == f - 1))
-    def _fin():
-        nrm_out[...] = nrm[...]
+def _row_dot(x, y):
+    """[1, n] · [n, m] → [1, m] at full f32 precision."""
+    return jnp.dot(x, y, precision=_HI, preferred_element_type=jnp.float32)
 
 
-def _reorth_right_batched_kernel(a_ref, u_ref, q_ref, z_out, nrm_out,
-                                 z_buf, p1, p2, nrm, *, f: int, blk: int):
-    """grid = (B, 3 passes, f column-blocks) — batch is the OUTERMOST grid
-    dim, so one launch covers every prompt and the per-pass scratch
-    (z_buf/p1/p2/nrm) is simply re-initialized as each batch element's
-    pass 0 begins."""
+def _row_dot_t(x, y):
+    """[1, n] · [m, n]ᵀ → [1, m] at full f32 precision."""
+    return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _reorth_kernel(a_ref, x_ref, q_ref, z_ref, nrm_ref, p1, p2, *,
+                   right: bool):
+    """grid = (B, 3 passes, f blocks) — batch is the OUTERMOST grid dim, so
+    one launch covers every prompt and p1/p2 are re-initialized as each
+    batch element's pass 0 begins.  A block: (S, blk) right / (blk, H) left;
+    x: u (1, S) right / v (1, H) left; Q block (blk, k)."""
     p = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -141,245 +83,132 @@ def _reorth_right_batched_kernel(a_ref, u_ref, q_ref, z_out, nrm_out,
     def _init():
         p1[...] = jnp.zeros_like(p1)
         p2[...] = jnp.zeros_like(p2)
-        nrm[...] = jnp.zeros_like(nrm)
+        nrm_ref[...] = jnp.zeros_like(nrm_ref)
 
-    q = q_ref[0].astype(jnp.float32)              # (blk, k)
-
-    @pl.when(p == 0)
-    def _pass0():
-        a = a_ref[0].astype(jnp.float32)          # (S, blk)
-        u = u_ref[0].astype(jnp.float32)          # (S, 1)
-        z = jnp.sum(a * u, axis=0)[None, :]       # (1, blk) — local reduce
-        pl.store(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)), z)
-        p1[...] += jnp.dot(z, q, preferred_element_type=jnp.float32)
-
-    @pl.when(p == 1)
-    def _pass1():
-        z = pl.load(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)))
-        z = z - jnp.dot(p1[...], q.T, preferred_element_type=jnp.float32)
-        pl.store(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)), z)
-        p2[...] += jnp.dot(z, q, preferred_element_type=jnp.float32)
-
-    @pl.when(p == 2)
-    def _pass2():
-        z = pl.load(z_buf, (pl.dslice(0, 1), pl.dslice(j * blk, blk)))
-        z = z - jnp.dot(p2[...], q.T, preferred_element_type=jnp.float32)
-        z_out[0] = z
-        nrm[...] += jnp.sum(z * z)
-
-    @pl.when((p == 2) & (j == f - 1))
-    def _fin():
-        nrm_out[0] = nrm[...]
-
-
-def _reorth_left_batched_kernel(a_ref, v_ref, q_ref, z_out, nrm_out,
-                                z_buf, p1, p2, nrm, *, f: int, blk: int):
-    """grid = (B, 3 passes, f row-blocks) — batched twin of the left step."""
-    p = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when((p == 0) & (j == 0))
-    def _init():
-        p1[...] = jnp.zeros_like(p1)
-        p2[...] = jnp.zeros_like(p2)
-        nrm[...] = jnp.zeros_like(nrm)
-
-    q = q_ref[0].astype(jnp.float32)              # (blk, k)
+    q = q_ref[0].astype(jnp.float32)                       # (blk, k)
 
     @pl.when(p == 0)
     def _pass0():
-        a = a_ref[0].astype(jnp.float32)          # (blk, H)
-        v = v_ref[0].astype(jnp.float32)          # (1, H)
-        z = jnp.sum(a * v, axis=1)[:, None]       # (blk, 1) — local reduce
-        pl.store(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)), z)
-        p1[...] += jnp.dot(z.T, q, preferred_element_type=jnp.float32)
+        a = a_ref[0].astype(jnp.float32)
+        x = x_ref[0].astype(jnp.float32)
+        z = _row_dot(x, a) if right else _row_dot_t(x, a)  # (1, blk)
+        z_ref[0, j] = z
+        p1[...] += _row_dot(z, q)
 
     @pl.when(p == 1)
     def _pass1():
-        z = pl.load(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)))
-        z = z - jnp.dot(q, p1[...].T, preferred_element_type=jnp.float32)
-        pl.store(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)), z)
-        p2[...] += jnp.dot(z.T, q, preferred_element_type=jnp.float32)
+        z = z_ref[0, j] - _row_dot_t(p1[...], q)
+        z_ref[0, j] = z
+        p2[...] += _row_dot(z, q)
 
     @pl.when(p == 2)
     def _pass2():
-        z = pl.load(z_buf, (pl.dslice(j * blk, blk), pl.dslice(0, 1)))
-        z = z - jnp.dot(q, p2[...].T, preferred_element_type=jnp.float32)
-        z_out[0] = z
-        nrm[...] += jnp.sum(z * z)
-
-    @pl.when((p == 2) & (j == f - 1))
-    def _fin():
-        nrm_out[0] = nrm[...]
+        z = z_ref[0, j] - _row_dot_t(p2[...], q)
+        z_ref[0, j] = z
+        nrm_ref[0] += jnp.sum(z * z, axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("expansion", "interpret"))
+def _vmem_limit(a_blk: int, x_len: int, q_blk: int, k: int, out_len: int
+                ) -> int:
+    """Scoped VMEM for one launch: double-buffered A/x/Q/z blocks (rows pad
+    to 8 sublanes, k pads to whole lanes) plus headroom."""
+    k_pad = -(-k // LANE) * LANE
+    need = 4 * 2 * (a_blk + 8 * x_len + q_blk * k_pad + 8 * out_len)
+    need = need + need // 4 + 2 * 2 ** 20
+    if need > _VMEM_CAP:
+        raise ValueError(f"re-orth launch needs {need} B of VMEM "
+                         f"(cap {_VMEM_CAP}); raise the expansion factor")
+    return max(need, _VMEM_DEFAULT)
+
+
+def _launch(a, x, q, *, right: bool, expansion: int, interpret: bool):
+    """One pallas_call over the batch: returns (z [B, n], ‖z‖² [B])."""
+    b_dim, s_dim, h_dim = a.shape
+    k = q.shape[-1]
+    n = h_dim if right else s_dim
+    f = expansion
+    if n % f:
+        raise ValueError(f"reduced axis {n} must divide expansion {f}")
+    blk = n // f
+    tile = LANE if right else SUBLANE
+    if not interpret and f > 1 and blk % tile:
+        raise ValueError(f"compiled re-orth needs {n}/{f} = {blk} to be a "
+                         f"multiple of {tile}; pad through "
+                         "kernels.ops.padded_dims")
+    if right:
+        a_spec = pl.BlockSpec((1, s_dim, blk), lambda b, p, j: (b, 0, j))
+        x_len = s_dim
+    else:
+        a_spec = pl.BlockSpec((1, blk, h_dim), lambda b, p, j: (b, j, 0))
+        x_len = h_dim
+    params = None if interpret else pltpu.CompilerParams(
+        vmem_limit_bytes=_vmem_limit(s_dim * h_dim // f, x_len, blk, k, n))
+    z, nrm = pl.pallas_call(
+        functools.partial(_reorth_kernel, right=right),
+        grid=(b_dim, 3, f),
+        in_specs=[
+            a_spec,
+            pl.BlockSpec((1, 1, x_len), lambda b, p, j: (b, 0, 0)),
+            pl.BlockSpec((1, blk, k), lambda b, p, j: (b, j, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, f, 1, blk), lambda b, p, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, p, j: (b, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b_dim, f, 1, blk), jnp.float32),
+            jax.ShapeDtypeStruct((b_dim, 1, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((1, k), jnp.float32),                # p1 = Qᵀz
+            pltpu.VMEM((1, k), jnp.float32),                # p2
+        ],
+        compiler_params=params,
+        interpret=interpret,
+    )(a, x[:, None, :], q)
+    return z.reshape(b_dim, n), nrm[:, 0, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("expansion", "interpret"))
 def reorth_right_batched(a: jax.Array, u: jax.Array, v_buf: jax.Array,
                          *, expansion: int = 8,
                          interpret: Optional[bool] = None):
     """Batched fused  z_b = CGS2(A_bᵀ·u_b, V_b)  → (z [B, H], ‖z‖² [B]).
 
     ONE pallas_call for the whole batch: grid (B, 3, f).  H must divide by
-    ``expansion``.
+    ``expansion`` (and by 128·f when compiled).
     """
-    interpret = resolve_interpret(interpret)
-    b_dim, s_dim, h_dim = a.shape
-    k = v_buf.shape[-1]
-    assert h_dim % expansion == 0, (h_dim, expansion)
-    blk = h_dim // expansion
-    f = expansion
-
-    z, nrm = pl.pallas_call(
-        functools.partial(_reorth_right_batched_kernel, f=f, blk=blk),
-        grid=(b_dim, 3, f),
-        in_specs=[
-            pl.BlockSpec((1, s_dim, blk), lambda b, p, j: (b, 0, j)),
-            pl.BlockSpec((1, s_dim, 1), lambda b, p, j: (b, 0, 0)),
-            pl.BlockSpec((1, blk, k), lambda b, p, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, blk), lambda b, p, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, 1), lambda b, p, j: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b_dim, 1, h_dim), jnp.float32),
-            jax.ShapeDtypeStruct((b_dim, 1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, h_dim), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(a, u[..., None], v_buf)
-    return z[:, 0], nrm[:, 0, 0]
+    return _launch(a, u, v_buf, right=True, expansion=expansion,
+                   interpret=resolve_interpret(interpret))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("expansion", "interpret"))
+@functools.partial(jax.jit, static_argnames=("expansion", "interpret"))
 def reorth_left_batched(a: jax.Array, v: jax.Array, u_buf: jax.Array,
                         *, expansion: int = 8,
-                         interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None):
     """Batched fused  w_b = CGS2(A_b·v_b, U_b)  → (w [B, S], ‖w‖² [B]).
-    S % expansion == 0."""
-    interpret = resolve_interpret(interpret)
-    b_dim, s_dim, h_dim = a.shape
-    k = u_buf.shape[-1]
-    assert s_dim % expansion == 0, (s_dim, expansion)
-    blk = s_dim // expansion
-    f = expansion
 
-    z, nrm = pl.pallas_call(
-        functools.partial(_reorth_left_batched_kernel, f=f, blk=blk),
-        grid=(b_dim, 3, f),
-        in_specs=[
-            pl.BlockSpec((1, blk, h_dim), lambda b, p, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, h_dim), lambda b, p, j: (b, 0, 0)),
-            pl.BlockSpec((1, blk, k), lambda b, p, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk, 1), lambda b, p, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, p, j: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b_dim, s_dim, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b_dim, 1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((s_dim, 1), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(a, v[:, None, :], u_buf)
-    return z[..., 0], nrm[:, 0, 0]
+    S must divide by ``expansion`` (and by 8·f when compiled)."""
+    return _launch(a, v, u_buf, right=False, expansion=expansion,
+                   interpret=resolve_interpret(interpret))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("expansion", "interpret"))
 def reorth_right(a: jax.Array, u: jax.Array, v_buf: jax.Array,
-                 *, expansion: int = 8,
-                         interpret: Optional[bool] = None):
-    """Fused  z = CGS2(Aᵀ·u, V)  → (z [H], ‖z‖² scalar).
-
-    ``expansion`` is the paper's f: the number of column-blocks the
-    reduction is expanded over.  H must divide by ``expansion``.
-    """
-    interpret = resolve_interpret(interpret)
-    s_dim, h_dim = a.shape
-    k = v_buf.shape[-1]
-    assert h_dim % expansion == 0, (h_dim, expansion)
-    blk = h_dim // expansion
-    f = expansion
-
-    z, nrm = pl.pallas_call(
-        functools.partial(_reorth_right_kernel, f=f, blk=blk),
-        grid=(3, f),
-        in_specs=[
-            pl.BlockSpec((s_dim, blk), lambda p, j: (0, j)),   # A columns
-            pl.BlockSpec((s_dim, 1), lambda p, j: (0, 0)),     # u
-            pl.BlockSpec((blk, k), lambda p, j: (j, 0)),       # V rows
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk), lambda p, j: (0, j)),       # z
-            pl.BlockSpec((1, 1), lambda p, j: (0, 0)),         # ‖z‖²
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, h_dim), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, h_dim), jnp.float32),               # z intermediate
-            pltpu.VMEM((1, k), jnp.float32),                   # p1 = Qᵀz
-            pltpu.VMEM((1, k), jnp.float32),                   # p2
-            pltpu.VMEM((1, 1), jnp.float32),                   # norm acc
-        ],
-        interpret=interpret,
-    )(a, u[:, None], v_buf)
-    return z[0], nrm[0, 0]
+                 *, expansion: int = 8, interpret: Optional[bool] = None):
+    """Fused  z = CGS2(Aᵀ·u, V)  → (z [H], ‖z‖² scalar): the B=1 slice of
+    :func:`reorth_right_batched`."""
+    z, nrm = reorth_right_batched(a[None], u[None], v_buf[None],
+                                  expansion=expansion, interpret=interpret)
+    return z[0], nrm[0]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("expansion", "interpret"))
 def reorth_left(a: jax.Array, v: jax.Array, u_buf: jax.Array,
-                *, expansion: int = 8,
-                         interpret: Optional[bool] = None):
-    """Fused  w = CGS2(A·v, U)  → (w [S], ‖w‖² scalar).  S % expansion == 0."""
-    interpret = resolve_interpret(interpret)
-    s_dim, h_dim = a.shape
-    k = u_buf.shape[-1]
-    assert s_dim % expansion == 0, (s_dim, expansion)
-    blk = s_dim // expansion
-    f = expansion
-
-    z, nrm = pl.pallas_call(
-        functools.partial(_reorth_left_kernel, f=f, blk=blk),
-        grid=(3, f),
-        in_specs=[
-            pl.BlockSpec((blk, h_dim), lambda p, j: (j, 0)),   # A rows
-            pl.BlockSpec((1, h_dim), lambda p, j: (0, 0)),     # v
-            pl.BlockSpec((blk, k), lambda p, j: (j, 0)),       # U rows
-        ],
-        out_specs=[
-            pl.BlockSpec((blk, 1), lambda p, j: (j, 0)),       # w
-            pl.BlockSpec((1, 1), lambda p, j: (0, 0)),         # ‖w‖²
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s_dim, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((s_dim, 1), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(a, v[None, :], u_buf)
-    return z[:, 0], nrm[0, 0]
+                *, expansion: int = 8, interpret: Optional[bool] = None):
+    """Fused  w = CGS2(A·v, U)  → (w [S], ‖w‖² scalar): the B=1 slice of
+    :func:`reorth_left_batched`."""
+    w, nrm = reorth_left_batched(a[None], v[None], u_buf[None],
+                                 expansion=expansion, interpret=interpret)
+    return w[0], nrm[0]
 
 
 # -- tunable space (see repro.tune): the decomposition operating point ------

@@ -45,9 +45,13 @@ def pad_plan(shape: tuple, axis: int, mult: int):
 
 @functools.lru_cache(maxsize=None)
 def padded_dims(s: int, h: int, expansion: int):
-    """Cached (S_pad, H_pad) for a fused-Lanczos launch: the left step needs
-    S % f == 0, the right step H % f == 0."""
-    return s + ((-s) % expansion), h + ((-h) % expansion)
+    """Cached (S_pad, H_pad) for a fused-Lanczos launch: the left step
+    splits S into f row blocks of whole sublane tiles, the right step H
+    into f column blocks of whole lane tiles (what the compiled kernel
+    needs; zero pads are exact, so interpret mode takes the same plan)."""
+    sm = lanczos_reorth.SUBLANE * expansion
+    hm = lanczos_reorth.LANE * expansion
+    return s + ((-s) % sm), h + ((-h) % hm)
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):

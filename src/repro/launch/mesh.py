@@ -7,6 +7,15 @@ smoke tests see 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the compiler propagates shardings
+    through every op, as the serving and model code assume (make_mesh's
+    default Explicit axes put shardings in the types, where a reshape of a
+    sharded axis must state its output sharding)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     "model").  512 placeholder devices are required for multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -25,7 +34,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` BEFORE jax
     initializes — the CI distributed job and
     ``benchmarks/serving_sharded.py`` both do)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh(spec):

@@ -6,15 +6,47 @@ device.
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 from typing import Any, Dict
 
 # ---------------------------------------------------------------------------
-# TPU v5e hardware constants (roofline denominators)
+# Published chip peaks (roofline denominators), keyed by device_kind
 # ---------------------------------------------------------------------------
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float            # FLOP/s
+    hbm_bw: float                # bytes/s
+    ici_link_bw: float           # bytes/s per interconnect link
+    source: str
+
+
+#: ``jax.Device.device_kind`` → peaks.  The one table every roofline in the
+#: repo reads (cost model, dry-run, benchmarks); a kind missing here is an
+#: error, never a default.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bw=819e9, ici_link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI "
+               "(4 links x 50 GB/s)"),
+}
+#: device_kind JAX reports for a TPU v5e chip (the dry-run's target).
+V5E_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; raises KeyError for a kind not in
+    :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "u64": 8, "s64": 8,
                 "u32": 4, "s32": 4, "u16": 2, "s16": 2, "u8": 1, "s8": 1,
@@ -69,13 +101,15 @@ def roofline_terms(flops_per_dev: float, hbm_bytes_per_dev: float,
     """Three roofline terms in seconds (all PER-DEVICE quantities).
 
     cost_analysis of the SPMD-partitioned module is per-device, so we divide
-    by single-chip peaks (equivalent to global/chips — see EXPERIMENTS.md).
+    by single-chip v5e peaks (equivalent to global/chips — see
+    EXPERIMENTS.md).
     """
+    pk = chip_peaks(V5E_KIND)
     coll_bytes = sum(v["bytes"] * _RING_FACTOR[k] for k, v in coll.items())
     return {
-        "compute_s": flops_per_dev / PEAK_FLOPS,
-        "memory_s": hbm_bytes_per_dev / HBM_BW,
-        "collective_s": coll_bytes / ICI_BW,
+        "compute_s": flops_per_dev / pk.bf16_flops,
+        "memory_s": hbm_bytes_per_dev / pk.hbm_bw,
+        "collective_s": coll_bytes / pk.ici_link_bw,
         "collective_bytes": coll_bytes,
     }
 

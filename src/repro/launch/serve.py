@@ -1,29 +1,40 @@
-"""Serving CLI: continuous-batching engine on a reduced config.
+"""Serving CLI: continuous-batching engine on a registered config.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
+      --decompose-kv-rank 64 --dkv-tail 128 --requests 8
+
+serves the architecture at its published widths, with seeded random
+weights.  ``--reduced`` swaps in the tiny same-family variant
+(``ArchConfig.reduced``: d_model 128, vocab 512) for CPU runs and tests:
+
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --arch gemma-2b --reduced --requests 8
 
 The engine is family-generic (``repro.serving.families``): ``--family
-ssm|moe|hybrid|dense`` serves that family's default reduced arch on the
-same slot/fused/async machinery, e.g.
+ssm|moe|hybrid|dense`` serves that family's default arch on the same
+slot/fused/async machinery, e.g.
 
-  PYTHONPATH=src python -m repro.launch.serve --family ssm --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --family ssm --reduced
 
 Decomposed-KV serving (the paper's activation decomposition applied to the
 KV stream) rides one DecomposeEngine, constructed here from the CLI flags
-and handed to the serving engine:
+and handed to the serving engine (``--decompose-kv-rank 8 --dkv-tail 16``).
+``--backend auto`` (the default) resolves to the compiled Pallas kernels on
+a TPU and to the jnp reference elsewhere.
 
-  ... --decompose-kv-rank 8 --dkv-tail 16 --backend pallas_interpret
+``--expansion auto`` resolves through the ``repro.tune`` autotuner; warmup
+then PRE-TUNES the prefill decomposition shape this serving config will
+actually launch (the bucketed prompt length through the lanczos_reorth
+kernel family), so the first request pays no tuning cost and the resolved
+operating point is printed before traffic starts.
 
-``--backend auto`` / ``--expansion auto`` resolve through the ``repro.tune``
-autotuner; with ``--expansion auto`` warmup PRE-TUNES the prefill
-decomposition shape this serving config will actually launch (the bucketed
-prompt length through the lanczos_reorth kernel family), so the first
-request pays no tuning cost and the resolved operating point is printed
-before traffic starts.
+:func:`main` takes an ``argv`` list and returns the engine and its finished
+requests, so scripts drive exactly the code the CLI runs.
 """
 from __future__ import annotations
 
 import argparse
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -34,6 +45,7 @@ from ..models import api
 from ..obs import (GLOBAL, Observability, compile_stats, write_json_snapshot,
                    write_prometheus)
 from ..serving import Engine, Request
+from .compile_cache import enable_compile_cache
 from .mesh import parse_mesh
 
 
@@ -46,7 +58,10 @@ _FAMILY_DEFAULT_ARCH = {
 }
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None
+         ) -> Tuple[Engine, List[Request]]:
+    """Run the CLI on ``argv`` (``sys.argv[1:]`` when None); returns the
+    serving engine and its finished requests."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="architecture name (required unless --family "
@@ -58,6 +73,11 @@ def main() -> None:
                          "zamba2-1.2b, dense = llama2-7b); --arch "
                          "overrides the arch, and the engine checks it "
                          "really is that family")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family variant (d_model 128, "
+                         "vocab 512) instead of the published widths")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -84,10 +104,11 @@ def main() -> None:
     ap.add_argument("--eos-id", type=int, default=None,
                     help="stop token id: requests finish (and free their "
                          "slot) the moment they emit it")
-    ap.add_argument("--backend", default="reference",
+    ap.add_argument("--backend", default="auto",
                     choices=available_backends() + ["auto"],
-                    help="decomposition backend for the engine "
-                         "(auto = tuner-resolved)")
+                    help="decomposition backend for the engine (auto = "
+                         "compiled Pallas on a TPU, jnp reference "
+                         "elsewhere, unless a tuned override exists)")
     ap.add_argument("--expansion", default="8",
                     help="D-com compute-expansion factor f, or 'auto' "
                          "(tuner-resolved per shape-bucket)")
@@ -136,19 +157,21 @@ def main() -> None:
     ap.add_argument("--stats-every", type=int, default=0, metavar="N",
                     help="print a p50/p95/p99 stats snapshot every N "
                          "engine steps (0 = only the final summary)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.arch is None:
         if args.family is None:
             ap.error("one of --arch / --family is required")
         args.arch = _FAMILY_DEFAULT_ARCH[args.family]
+    enable_compile_cache()
     mesh = parse_mesh(args.mesh)
-    cfg = get_arch(args.arch).reduced()
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     if args.family is not None and cfg.family != args.family:
         ap.error(f"--arch {args.arch} is family {cfg.family!r}, "
                  f"not {args.family!r}")
-    fns = api.model_fns(cfg)
-    params = fns.init(jax.random.PRNGKey(0), cfg)
+    params = api.init_params(cfg, args.seed, mesh)
     expansion = args.expansion if args.expansion == "auto" \
         else int(args.expansion)
     decode_block = args.decode_block if args.decode_block == "auto" \
@@ -196,7 +219,7 @@ def main() -> None:
                  eos_id=args.eos_id, prefill_async=args.prefill_async,
                  ready_order=args.ready_order, obs=obs)
 
-    rng = np.random.RandomState(0)
+    rng = np.random.RandomState(args.seed)
     for i in range(args.requests):
         eng.submit(Request(uid=i,
                            prompt=rng.randint(0, cfg.vocab, args.prompt_len,
@@ -265,6 +288,7 @@ def main() -> None:
         print(f"trace: wrote {args.trace_out} "
               f"({len(obs.tracer.events)} events, "
               f"{obs.tracer.dropped} dropped)")
+    return eng, done
 
 
 def _pctl_line(s, prefix: str = "") -> str:

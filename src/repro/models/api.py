@@ -136,6 +136,20 @@ def model_fns(cfg: ArchConfig) -> ModelFns:
     return _FAMILY[cfg.family]
 
 
+def init_params(cfg: ArchConfig, seed: int = 0, mesh=None):
+    """Seeded random params in ``cfg.dtype`` from ONE jitted program, so no
+    float32 copy of a whole stacked weight is ever materialized (the eager
+    init draws each [L, ...] weight in f32 before casting).  With a mesh
+    the params are placed once, replicated over it (the key is made inside
+    the program from the host-side seed, so nothing moves between
+    devices)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rep = None if mesh is None else NamedSharding(mesh, P())
+    init = jax.jit(lambda s: model_fns(cfg).init(jax.random.PRNGKey(s), cfg),
+                   in_shardings=rep, out_shardings=rep)
+    return init(seed)
+
+
 # ---------------------------------------------------------------------------
 # Cache splicing (per-slot admission support, every family)
 # ---------------------------------------------------------------------------

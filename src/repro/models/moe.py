@@ -211,10 +211,7 @@ def moe_ffn_shard_map(p: Params, x: Array, cfg, mesh) -> Tuple[Array, Array]:
 
     dp = dp_names if len(dp_names) > 1 else dp_names[0]
     w_f_spec = "data" if two_d else None
-    # jax.shard_map is 0.5+; this tree pins 0.4.x where it lives under
-    # jax.experimental (same semantics, same kwargs).
-    from jax.experimental.shard_map import shard_map as _shard_map
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P("model", None, w_f_spec), P("model", None, w_f_spec),

@@ -922,9 +922,9 @@ class Engine:
     def _decode_block_round(self) -> List[Request]:
         blk = self._block_len()
         tok = self._last_tokens()
-        stops = jnp.asarray(self._stop_table())
+        stops = np.asarray(self._stop_table())
         key = jax.random.fold_in(self._key, 0)      # decode sample stream
-        n, r0 = jnp.int32(blk), jnp.int32(self._round)
+        n, r0 = np.int32(blk), np.int32(self._round)
         t0 = time.perf_counter()
         bspan = self.trace.begin("decode-block", "engine", {"max_steps": blk})
         with phase_scope("decode"):

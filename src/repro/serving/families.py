@@ -397,8 +397,8 @@ class ServingFamily:
         engine cache and returns the logits (sampling stays host-side in
         ``Engine._sample_host`` — the one sanctioned sync)."""
         eng = self.eng
-        logits, eng.cache = self._decode(eng.params, jnp.asarray(tok),
-                                         eng.cache, jnp.asarray(eng.pos))
+        logits, eng.cache = self._decode(eng.params, np.asarray(tok),
+                                         eng.cache, np.asarray(eng.pos))
         return logits
 
     def decode_block(self, tok: np.ndarray, n, stops, key, r0):
@@ -407,8 +407,8 @@ class ServingFamily:
         eng = self.eng
         fn = _jitted_decode_block(eng.fns, eng.cfg, eng.decode_block,
                                   eng.sampler, eng.mesh)
-        buf, steps, _, eng.cache = fn(eng.params, jnp.asarray(tok),
-                                      eng.cache, jnp.asarray(eng.pos),
+        buf, steps, _, eng.cache = fn(eng.params, np.asarray(tok),
+                                      eng.cache, np.asarray(eng.pos),
                                       n, stops, key, r0)
         return buf, steps
 
@@ -638,7 +638,7 @@ class TransformerDKVServing(ServingFamily):
         eng = self.eng
         nb = min(_pow2(len(batch)), max(eng.slots, 1))
         toks = eng._toks(batch, nb, plen, lambda j: j)
-        logits, fresh = self._prefill_dkv(eng.params, jnp.asarray(toks))
+        logits, fresh = self._prefill_dkv(eng.params, np.asarray(toks))
         eng.stats.prefill_batches += 1
 
         def complete():
@@ -739,9 +739,9 @@ class TransformerDKVServing(ServingFamily):
         start = np.full(m, match_len, np.int32)
         slen = np.full(m, plen - match_len, np.int32)
         logits, pg.cache = pg._suffix(
-            eng.params, jnp.asarray(stoks), pg.cache,
+            eng.params, np.asarray(stoks), pg.cache,
             np.asarray(ent_bt, np.int32), k_vt, v_vt,
-            jnp.asarray(start), jnp.asarray(slen),
+            np.asarray(start), np.asarray(slen),
             np.asarray(bt_t, np.int32), np.asarray(idx, np.int32),
             match_len, r_ent)
         eng.stats.prefill_batches += 1
@@ -785,7 +785,7 @@ class TransformerDKVServing(ServingFamily):
         mtoks = np.zeros((nb, plen), np.int32)
         for mi, j in enumerate(misses):
             mtoks[mi] = padded[j]
-        logits, fresh = self._prefill_dkv(eng.params, jnp.asarray(mtoks))
+        logits, fresh = self._prefill_dkv(eng.params, np.asarray(mtoks))
         eng.stats.prefill_batches += 1
         npg = pg.pages_for(plen)
         bt_u, bt_t, idx = [], [], []
@@ -847,7 +847,7 @@ class TransformerDKVServing(ServingFamily):
         eng = self.eng
         toks = eng._toks(batch, eng.slots, plen, lambda j: slots_idx[j])
         logits, eng.cache = self._prefill_dkv(eng.params,
-                                              jnp.asarray(toks))
+                                              np.asarray(toks))
         eng.rank_eff[slots_idx] = eng.cache["k_u"].shape[-1]
         return logits
 
@@ -857,17 +857,17 @@ class TransformerDKVServing(ServingFamily):
         if eng.pager is not None:
             pg = eng.pager
             logits, pg.cache = pg._decode(
-                eng.params, jnp.asarray(tok), pg.cache,
-                jnp.asarray(eng.pos),
-                jnp.asarray(eng.frozen_len),
-                jnp.asarray(pg.bt_array(pg.bt_u)),
-                jnp.asarray(pg.bt_array(pg.bt_t, pg.ntp)),
+                eng.params, np.asarray(tok), pg.cache,
+                np.asarray(eng.pos),
+                np.asarray(eng.frozen_len),
+                np.asarray(pg.bt_array(pg.bt_u)),
+                np.asarray(pg.bt_array(pg.bt_t, pg.ntp)),
                 pg.slab_t, pg.slab_r, eng.dkv_tail)
             return logits
         logits, eng.cache = self._decode_dkv(
-            eng.params, jnp.asarray(tok), eng.cache,
-            jnp.asarray(eng.pos),
-            jnp.asarray(eng.frozen_len))
+            eng.params, np.asarray(tok), eng.cache,
+            np.asarray(eng.pos),
+            np.asarray(eng.frozen_len))
         return logits
 
     def decode_block(self, tok: np.ndarray, n, stops, key, r0):
@@ -878,17 +878,17 @@ class TransformerDKVServing(ServingFamily):
             fn = _jitted_paged_decode_block(eng.cfg, eng.decode_block,
                                             eng.sampler, eng.mesh)
             buf, steps, _, pg.cache = fn(
-                eng.params, jnp.asarray(tok), pg.cache,
-                jnp.asarray(eng.pos), jnp.asarray(eng.frozen_len),
-                jnp.asarray(pg.bt_array(pg.bt_u)),
-                jnp.asarray(pg.bt_array(pg.bt_t, pg.ntp)),
+                eng.params, np.asarray(tok), pg.cache,
+                np.asarray(eng.pos), np.asarray(eng.frozen_len),
+                np.asarray(pg.bt_array(pg.bt_u)),
+                np.asarray(pg.bt_array(pg.bt_t, pg.ntp)),
                 n, stops, key, r0, pg.slab_t, pg.slab_r, eng.dkv_tail)
             return buf, steps
         fn = _jitted_dkv_decode_block(eng.cfg, eng.decode_block,
                                       eng.sampler, eng.mesh)
         buf, steps, _, eng.cache = fn(
-            eng.params, jnp.asarray(tok), eng.cache,
-            jnp.asarray(eng.pos), jnp.asarray(eng.frozen_len),
+            eng.params, np.asarray(tok), eng.cache,
+            np.asarray(eng.pos), np.asarray(eng.frozen_len),
             n, stops, key, r0)
         return buf, steps
 
@@ -925,9 +925,9 @@ class TransformerDKVServing(ServingFamily):
         new_frozen = np.where(fold, eng.pos,
                               eng.frozen_len).astype(np.int32)
         eng.cache = self._compress_dkv(eng.cache,
-                                       jnp.asarray(eng.frozen_len),
-                                       jnp.asarray(fold),
-                                       jnp.asarray(new_frozen))
+                                       np.asarray(eng.frozen_len),
+                                       np.asarray(fold),
+                                       np.asarray(new_frozen))
         eng.frozen_len = new_frozen
         eng.rank_eff = np.where(
             fold, DK.fold_rank(eng.dkv_rank, r_in, t_frozen,
@@ -980,9 +980,9 @@ class TransformerDKVServing(ServingFamily):
         new_frozen = np.where(fold, eng.pos,
                               eng.frozen_len).astype(np.int32)
         pg.cache = pg._fold(
-            pg.cache, jnp.asarray(eng.frozen_len), jnp.asarray(fold),
-            jnp.asarray(new_frozen), jnp.asarray(pg.bt_array(pg.bt_u)),
-            jnp.asarray(bt_new), jnp.asarray(pg.bt_array(pg.bt_t, pg.ntp)),
+            pg.cache, np.asarray(eng.frozen_len), np.asarray(fold),
+            np.asarray(new_frozen), np.asarray(pg.bt_array(pg.bt_u)),
+            np.asarray(bt_new), np.asarray(pg.bt_array(pg.bt_t, pg.ntp)),
             pg.slab_t, pg.slab_r, eng.dkv_tail)
         r_fold = DK.fold_rank(eng.dkv_rank, pg.slab_r, pg.slab_t,
                               eng.dkv_tail)

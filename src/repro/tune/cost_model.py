@@ -2,8 +2,8 @@
 
 Predicts the latency of one kernel launch as a function of the candidate
 operating point, per (shape, dtype, device).  The model is the paper's own
-explanation of Fig. 12 translated to a roofline (§5.3 + §6.4), reusing the
-v5e constants from ``launch.roofline``:
+explanation of Fig. 12 translated to a roofline (§5.3 + §6.4), reading the
+chip peaks from the ``launch.roofline`` table:
 
 * **memory side** (left of f*): the iterative chain is memory-bound and
   expansion unlocks bandwidth — f partial blocks stream concurrently, so
@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, Mapping, Sequence, Tuple
 
-from ..launch.roofline import HBM_BW, PEAK_FLOPS
+from ..launch.roofline import V5E_KIND, chip_peaks
 
 #: dtype-name → bytes (accepts jnp dtype names and numpy str())
 DTYPE_BYTES = {"float64": 8, "float32": 4, "float16": 2, "bfloat16": 2,
@@ -48,9 +48,16 @@ class DeviceModel:
     step_overhead_s: float       # fixed cost per grid step
 
 
-#: TPU v5e — the deployment target; constants shared with launch.roofline.
-V5E = DeviceModel("tpu-v5e", PEAK_FLOPS, HBM_BW, f_sat=8,
-                  step_overhead_s=1e-6)
+def tpu_model(device_kind: str) -> DeviceModel:
+    """Roofline model of a TPU kind from the peak table (KeyError for a
+    kind the table does not list)."""
+    pk = chip_peaks(device_kind)
+    return DeviceModel(f"tpu:{device_kind}", pk.bf16_flops, pk.hbm_bw,
+                       f_sat=8, step_overhead_s=1e-6)
+
+
+#: TPU v5e — the deployment target.
+V5E = tpu_model(V5E_KIND)
 
 #: Pallas interpret mode on a CPU container: every grid step is executed by
 #: the interpreter, so the per-step overhead dwarfs arithmetic and the model
@@ -60,10 +67,13 @@ CPU_INTERPRET = DeviceModel("cpu-interpret", 5e10, 2e10, f_sat=4,
 
 
 def detect_device() -> DeviceModel:
-    """Pick the device model for THIS process (TPU → v5e roofline,
-    anything else → interpret-mode CPU)."""
+    """Pick the device model for THIS process: a TPU's roofline from the
+    peak table by its ``device_kind`` (an unlisted kind raises), anything
+    else → interpret-mode CPU."""
     import jax
-    return V5E if jax.default_backend() == "tpu" else CPU_INTERPRET
+    if jax.default_backend() != "tpu":
+        return CPU_INTERPRET
+    return tpu_model(jax.devices()[0].device_kind)
 
 
 def device_kind() -> str:

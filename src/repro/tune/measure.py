@@ -46,31 +46,23 @@ def _rand(key: int, shape: Sequence[int], dtype) -> jax.Array:
 
 def _bench_lanczos_reorth(shape, dtype, cand) -> Callable[[], Any]:
     """One fused right re-orth step over [B, S, H] against a k-column
-    buffer, through the candidate's backend."""
-    from ..core.lanczos import DEFAULT_BATCHED_HOOKS
+    buffer, through the candidate backend's own hooks — exactly what the
+    engine executes with that backend (interpret mode included)."""
+    from ..engine.backends import get_backend
     from ..kernels import ops
     if len(shape) == 4:
         b, s, h, k = shape
     else:
         (b, s, h), k = tuple(shape), 16
     f = int(cand["expansion"])
-    backend = cand.get("backend", "pallas_interpret")
-    s_pad, h_pad = ops.padded_dims(s, h, f)
-    a = _rand(0, (b, s_pad, h_pad), dtype)
-    u = _rand(1, (b, s_pad), jnp.float32)
-    vbuf = jnp.zeros((b, h_pad, k), jnp.float32)
-    if backend == "reference":
-        step = jax.jit(DEFAULT_BATCHED_HOOKS.right_step)
-        return lambda: step(a, u, vbuf)
-    if backend == "pallas_vmap":
-        hooks = ops.make_vmapped_pallas_hooks(f, interpret=True)
-        return lambda: hooks.right_step(a, u, vbuf)
-    # measure EXACTLY what the backend executes: pallas_interpret hooks are
-    # built with interpret=True even on TPU (backends.py), so the platform
-    # default must not leak in here
-    interp = backend == "pallas_interpret"
-    return lambda: ops.reorth_right_batched(a, u, vbuf, expansion=f,
-                                            interpret=interp)
+    backend = get_backend(cand["backend"])
+    if backend.requires_padding:
+        s, h = ops.padded_dims(s, h, f)
+    a = _rand(0, (b, s, h), dtype)
+    u = _rand(1, (b, s), jnp.float32)
+    vbuf = jnp.zeros((b, h, k), jnp.float32)
+    step = jax.jit(backend.make_hooks(f).right_step)
+    return lambda: step(a, u, vbuf)
 
 
 def _bench_matvec_expand(shape, dtype, cand) -> Callable[[], Any]:

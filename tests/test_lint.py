@@ -431,7 +431,7 @@ def test_p1_suppression():
 
 def test_s1_flags_shard_map_missing_out_specs():
     active, _ = lint("""\
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         g = shard_map(f, mesh=mesh, in_specs=(spec,))
     """)
     assert active == ["S1"]
@@ -448,7 +448,7 @@ def test_s1_flags_half_specified_jit_shardings():
 def test_s1_allows_both_or_neither():
     active, _ = lint("""\
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         g1 = jax.jit(f, in_shardings=(s,), out_shardings=s)
         g2 = jax.jit(f)
         g3 = shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec)

@@ -348,9 +348,9 @@ from jax.sharding import AbstractMesh, PartitionSpec as ShP
 
 from repro.distributed import sharding as _sh
 
-_SMESHES = [AbstractMesh((("data", 8), ("model", 1))),
-            AbstractMesh((("data", 2), ("model", 4))),
-            AbstractMesh((("pod", 2), ("data", 2), ("model", 2)))]
+_SMESHES = [AbstractMesh((8, 1), ("data", "model")),
+            AbstractMesh((2, 4), ("data", "model")),
+            AbstractMesh((2, 2, 2), ("pod", "data", "model"))]
 
 
 def _axis_sz(mesh, axis):

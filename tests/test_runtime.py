@@ -75,6 +75,7 @@ from repro.optim import make_optimizer
 from repro.runtime import steps as steps_mod
 from repro.runtime.driver import restore_for_mesh
 from repro import checkpoint as ckpt
+from repro.launch.mesh import make_host_mesh
 
 cfg = all_archs()["deepseek-7b"].reduced().replace(name="elastic-e2e")
 shape = ShapeSpec("t", 16, 8, "train")
@@ -93,14 +94,14 @@ def run_steps(params, opt_state, mesh, start, n):
     return params, opt_state, losses
 
 # phase 1: train 6 steps on a 2x4 mesh, checkpoint
-mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+mesh_a = make_host_mesh(2, 4)
 params, opt_state = steps_mod.init_train_state(cfg, jax.random.PRNGKey(0),
                                                opt)
 params, opt_state, la = run_steps(params, opt_state, mesh_a, 0, 6)
 ckpt.save({"params": params, "opt": opt_state}, "%s", 5)
 
 # phase 2: ELASTIC restore onto a 4x2 mesh, continue 3 steps
-mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+mesh_b = make_host_mesh(4, 2)
 state = restore_for_mesh(cfg, "%s", mesh_b, optimizer=opt)
 p2, o2, lb = run_steps(state["params"], state["opt"], mesh_b, 6, 3)
 

@@ -733,13 +733,18 @@ _ASYNC_SHARDED_SCRIPT = textwrap.dedent("""
 def test_async_sharded_byte_identical_to_sync_1dev(dense_model, tmp_path):
     """8-device async twin (subprocess — device count locks at jax init):
     async dispatch in deterministic ready-order on the (8, 1) mesh is
-    byte-identical to this process's 1-device SYNCHRONOUS engine for
-    every combination of {slot, paged} × {single-step, fused} decode —
-    disaggregation, fusion, and sharding compose without perturbing
-    tokens."""
+    byte-identical to this process's 1-device SYNCHRONOUS engine at the
+    same block size, for every combination of {slot, paged} ×
+    {single-step, fused} decode — disaggregation, fusion, and sharding
+    compose without perturbing tokens.  (Arrivals land at outer-step
+    indices, so a fused run admits them at later rounds and co-folds
+    differently: block 4 is compared with sync block 4, and fused vs
+    single-step with identical schedules is gated by
+    ``test_fused_decode_token_exact``.)"""
     cfg, params = dense_model
     prompts = _prompts(cfg, lens=MESH_PROMPT_LENS)
-    local, _ = _serve_async_det(cfg, params, prompts, sync=True)
+    local = {blk: _serve_async_det(cfg, params, prompts, sync=True,
+                                   block=blk)[0] for blk in (1, 4)}
 
     out = tmp_path / "async_sharded.json"
     env = dict(os.environ,
@@ -753,8 +758,9 @@ def test_async_sharded_byte_identical_to_sync_1dev(dense_model, tmp_path):
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     got = json.load(open(out))
     assert got["ku_nshards"] == 8        # slot axis genuinely 8-way DP
-    for key in ("slot_b1", "slot_b4", "paged_b1", "paged_b4"):
-        assert {int(k): v for k, v in got[key].items()} == local, \
+    for key, blk in (("slot_b1", 1), ("slot_b4", 4), ("paged_b1", 1),
+                     ("paged_b4", 4)):
+        assert {int(k): v for k, v in got[key].items()} == local[blk], \
             f"8-device async {key} tokens diverged vs 1-device sync"
 
 
@@ -770,8 +776,9 @@ def test_async_sharded_inprocess_8dev(dense_model):
     cfg, params = dense_model
     mesh = make_host_mesh(8, 1)
     prompts = _prompts(cfg, lens=MESH_PROMPT_LENS)
-    base, _ = _serve_async_det(cfg, params, prompts, sync=True)
     for block in (1, 4):
+        base, _ = _serve_async_det(cfg, params, prompts, sync=True,
+                                   block=block)
         got, eng = _serve_async_det(cfg, params, prompts, mesh=mesh,
                                     block=block)
         assert got == base, f"8-device async block={block} diverged"

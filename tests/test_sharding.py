@@ -13,12 +13,12 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.configs import all_archs
 from repro.distributed import sharding as sh
 
-# AbstractMesh takes ((name, size), ...) pairs since jax 0.4.35
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+# AbstractMesh takes (axis_sizes, axis_names)
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 # host-platform serving meshes (8 forced devices)
-MESH8 = AbstractMesh((("data", 8), ("model", 1)))
-MESH8_2D = AbstractMesh((("data", 2), ("model", 4)))
+MESH8 = AbstractMesh((8, 1), ("data", "model"))
+MESH8_2D = AbstractMesh((2, 4), ("data", "model"))
 
 
 def test_dp_axes():

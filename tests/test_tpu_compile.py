@@ -1,0 +1,93 @@
+"""Compile the served-path re-orthogonalization kernels for a TPU v5e.
+
+Nothing runs: the TPU compiler compiles for a described (not attached)
+v5e chip, so these tests catch what interpret mode accepts and Mosaic
+refuses — blocks off the (8, 128) tiling, VMEM over the scoped limit, a
+program over the chip's HBM — at the shapes granite-3-2b's prefill
+launches.  The topology is described inside a module fixture (never at
+import): only one process may load the TPU library, and the suite runs
+under several pytest-xdist workers.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import get_arch
+from repro.engine.config import EngineConfig
+from repro.kernels import lanczos_reorth as LR
+from repro.kernels import ops
+
+GRANITE = get_arch("granite-3-2b")
+KVW = GRANITE.num_kv_heads * GRANITE.resolved_head_dim     # 512
+SLOTS, PROMPT, RANK = 8, 1024, 64
+K = RANK + EngineConfig().kv_iters_extra                    # Lanczos buffer
+LONG_PROMPT = 8192
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off for these tests
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile_reorth(sharding, side: str, batch: int, s: int, f: int):
+    s_pad, h_pad = ops.padded_dims(s, KVW, f)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    if side == "right":
+        fn, x, q = LR.reorth_right_batched, sds(batch, s_pad), \
+            sds(batch, h_pad, K)
+    else:
+        fn, x, q = LR.reorth_left_batched, sds(batch, h_pad), \
+            sds(batch, s_pad, K)
+    compiled = jax.jit(lambda a, x, q: fn(a, x, q, expansion=f,
+                                          interpret=False)).lower(
+        sds(batch, s_pad, h_pad), x, q).compile()
+    return compiled, (s_pad, h_pad)
+
+
+@pytest.mark.parametrize("f", [4, 8, 16])
+@pytest.mark.parametrize("side", ["right", "left"])
+def test_reorth_compiles_at_granite_serve_shape(one_chip, side, f):
+    """[40 layers x 8 slots, 1024-token bucket, 512] f32 — one admitted
+    batch's K (or V) through one Lanczos pass."""
+    batch = GRANITE.num_layers * SLOTS
+    compiled, (s_pad, h_pad) = _compile_reorth(one_chip, side, batch,
+                                               PROMPT, f)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert (h_pad // f) % LR.LANE == 0 and (s_pad // f) % LR.SUBLANE == 0
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * batch * s_pad * h_pad
+
+
+@pytest.mark.parametrize("side", ["right", "left"])
+def test_reorth_compiles_at_long_prompt(one_chip, side):
+    """An 8192-token prompt for one slot (40 layers): the A block is
+    (8192, 128) on the right step, so the VMEM request must stay under the
+    scoped cap."""
+    compiled, _ = _compile_reorth(one_chip, side, GRANITE.num_layers,
+                                  LONG_PROMPT, 8)
+    assert "tpu_custom_call" in compiled.as_text()
