@@ -33,7 +33,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import lanczos as lz
 from ..core import outlier as ol
-from ..obs import GLOBAL as _OBS, bucket_label
 from ..core.lowrank import LowRank, add_bias_rank, from_dense_svd
 from ..core.policy import LayerPolicy
 from ..core.preserved import (decompose_weight, lowrank_matmul,
@@ -186,15 +185,6 @@ class DecomposeEngine:
             z0 = _padded_z0(h_dim, h_pad)
         else:
             xp, z0 = x, None        # jitted core generates the same z0
-        # decomposition telemetry (DESIGN.md §13): one counter bump per
-        # decompose call, labeled with the pow2 shape bucket, the RESOLVED
-        # backend and expansion f, and which execution path ran.  Host-side
-        # only — the landscape of what actually decomposed, per process.
-        path = "sharded" if self.config.mesh is not None else "local"
-        _OBS.counter(
-            "decompose_total", "batched Lanczos decompositions",
-            bucket=bucket_label(max(1, batch), s_dim, h_dim),
-            backend=self.backend.name, f=str(f), path=path).inc()
         if self.config.mesh is not None:
             lr = self._decompose_sharded(xp, rank, iters, hooks, z0)
         else:
@@ -276,9 +266,6 @@ class DecomposeEngine:
         requested rank caps at min(T, kvw) — a factorization cannot carry
         more directions than the matrix has."""
         rank = min(rank, *x.shape[-2:])
-        _OBS.counter("decompose_kv_total", "KV-cache factorizations",
-                     mode="exact" if exact else "lanczos",
-                     bucket=bucket_label(*x.shape[-2:])).inc()
         if exact:
             lr = from_dense_svd(x.astype(jnp.float32), rank)
         else:

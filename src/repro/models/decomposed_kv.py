@@ -93,21 +93,27 @@ def prefill_dkv(p: Params, cfg, tokens: Array, rank: int,
     (Lanczos via the engine's backend; ``exact`` switches to direct SVD for
     r near full rank, where floating-point Lanczos loses trailing
     directions — §2.3: Lanczos is the small-rank algorithm).
+
+    The forward pass and the factorizations sit in the named scopes
+    ``dcom.forward`` and ``dcom.lanczos``: compiled HLO carries them in each
+    instruction's ``op_name``, so a device trace splits admission by them.
     """
     if rank < 1:
         raise ValueError(f"prefill_dkv needs rank >= 1, got {rank} "
                          "(is the engine's kv_rank configured?)")
     engine = engine or _DEFAULT_ENGINE
     b, s = tokens.shape
-    logits, dense_cache = T.prefill(p, cfg, tokens, s)
+    with jax.named_scope("dcom.forward"):
+        logits, dense_cache = T.prefill(p, cfg, tokens, s)
     kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
 
     def one(kv):
-        flat = kv.reshape(cfg.num_layers * b, s, kvh * hd)
-        u, vt = engine.decompose_kv(flat, rank, exact=exact)
-        r_eff = u.shape[-1]          # rank caps at min(s, kvw) (exact SVD)
-        return (u.reshape(cfg.num_layers, b, s, r_eff),
-                vt.reshape(cfg.num_layers, b, r_eff, kvh * hd))
+        with jax.named_scope("dcom.lanczos"):
+            flat = kv.reshape(cfg.num_layers * b, s, kvh * hd)
+            u, vt = engine.decompose_kv(flat, rank, exact=exact)
+            r_eff = u.shape[-1]      # rank caps at min(s, kvw) (exact SVD)
+            return (u.reshape(cfg.num_layers, b, s, r_eff),
+                    vt.reshape(cfg.num_layers, b, r_eff, kvh * hd))
 
     k_u, k_vt = one(dense_cache["k"])
     v_u, v_vt = one(dense_cache["v"])
@@ -349,34 +355,36 @@ def splice_dkv(live: Params, fresh: Params, slot_indices,
     Time and rank axes are zero-padded to the pairwise max first (zero U
     rows/columns and zero Vᵀ rows are inert), so a fresh short prefix can
     join a cache whose prefix has grown through tail folds, and vice
-    versa.
+    versa.  Its ops sit in the named scope ``dcom.splice``.
     """
-    idx = jnp.asarray(slot_indices, jnp.int32)      # traced-input friendly
-    src = jnp.arange(idx.shape[0], dtype=jnp.int32) \
-        if src_indices is None else jnp.asarray(src_indices, jnp.int32)
+    with jax.named_scope("dcom.splice"):
+        idx = jnp.asarray(slot_indices, jnp.int32)  # traced-input friendly
+        src = jnp.arange(idx.shape[0], dtype=jnp.int32) \
+            if src_indices is None \
+            else jnp.asarray(src_indices, jnp.int32)
 
-    def pad_to(a, axis, size):
-        if a.shape[axis] >= size:
-            return a
-        w = [(0, 0)] * a.ndim
-        w[axis] = (0, size - a.shape[axis])
-        return jnp.pad(a, w)
+        def pad_to(a, axis, size):
+            if a.shape[axis] >= size:
+                return a
+            w = [(0, 0)] * a.ndim
+            w[axis] = (0, size - a.shape[axis])
+            return jnp.pad(a, w)
 
-    t = max(live["k_u"].shape[2], fresh["k_u"].shape[2])
-    r = max(live["k_u"].shape[-1], fresh["k_u"].shape[-1])
-    out: Params = {}
-    for key in ("k_u", "v_u"):
-        old = pad_to(pad_to(live[key], 2, t), 3, r)
-        new = pad_to(pad_to(fresh[key], 2, t), 3, r)
-        out[key] = old.at[:, idx].set(new[:, src].astype(old.dtype))
-    for key in ("k_vt", "v_vt"):
-        old = pad_to(live[key], 2, r)
-        new = pad_to(fresh[key], 2, r)
-        out[key] = old.at[:, idx].set(new[:, src].astype(old.dtype))
-    out["tail"] = {k: live["tail"][k].at[:, idx].set(
-        fresh["tail"][k][:, src].astype(live["tail"][k].dtype))
-        for k in live["tail"]}
-    return out
+        t = max(live["k_u"].shape[2], fresh["k_u"].shape[2])
+        r = max(live["k_u"].shape[-1], fresh["k_u"].shape[-1])
+        out: Params = {}
+        for key in ("k_u", "v_u"):
+            old = pad_to(pad_to(live[key], 2, t), 3, r)
+            new = pad_to(pad_to(fresh[key], 2, t), 3, r)
+            out[key] = old.at[:, idx].set(new[:, src].astype(old.dtype))
+        for key in ("k_vt", "v_vt"):
+            old = pad_to(live[key], 2, r)
+            new = pad_to(fresh[key], 2, r)
+            out[key] = old.at[:, idx].set(new[:, src].astype(old.dtype))
+        out["tail"] = {k: live["tail"][k].at[:, idx].set(
+            fresh["tail"][k][:, src].astype(live["tail"][k].dtype))
+            for k in live["tail"]}
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -263,8 +263,8 @@ class MetricsRegistry:
         return out
 
 
-#: process-global registry: decomposition telemetry, tuner cache counters,
-#: and the jit compile-watch land here (they are not tied to one serving
+#: process-global registry: tuner cache counters and the jit compile-watch
+#: land here (they are not tied to one serving
 #: engine); per-engine serving stats live in each EngineStats' registry.
 GLOBAL = MetricsRegistry()
 

@@ -6,8 +6,12 @@ tracing.  Tracks (one ``tid`` each, named via ``thread_name`` metadata
 events) separate the concurrent stories serving interleaves:
 
 * ``engine``   — the step loop: ``step`` spans containing ``admit`` /
-  ``decode-block`` / ``fold`` / ``drain-pool`` children (nesting is time
-  containment on one tid, which is exactly how Perfetto renders it);
+  ``decode-block`` / ``fold`` / ``drain-pool`` children, and those the
+  phases between device programs (``admit.prepare``, ``admit.launch``,
+  ``splice``, ``admit.first_token``, ``admit.activate``;
+  ``decode.prepare``, ``decode.launch``, ``decode.readback``,
+  ``decode.deliver``).  Nesting is time containment on one tid, which is
+  exactly how Perfetto renders it;
 * ``tickets``  — in-flight async ``PrefillTicket``s (dispatch → splice),
   on their own track so the P/D overlap is visible as spans running UNDER
   the engine's decode spans;
@@ -16,11 +20,18 @@ events) separate the concurrent stories serving interleaves:
   ``prefill`` (dispatch → first token) and ``decode`` (first → last
   token) child spans.
 
-Everything is plain Python list-append on the host — a disabled tracer
-(the default) reduces every call to one attribute check and shared no-op
-objects, and an enabled tracer never touches device state, so tokens are
-byte-identical either way (the §13 zero-device-op rule; conformance-gated
-in tests/test_serving_conformance.py).
+Every ``engine``-track span is also a ``jax.profiler.TraceAnnotation``
+named ``engine.<span>``, enabled tracer or not, so a profiler trace holds
+the step loop's phases on the device trace's clock: an idle gap on the
+device falls inside the host phase that left it idle.  While no profiler
+trace is active that costs one ``TraceAnnotation.is_enabled()`` check per
+span.  The other tracks overlap across steps and stay in the JSON only.
+
+Everything else is plain Python list-append on the host — a disabled
+tracer (the default) reduces every call to one attribute check and shared
+no-op objects, and an enabled tracer never touches device state, so
+tokens are byte-identical either way (the §13 zero-device-op rule;
+conformance-gated in tests/test_serving_conformance.py).
 
 The module also owns the PHASE stack used to attribute jit recompiles:
 ``phase_scope("decode")`` marks host-side sections that launch device
@@ -34,20 +45,37 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+#: the track whose spans also go into the profiler's trace
+ENGINE_TRACK = "engine"
+
+
+def _annotation(name: str, track: str) -> Optional[TraceAnnotation]:
+    """The open ``engine.<name>`` profiler annotation of an engine-track
+    span; None on other tracks and while no profiler trace is active."""
+    if track != ENGINE_TRACK or not TraceAnnotation.is_enabled():
+        return None
+    ann = TraceAnnotation(f"engine.{name}")
+    ann.__enter__()
+    return ann
+
 
 class Span:
     """One open interval on a track; ``end()`` records the event."""
 
-    __slots__ = ("tracer", "name", "track", "args", "t0", "_done")
+    __slots__ = ("tracer", "name", "track", "args", "t0", "_done", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str,
-                 args: Optional[Dict[str, Any]] = None):
+                 args: Optional[Dict[str, Any]] = None,
+                 ann: Optional[TraceAnnotation] = None):
         self.tracer = tracer
         self.name = name
         self.track = track
         self.args = dict(args or {})
         self.t0 = time.perf_counter()
         self._done = False
+        self._ann = ann
 
     def annotate(self, **kw) -> "Span":
         self.args.update(kw)
@@ -57,6 +85,8 @@ class Span:
         if self._done:                    # idempotent: double-end is a no-op
             return
         self._done = True
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if kw:
             self.args.update(kw)
         self.tracer._record(self)
@@ -89,6 +119,24 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _AnnotationSpan(_NullSpan):
+    """An engine-track span of a disabled tracer while a profiler trace is
+    active: the profiler annotation alone, closed once by ``end()``."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann: TraceAnnotation):
+        self._ann = ann
+
+    def end(self, **kw) -> None:
+        if self._ann is not None:
+            ann, self._ann = self._ann, None
+            ann.__exit__(None, None, None)
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
 class Tracer:
     """Span recorder.  ``enabled=False`` (the default engine state) makes
     ``begin``/``span``/``instant`` constant-time no-ops."""
@@ -104,17 +152,19 @@ class Tracer:
         self._tids: Dict[str, int] = {}
 
     # -- recording --------------------------------------------------------
-    def begin(self, name: str, track: str = "engine",
+    def begin(self, name: str, track: str = ENGINE_TRACK,
               args: Optional[Dict[str, Any]] = None):
         """Open a span; the caller ends it (possibly in another scope —
-        request-lifecycle spans end steps later than they begin)."""
+        request-lifecycle spans end steps later than they begin).  An
+        engine-track span must end before the span that encloses it."""
+        ann = _annotation(name, track)
         if not self.enabled:
-            return NULL_SPAN
-        return Span(self, name, track, args)
+            return NULL_SPAN if ann is None else _AnnotationSpan(ann)
+        return Span(self, name, track, args, ann)
 
     span = begin                          # context-manager idiom: with t.span(..)
 
-    def instant(self, name: str, track: str = "engine",
+    def instant(self, name: str, track: str = ENGINE_TRACK,
                 args: Optional[Dict[str, Any]] = None) -> None:
         if not self.enabled:
             return

@@ -13,7 +13,6 @@ Counters land in the GLOBAL registry:
 
 * ``jit_compiles_total{phase=…}``        — backend compiles
 * ``jit_compile_seconds_total{phase=…}`` — wall time inside XLA
-* ``jit_traces_total{phase=…}``          — jaxpr traces (cheaper, noisier)
 
 ``install_compile_watch`` is idempotent; the listener stays registered
 for the life of the process (jax has no per-listener removal).
@@ -24,7 +23,6 @@ from .registry import GLOBAL, MetricsRegistry
 from .trace import current_phase
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 
 _installed = False
 
@@ -51,10 +49,6 @@ def install_compile_watch(registry: MetricsRegistry = GLOBAL) -> bool:
                 "jit_compile_seconds_total",
                 "wall seconds spent in XLA backend compiles",
                 phase=phase).add(float(duration))
-        elif event == _TRACE_EVENT:
-            registry.counter(
-                "jit_traces_total", "jaxpr traces",
-                phase=current_phase()).inc()
 
     monitoring.register_event_duration_secs_listener(on_duration)
     _installed = True

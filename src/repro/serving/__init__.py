@@ -136,6 +136,9 @@ from .families import (PrefillTicket, ServingFamily,  # noqa: F401
 
 Array = jax.Array
 
+#: the engine span of a host sampling readback, by sample stream
+_READBACK_SPAN = {0: "decode.readback", 1: "admit.first_token"}
+
 
 def greedy_sampler(logits: Array, k: int) -> Array:
     """Default sampler: argmax over the vocab axis.  Module-level (not a
@@ -238,6 +241,13 @@ class EngineStats:
         for name, metric, help_ in self._HISTS:
             self._m[name] = LatencySeries(
                 self.registry.histogram(f"serving_{metric}", help_))
+        # entries of the prefill token matrices launched: real prompt
+        # tokens, and bucket and batch padding (``Engine._count_prefill``)
+        self.prefill_tokens = {
+            kind: self.registry.counter(
+                "serving_prefill_tokens_total",
+                "prefill token-matrix entries launched", kind=kind)
+            for kind in ("prompt", "pad")}
 
     def __repr__(self) -> str:
         return (f"EngineStats(prefills={self.prefills}, "
@@ -524,8 +534,7 @@ class Engine:
         ``step()``-driven callers (benchmarks, the serve CLI loop) get the
         same tok/s accounting as ``run()``."""
         t0 = time.perf_counter()
-        step_span = self.trace.begin("step", "engine",
-                                     {"round": self._round})
+        step_span = self.trace.begin("step", args={"round": self._round})
         finished: List[Request] = []
         try:
             if self._pool:
@@ -536,7 +545,7 @@ class Engine:
                 finished.extend(self._drain_pool(
                     block=not any(r is not None for r in self.live)))
             if self._round % self.admit_every == 0 or not self._occupied():
-                with self.trace.span("admit", "engine"):
+                with self.trace.span("admit"):
                     finished.extend(self._admit())
             if any(self.live):
                 finished.extend(self._decode_rounds())
@@ -575,11 +584,12 @@ class Engine:
         block interleaving draw the same tokens."""
         # the ONE sanctioned device→host sync in the engine: emitted
         # tokens must land in host lists, so the readback is the point
-        if getattr(self.sampler, "takes_key", False):
-            k = jax.random.fold_in(jax.random.fold_in(self._key, stream),
-                                   self._round)
-            return np.asarray(self.sampler(logits, 1, k))  # dcomlint: disable=J2
-        return np.asarray(self.sampler(logits, 1))  # dcomlint: disable=J2
+        with self.trace.span(_READBACK_SPAN[stream]):
+            if getattr(self.sampler, "takes_key", False):
+                k = jax.random.fold_in(
+                    jax.random.fold_in(self._key, stream), self._round)
+                return np.asarray(self.sampler(logits, 1, k))  # dcomlint: disable=J2
+            return np.asarray(self.sampler(logits, 1))  # dcomlint: disable=J2
 
     def _stops(self, req: Request) -> frozenset:
         eos = req.eos_id if req.eos_id is not None else self.eos_id
@@ -638,20 +648,21 @@ class Engine:
                 # legacy gang restriction, kept only for the A/B benchmark:
                 # splice-merge used to exist for the dense-cache path only
                 break
-            batch = self.sched.next_batch(len(free))
-            if not batch:
-                break
-            maxp = max(len(r.prompt) for r in batch)
-            plen = self.sched.bucket_of(maxp)
-            if plen >= self.max_len:
-                # bucket rounds past the cache: fall back to the exact
-                # length (one extra jit shape near the cap beats losing
-                # decode room)
-                plen = maxp
-            # family capacity check (paged: prefix lookups + page
-            # reservation — hit refs already held inside ctx); None
-            # defers the batch until in-flight work frees resources
-            ctx = self.family.reserve(batch, plen)
+            with self.trace.span("admit.prepare"):
+                batch = self.sched.next_batch(len(free))
+                if not batch:
+                    break
+                maxp = max(len(r.prompt) for r in batch)
+                plen = self.sched.bucket_of(maxp)
+                if plen >= self.max_len:
+                    # bucket rounds past the cache: fall back to the
+                    # exact length (one extra jit shape near the cap
+                    # beats losing decode room)
+                    plen = maxp
+                # family capacity check (paged: prefix lookups + page
+                # reservation — hit refs already held inside ctx); None
+                # defers the batch until in-flight work frees resources
+                ctx = self.family.reserve(batch, plen)
             if ctx is None:
                 self.sched.requeue(batch)
                 self.stats.stalls += 1
@@ -724,30 +735,30 @@ class Engine:
         apply first-token stop checks."""
         now = time.perf_counter()
         finished: List[Request] = []
-        for j, (slot, req) in enumerate(zip(slots_idx, batch)):
-            self._reserved[slot] = False
-            self.live[slot] = req
-            self.pos[slot] = plen
-            self.frozen_len[slot] = fls[j]
-            req.out_tokens.append(int(nxt[j]))
-            req.t_first = req.t_last = now
-            spans = self._req_spans.get(req.uid)
-            if spans:
-                spans["prefill"].end(slot=slot)
-                spans["decode"] = self.trace.begin("decode",
-                                                   f"req/{req.uid}")
-            self.stats.ttft_s.append(now - req.t_submit)
-            self.stats.ttft_queue_s.append(req.t_dispatch - req.t_submit)
-            self.stats.ttft_compute_s.append(now - req.t_dispatch)
-            # the FIRST token can already be a stop token (or the whole
-            # budget): finish and free the slot immediately
-            if self._check_stop(slot, req, now):
-                finished.append(req)
+        with self.trace.span("admit.activate"):
+            for j, (slot, req) in enumerate(zip(slots_idx, batch)):
+                self._reserved[slot] = False
+                self.live[slot] = req
+                self.pos[slot] = plen
+                self.frozen_len[slot] = fls[j]
+                req.out_tokens.append(int(nxt[j]))
+                req.t_first = req.t_last = now
+                spans = self._req_spans.get(req.uid)
+                if spans:
+                    spans["prefill"].end(slot=slot)
+                    spans["decode"] = self.trace.begin("decode",
+                                                       f"req/{req.uid}")
+                self.stats.ttft_s.append(now - req.t_submit)
+                self.stats.ttft_queue_s.append(req.t_dispatch - req.t_submit)
+                self.stats.ttft_compute_s.append(now - req.t_dispatch)
+                # the FIRST token can already be a stop token (or the whole
+                # budget): finish and free the slot immediately
+                if self._check_stop(slot, req, now):
+                    finished.append(req)
         return finished
 
     def _finish_ticket(self, t: PrefillTicket) -> List[Request]:
-        with self.trace.span("splice", "engine",
-                             {"requests": len(t.requests)}), \
+        with self.trace.span("splice", args={"requests": len(t.requests)}), \
                 phase_scope("splice"):
             nxt, fls = t.complete()
         if t.span is not None:
@@ -766,8 +777,8 @@ class Engine:
         finished: List[Request] = []
         rest: List[PrefillTicket] = []
         spliced = 0
-        with self.trace.span("drain-pool", "engine",
-                             {"pool": len(self._pool)}) as dspan:
+        with self.trace.span("drain-pool",
+                             args={"pool": len(self._pool)}) as dspan:
             for t in self._pool:
                 if (block and not spliced and not rest) or t.ready():
                     finished.extend(self._finish_ticket(t))
@@ -819,12 +830,29 @@ class Engine:
         self._pool = []
         return n
 
-    def _toks(self, batch: List[Request], rows: int, plen: int,
-              row_of: Callable[[int], int]) -> np.ndarray:
+    @staticmethod
+    def _padded(batch: List[Request], rows: int, plen: int,
+                row_of: Callable[[int], int]) -> np.ndarray:
+        """``[rows, plen]`` int32: each prompt left-padded with 0 in row
+        ``row_of(j)``; the other rows all padding."""
         toks = np.zeros((rows, plen), np.int32)
         for j, req in enumerate(batch):
             toks[row_of(j), plen - len(req.prompt):] = req.prompt  # left-pad
         return toks
+
+    def _toks(self, batch: List[Request], rows: int, plen: int,
+              row_of: Callable[[int], int]) -> np.ndarray:
+        """The token matrix of one prefill launch (``_padded``), counted
+        as launched."""
+        toks = self._padded(batch, rows, plen, row_of)
+        self._count_prefill(toks, sum(len(r.prompt) for r in batch))
+        return toks
+
+    def _count_prefill(self, toks: np.ndarray, real: int) -> None:
+        """Count a launched prefill token matrix holding ``real`` prompt
+        tokens: the rest of its entries are bucket and batch padding."""
+        self.stats.prefill_tokens["prompt"].inc(real)
+        self.stats.prefill_tokens["pad"].inc(toks.size - real)
 
     def _last_tokens(self) -> np.ndarray:
         tok = np.zeros((self.slots,), np.int32)
@@ -847,28 +875,32 @@ class Engine:
         return self._decode_block_round()
 
     def _decode_round(self) -> List[Request]:
-        tok = self._last_tokens()
-        with self.trace.span("decode-step", "engine"), \
-                phase_scope("decode"):
-            logits = self.family.decode(tok)
-            nxt = self._sample_host(logits)
-        self.stats.decode_steps += 1
-        self.stats.blocks += 1
-        now = time.perf_counter()
-        done: List[Request] = []
-        for i, req in enumerate(self.live):
-            if req is None:
-                continue
-            self.pos[i] += 1
-            req.out_tokens.append(int(nxt[i]))
-            self.stats.tokens_out += 1
-            self.stats.itl_s.append(now - req.t_last)
-            req.t_last = now
-            # EOS / stop tokens end a request the moment they are emitted
-            # (the old loop only stopped on budget or cache exhaustion,
-            # so every request burned its full max_new_tokens)
-            if self._check_stop(i, req, now):
-                done.append(req)
+        with self.trace.span("decode-step"):
+            with self.trace.span("decode.prepare"):
+                tok = self._last_tokens()
+            with phase_scope("decode"):
+                with self.trace.span("decode.launch"):
+                    logits = self.family.decode(tok)
+                nxt = self._sample_host(logits)
+            self.stats.decode_steps += 1
+            self.stats.blocks += 1
+            now = time.perf_counter()
+            done: List[Request] = []
+            with self.trace.span("decode.deliver"):
+                for i, req in enumerate(self.live):
+                    if req is None:
+                        continue
+                    self.pos[i] += 1
+                    req.out_tokens.append(int(nxt[i]))
+                    self.stats.tokens_out += 1
+                    self.stats.itl_s.append(now - req.t_last)
+                    req.t_last = now
+                    # EOS / stop tokens end a request the moment they are
+                    # emitted (the old loop only stopped on budget or
+                    # cache exhaustion, so every request burned its full
+                    # max_new_tokens)
+                    if self._check_stop(i, req, now):
+                        done.append(req)
         return done
 
     # -- fused block decode ------------------------------------------------
@@ -920,39 +952,44 @@ class Engine:
         return tbl
 
     def _decode_block_round(self) -> List[Request]:
-        blk = self._block_len()
-        tok = self._last_tokens()
-        stops = np.asarray(self._stop_table())
-        key = jax.random.fold_in(self._key, 0)      # decode sample stream
-        n, r0 = np.int32(blk), np.int32(self._round)
-        t0 = time.perf_counter()
-        bspan = self.trace.begin("decode-block", "engine", {"max_steps": blk})
-        with phase_scope("decode"):
-            buf, steps = self.family.decode_block(tok, n, stops, key, r0)
-            steps = int(steps)
-            toks = np.asarray(buf)[:steps]          # [steps, slots], syncs
-        bspan.end(steps=steps)
-        now = time.perf_counter()
-        # ITL under block decode: one wall measurement per LAUNCH,
-        # attributed wall/steps per token (the per-round "now − t_last"
-        # stamp would collapse to ~0 for all but the first token of a
-        # block and overstate the first)
-        per_tok = (now - t0) / max(steps, 1)
-        self.stats.decode_steps += steps
-        self.stats.blocks += 1
-        self._round += steps
-        done: List[Request] = []
-        for i, req in enumerate(self.live):
-            if req is None:
-                continue
-            req.out_tokens.extend(int(t) for t in toks[:, i])
-            self.pos[i] += steps
-            self.stats.tokens_out += steps
-            self.stats.itl_s.extend([per_tok] * steps)
-            req.t_last = now
-            # stops can only sit on the block's LAST step (early exit),
-            # so the boundary check sees exactly what the single-step
-            # engine's per-round check would have
-            if self._check_stop(i, req, now):
-                done.append(req)
+        with self.trace.span("decode-block") as bspan:
+            with self.trace.span("decode.prepare"):
+                blk = self._block_len()
+                tok = self._last_tokens()
+                stops = np.asarray(self._stop_table())
+                key = jax.random.fold_in(self._key, 0)  # decode stream
+                n, r0 = np.int32(blk), np.int32(self._round)
+            with phase_scope("decode"):
+                with self.trace.span("decode.launch"):
+                    buf, steps = self.family.decode_block(tok, n, stops,
+                                                          key, r0)
+                with self.trace.span("decode.readback"):
+                    steps = int(steps)
+                    toks = np.asarray(buf)[:steps]  # [steps, slots], syncs
+            bspan.annotate(max_steps=blk, steps=steps)
+            now = time.perf_counter()
+            self.stats.decode_steps += steps
+            self.stats.blocks += 1
+            self._round += steps
+            done: List[Request] = []
+            with self.trace.span("decode.deliver"):
+                for i, req in enumerate(self.live):
+                    if req is None:
+                        continue
+                    req.out_tokens.extend(int(t) for t in toks[:, i])
+                    self.pos[i] += steps
+                    self.stats.tokens_out += steps
+                    # ITL under block decode: the time since the slot's
+                    # previous token, host time between launches included,
+                    # spread evenly over the block's tokens (a per-round
+                    # stamp would collapse to ~0 for all but the first
+                    # token of a block and overstate the first)
+                    self.stats.itl_s.extend(
+                        [(now - req.t_last) / max(steps, 1)] * steps)
+                    req.t_last = now
+                    # stops can only sit on the block's LAST step (early
+                    # exit), so the boundary check sees exactly what the
+                    # single-step engine's per-round check would have
+                    if self._check_stop(i, req, now):
+                        done.append(req)
         return done

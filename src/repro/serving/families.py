@@ -356,10 +356,13 @@ class ServingFamily:
         ``complete()`` (ready-pool splice for async, immediately for
         sync)."""
         eng = self.eng
-        nb = min(_pow2(len(batch)), max(eng.slots, 1))
-        toks = eng._toks(batch, nb, plen, lambda j: j)
-        args = eng.fns.prefill_inputs(eng.cfg, jnp.asarray(toks), jnp.zeros)
-        logits, fresh = self._prefill(eng.params, *args)
+        with eng.trace.span("admit.prepare"):
+            nb = min(_pow2(len(batch)), max(eng.slots, 1))
+            toks = eng._toks(batch, nb, plen, lambda j: j)
+            args = eng.fns.prefill_inputs(eng.cfg, jnp.asarray(toks),
+                                          jnp.zeros)
+        with eng.trace.span("admit.launch"):
+            logits, fresh = self._prefill(eng.params, *args)
         eng.stats.prefill_batches += 1
 
         def complete():
@@ -382,9 +385,12 @@ class ServingFamily:
         family supports it, replace the cache wholesale otherwise (all
         slots are free by the gang restriction)."""
         eng = self.eng
-        toks = eng._toks(batch, eng.slots, plen, lambda j: slots_idx[j])
-        args = eng.fns.prefill_inputs(eng.cfg, jnp.asarray(toks), jnp.zeros)
-        logits, cache = self._prefill(eng.params, *args)
+        with eng.trace.span("admit.prepare"):
+            toks = eng._toks(batch, eng.slots, plen, lambda j: slots_idx[j])
+            args = eng.fns.prefill_inputs(eng.cfg, jnp.asarray(toks),
+                                          jnp.zeros)
+        with eng.trace.span("admit.launch"):
+            logits, cache = self._prefill(eng.params, *args)
         if has_live:
             idx = np.asarray(slots_idx, np.int32)
             cache = self._splice_fam(eng.cache, cache, idx, idx, eng.cfg)
@@ -636,9 +642,11 @@ class TransformerDKVServing(ServingFamily):
         """Launch the slab-path dkv prefill (Lanczos included) for one
         admission batch and return its ticket."""
         eng = self.eng
-        nb = min(_pow2(len(batch)), max(eng.slots, 1))
-        toks = eng._toks(batch, nb, plen, lambda j: j)
-        logits, fresh = self._prefill_dkv(eng.params, np.asarray(toks))
+        with eng.trace.span("admit.prepare"):
+            nb = min(_pow2(len(batch)), max(eng.slots, 1))
+            toks = eng._toks(batch, nb, plen, lambda j: j)
+        with eng.trace.span("admit.launch"):
+            logits, fresh = self._prefill_dkv(eng.params, np.asarray(toks))
         eng.stats.prefill_batches += 1
 
         def complete():
@@ -679,7 +687,7 @@ class TransformerDKVServing(ServingFamily):
         eng = self.eng
         pg = eng.pager
         n = len(batch)
-        padded = eng._toks(batch, n, plen, lambda j: j)
+        padded = eng._padded(batch, n, plen, lambda j: j)
         hits: dict = {}            # (L, r_eff) -> [(j, entry, share), ...]
         misses: List[int] = []
         for j in range(n):
@@ -717,33 +725,39 @@ class TransformerDKVServing(ServingFamily):
                              r_ent: int, group: list) -> PrefillTicket:
         eng = self.eng
         pg = eng.pager
-        m = len(group)
-        stoks = np.zeros((m, plen - match_len), np.int32)
-        ent_bt, bt_t, idx = [], [], []
-        reqs: List[Any] = []
-        slots_l: List[int] = []
-        shares: List[list] = []
-        for gi, (j, ent, share) in enumerate(group):
-            slot = slots_idx[j]
-            stoks[gi] = padded[j][match_len:]
-            tpages = pg.talloc.alloc(pg.ntp)
-            assert tpages is not None, "tail pages after _reserve_pages"
-            ent_bt.append(share)
-            shares.append(list(share))
-            bt_t.append(tpages)
-            idx.append(slot)
-            reqs.append(batch[j])
-            slots_l.append(slot)
-        k_vt = jnp.stack([ent.k_vt for _, ent, _ in group], axis=1)
-        v_vt = jnp.stack([ent.v_vt for _, ent, _ in group], axis=1)
-        start = np.full(m, match_len, np.int32)
-        slen = np.full(m, plen - match_len, np.int32)
-        logits, pg.cache = pg._suffix(
-            eng.params, np.asarray(stoks), pg.cache,
-            np.asarray(ent_bt, np.int32), k_vt, v_vt,
-            np.asarray(start), np.asarray(slen),
-            np.asarray(bt_t, np.int32), np.asarray(idx, np.int32),
-            match_len, r_ent)
+        with eng.trace.span("admit.prepare"):
+            m = len(group)
+            stoks = np.zeros((m, plen - match_len), np.int32)
+            ent_bt, bt_t, idx = [], [], []
+            reqs: List[Any] = []
+            slots_l: List[int] = []
+            shares: List[list] = []
+            for gi, (j, ent, share) in enumerate(group):
+                slot = slots_idx[j]
+                stoks[gi] = padded[j][match_len:]
+                tpages = pg.talloc.alloc(pg.ntp)
+                assert tpages is not None, "tail pages after _reserve_pages"
+                ent_bt.append(share)
+                shares.append(list(share))
+                bt_t.append(tpages)
+                idx.append(slot)
+                reqs.append(batch[j])
+                slots_l.append(slot)
+            # the suffix holds a prompt's last min(len, plen − match_len)
+            # tokens; the rest of its row is left padding
+            eng._count_prefill(stoks, sum(
+                min(len(r.prompt), plen - match_len) for r in reqs))
+            k_vt = jnp.stack([ent.k_vt for _, ent, _ in group], axis=1)
+            v_vt = jnp.stack([ent.v_vt for _, ent, _ in group], axis=1)
+            start = np.full(m, match_len, np.int32)
+            slen = np.full(m, plen - match_len, np.int32)
+        with eng.trace.span("admit.launch"):
+            logits, pg.cache = pg._suffix(
+                eng.params, np.asarray(stoks), pg.cache,
+                np.asarray(ent_bt, np.int32), k_vt, v_vt,
+                np.asarray(start), np.asarray(slen),
+                np.asarray(bt_t, np.int32), np.asarray(idx, np.int32),
+                match_len, r_ent)
         eng.stats.prefill_batches += 1
 
         def complete():
@@ -781,11 +795,15 @@ class TransformerDKVServing(ServingFamily):
                              misses: List[int]) -> PrefillTicket:
         eng = self.eng
         pg = eng.pager
-        nb = min(_pow2(len(misses)), max(eng.slots, 1))
-        mtoks = np.zeros((nb, plen), np.int32)
-        for mi, j in enumerate(misses):
-            mtoks[mi] = padded[j]
-        logits, fresh = self._prefill_dkv(eng.params, np.asarray(mtoks))
+        with eng.trace.span("admit.prepare"):
+            nb = min(_pow2(len(misses)), max(eng.slots, 1))
+            mtoks = np.zeros((nb, plen), np.int32)
+            for mi, j in enumerate(misses):
+                mtoks[mi] = padded[j]
+            eng._count_prefill(mtoks,
+                               sum(len(batch[j].prompt) for j in misses))
+        with eng.trace.span("admit.launch"):
+            logits, fresh = self._prefill_dkv(eng.params, np.asarray(mtoks))
         eng.stats.prefill_batches += 1
         npg = pg.pages_for(plen)
         bt_u, bt_t, idx = [], [], []
@@ -845,9 +863,11 @@ class TransformerDKVServing(ServingFamily):
     def gang(self, batch: List[Any], slots_idx: List[int], plen: int,
              has_live: bool) -> Array:
         eng = self.eng
-        toks = eng._toks(batch, eng.slots, plen, lambda j: slots_idx[j])
-        logits, eng.cache = self._prefill_dkv(eng.params,
-                                              np.asarray(toks))
+        with eng.trace.span("admit.prepare"):
+            toks = eng._toks(batch, eng.slots, plen, lambda j: slots_idx[j])
+        with eng.trace.span("admit.launch"):
+            logits, eng.cache = self._prefill_dkv(eng.params,
+                                                  np.asarray(toks))
         eng.rank_eff[slots_idx] = eng.cache["k_u"].shape[-1]
         return logits
 

@@ -151,6 +151,35 @@ def test_tracer_spans_nest_and_validate(tmp_path):
     assert validate_trace(p.read_text()) == 3
 
 
+def test_engine_spans_are_profiler_annotations(tmp_path):
+    """Engine-track spans, enabled tracer or not, are written into an
+    active profiler trace as ``engine.<name>`` host events, nested as
+    they were opened; other tracks are not."""
+    import glob
+
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for t in (Tracer(enabled=False), Tracer(enabled=True)):
+            with t.span("step"):
+                with t.span("admit.launch"):
+                    t.begin("queue", "req/7").end()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    got = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+           for plane in jax.profiler.ProfileData.from_file(path).planes
+           for line in plane.lines for e in line.events
+           if e.name.startswith("engine.") or e.name == "queue"]
+    names = sorted(n for n, _, _ in got)
+    assert names == ["engine.admit.launch"] * 2 + ["engine.step"] * 2
+    steps = [g for g in got if g[0] == "engine.step"]
+    for _, a, b in (g for g in got if g[0] == "engine.admit.launch"):
+        assert any(s <= a and b <= e for _, s, e in steps)
+    # no profiler trace active: a disabled tracer hands out the no-op span
+    assert Tracer(enabled=False).begin("step") is NULL_SPAN
+
+
 def test_tracer_event_cap():
     t = Tracer(enabled=True, max_events=3)
     for k in range(10):

@@ -1,6 +1,7 @@
 """Serving engine: continuous batching, slot reuse, stats."""
 import jax
 import numpy as np
+import pytest
 
 from repro.configs import all_archs
 from repro.models import model_fns
@@ -300,3 +301,61 @@ def test_engine_buckets_on_family_prefill_cost():
     b = eng.sched.bucket_of
     assert b(eng.sched.cost(Request(uid=1, prompt=np.zeros(7, np.int32)))) \
         != b(eng.sched.cost(Request(uid=2, prompt=np.zeros(20, np.int32))))
+
+
+@pytest.mark.parametrize("mode", ["slab", "paged", "gang"])
+def test_prefill_token_counters_count_the_launched_matrix(mode):
+    """Prompts of 5, 9 and 12 tokens at bucket 16 in one admission: a
+    power-of-two batch of 4 rows of 16, so 26 prompt tokens and 38 of
+    padding, on every path that builds the matrix."""
+    from repro.engine import DecomposeEngine, EngineConfig
+    cfg = all_archs()["deepseek-7b"].reduced()
+    params = model_fns(cfg).init(jax.random.PRNGKey(0), cfg)
+    de = DecomposeEngine(EngineConfig(kv_rank=4, kv_tail=8, sched_bucket=16,
+                                      kv_page=8))
+    eng = Engine(cfg, params, slots=4, max_len=48, decompose_engine=de,
+                 paged=mode == "paged",
+                 admission="gang" if mode == "gang" else "per_slot")
+    rng = np.random.RandomState(0)
+    for i, n in enumerate((5, 9, 12)):
+        eng.submit(Request(uid=i, prompt=rng.randint(1, cfg.vocab, n,
+                                                     dtype=np.int32),
+                           max_new_tokens=2))
+    eng.run()
+    assert eng.stats.prefill_batches == 1
+    got = {m.labels["kind"]: m.value for m in eng.obs.registry.metrics()
+           if m.name == "serving_prefill_tokens_total"}
+    assert got == {"prompt": 26, "pad": 38}
+
+
+def test_block_itl_counts_host_time_between_launches(monkeypatch):
+    """Under block decode a token's ITL is the time since its slot's
+    previous token over the block's steps, so host time between launches
+    counts: each request's ITL samples add up to its first-to-last token
+    span, slowed host included."""
+    import time
+    cfg = all_archs()["deepseek-7b"].reduced()
+    params = model_fns(cfg).init(jax.random.PRNGKey(0), cfg)
+    eng = Engine(cfg, params, slots=2, max_len=64, decompose_kv_rank=4,
+                 dkv_tail=16, decode_block=4)
+    slow = 0.05
+    fold = eng.family.maybe_fold
+
+    def slowed():                    # the host, between two launches
+        time.sleep(slow)
+        fold()
+
+    monkeypatch.setattr(eng.family, "maybe_fold", slowed)
+    rng = np.random.RandomState(0)
+    reqs = [Request(uid=i, prompt=rng.randint(1, cfg.vocab, 8,
+                                              dtype=np.int32),
+                    max_new_tokens=9) for i in range(2)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    s = eng.stats
+    assert len(s.itl_s) == s.tokens_out == 2 * 8
+    assert s.itl_s.hist.sum == pytest.approx(
+        sum(r.t_last - r.t_first for r in reqs))
+    # two blocks of 4 per request, each behind one slowed boundary
+    assert s.itl_s.hist.sum >= 2 * 2 * slow
