@@ -22,7 +22,11 @@ The p1/p2 accumulators are tiny [1, k] VMEM scratch — the paper's "small
 global memory for broadcast purposes".  z lives in the output block, which
 stays resident in VMEM across all 3·f steps of one batch element (its block
 index only moves with the batch), so the intermediate never round-trips
-through HBM.
+through HBM.  Only pass 0 reads A, so A's block index (:func:`a_block_index`)
+walks the f blocks in pass 0 and then holds at the last one: the pipeline
+copies a block only when its index changes, so each A block moves HBM→VMEM
+once per batch element, and block f−1 stays resident through passes 1–2.
+Q is read in every pass and is fetched in each.
 
 Two symmetric variants share one kernel body:
 * ``right``: z = CGS2(Aᵀu, V) — output over columns of A (length H),
@@ -121,6 +125,19 @@ def _vmem_limit(a_blk: int, x_len: int, q_blk: int, k: int, out_len: int
     return max(need, _VMEM_DEFAULT)
 
 
+def a_block_index(right: bool, f: int):
+    """A's index map over the grid (b, p, j): block j in pass 0, then block
+    f−1 (the one pass 0 leaves resident) in passes 1–2, which never read A,
+    so the pipeline issues no copy of A after pass 0."""
+    last = f - 1
+
+    def index(b, p, j):
+        blk = jnp.where(p == 0, j, last)
+        return (b, 0, blk) if right else (b, blk, 0)
+
+    return index
+
+
 def _launch(a, x, q, *, right: bool, expansion: int, interpret: bool):
     """One pallas_call over the batch: returns (z [B, n], ‖z‖² [B])."""
     b_dim, s_dim, h_dim = a.shape
@@ -135,12 +152,9 @@ def _launch(a, x, q, *, right: bool, expansion: int, interpret: bool):
         raise ValueError(f"compiled re-orth needs {n}/{f} = {blk} to be a "
                          f"multiple of {tile}; pad through "
                          "kernels.ops.padded_dims")
-    if right:
-        a_spec = pl.BlockSpec((1, s_dim, blk), lambda b, p, j: (b, 0, j))
-        x_len = s_dim
-    else:
-        a_spec = pl.BlockSpec((1, blk, h_dim), lambda b, p, j: (b, j, 0))
-        x_len = h_dim
+    a_blk = (1, s_dim, blk) if right else (1, blk, h_dim)
+    a_spec = pl.BlockSpec(a_blk, a_block_index(right, f))
+    x_len = s_dim if right else h_dim
     params = None if interpret else pltpu.CompilerParams(
         vmem_limit_bytes=_vmem_limit(s_dim * h_dim // f, x_len, blk, k, n))
     z, nrm = pl.pallas_call(

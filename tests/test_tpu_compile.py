@@ -3,10 +3,10 @@
 Nothing runs: the TPU compiler compiles for a described (not attached)
 v5e chip, so these tests catch what interpret mode accepts and Mosaic
 refuses — blocks off the (8, 128) tiling, VMEM over the scoped limit, a
-program over the chip's HBM — at the shapes granite-3-2b's prefill
-launches.  The topology is described inside a module fixture (never at
-import): only one process may load the TPU library, and the suite runs
-under several pytest-xdist workers.
+program over the chip's HBM — at the shapes granite-3-2b's and
+deepseek-7b's (15 layers) prefill launches.  The topology is described
+inside a module fixture (never at import): only one process may load the
+TPU library, and the suite runs under several pytest-xdist workers.
 """
 import os
 
@@ -22,6 +22,9 @@ from repro.kernels import ops
 
 GRANITE = get_arch("granite-3-2b")
 KVW = GRANITE.num_kv_heads * GRANITE.resolved_head_dim     # 512
+DEEPSEEK = get_arch("deepseek-7b")
+DEEPSEEK_KVW = DEEPSEEK.num_kv_heads * DEEPSEEK.resolved_head_dim   # 4096
+DEEPSEEK_LAYERS, DEEPSEEK_ADMIT = 15, 4                     # one stage
 SLOTS, PROMPT, RANK = 8, 1024, 64
 K = RANK + EngineConfig().kv_iters_extra                    # Lanczos buffer
 LONG_PROMPT = 8192
@@ -51,8 +54,9 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compile_reorth(sharding, side: str, batch: int, s: int, f: int):
-    s_pad, h_pad = ops.padded_dims(s, KVW, f)
+def _compile_reorth(sharding, side: str, batch: int, s: int, f: int,
+                    kvw: int = KVW):
+    s_pad, h_pad = ops.padded_dims(s, kvw, f)
 
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
@@ -91,3 +95,16 @@ def test_reorth_compiles_at_long_prompt(one_chip, side):
     compiled, _ = _compile_reorth(one_chip, side, GRANITE.num_layers,
                                   LONG_PROMPT, 8)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("side", ["right", "left"])
+def test_reorth_compiles_at_deepseek_serve_shape(one_chip, side):
+    """[15 layers x 4 admitted prompts, 1024-token bucket, 4096] f32 at
+    f = 8: deepseek-7b's 4096-wide K (or V), no lane padding."""
+    batch = DEEPSEEK_LAYERS * DEEPSEEK_ADMIT
+    compiled, (s_pad, h_pad) = _compile_reorth(one_chip, side, batch,
+                                               PROMPT, 8, DEEPSEEK_KVW)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert (s_pad, h_pad) == (PROMPT, DEEPSEEK_KVW)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * batch * s_pad * h_pad
