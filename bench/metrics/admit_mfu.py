@@ -4,7 +4,7 @@ of every program there but the decode block (``jit_run`` in the trace's
 program line): the prefill programs, with the Lanczos kernel inside them,
 and the splice and first-token programs around them.  Times the chip's
 bf16 peak.  Lanczos, splice and bucket and batch padding are time, not
-work, here."""
+work, here.  The FLOPs are the model's block's ``forward_flops``."""
 import re
 
 from bench import counts
@@ -21,6 +21,7 @@ def read(rec):
     reqs = rec.admitted_in_span()
     if secs <= 0 or not reqs:
         return None
-    flops = sum(counts.forward_flops(rec.model, r.prompt_len) for r in reqs)
+    flops = sum(rec.block.forward_flops(rec.model, r.prompt_len)
+                for r in reqs)
     peak = counts.peaks(rec.device_kind)["bf16_flops"]
     return 100.0 * flops / (secs * peak)

@@ -25,7 +25,8 @@ A run:
    end-to-end ones;
 4. reads ``peak_bytes_in_use``, frees the engine, and checks a sample of
    the finished requests, drawn from the seed with the longest among them,
-   against the plain float32 reference (``bench/reference.py``).
+   against the plain float32 reference: the ``served_logits`` of the
+   block that the configuration names (``bench/blocks/<block>.py``).
 
 Without a TPU, or with fewer chips than the cell asks for, or without the
 system under test beside ``bench/``, it prints no result and exits 2.  The
@@ -105,6 +106,10 @@ class Record:
     #: window seconds the device metrics cover: the traced span in a
     #: traced run, else the whole window
     span: Tuple[float, float] = (0.0, 0.0)
+    #: the model's block (``bench/blocks/<block>.py``): its
+    #: ``forward_flops`` and ``reorth_needed`` count the work the
+    #: roofline readers divide
+    block: object = None
 
     @property
     def measured(self) -> List[ReqRec]:
@@ -139,6 +144,7 @@ class Ctx:
     device: object
     n_devices: int
     counter: object
+    block: object                   # bench/blocks/<block>.py
 
 
 def _compile_cache(root: Path) -> None:
@@ -158,6 +164,7 @@ def prepare(name: str, *, root: Path, bench_dir: Path, bm_root: Path,
     conf = spec.config_file(wl["config"], bench_dir)
     traffic = spec.traffic_file(wl["traffic"], bench_dir)
     cell = spec.cell_file(name, bench_dir)
+    block = spec.config_block(conf, bench_dir)
     if str(root / "src") not in sys.path:
         sys.path.insert(0, str(root / "src"))
     _compile_cache(root)
@@ -178,11 +185,10 @@ def prepare(name: str, *, root: Path, bench_dir: Path, bm_root: Path,
         if getattr(cfg, k) != v:
             raise spec.SpecError(f"config {wl['config']}: {k}={v} in the "
                                  f"file, {getattr(cfg, k)} as run")
-    model.setdefault("head_dim", cfg.resolved_head_dim)
     ec = dict(conf["engine"])
     ec.update(cell["serving"])
     return Ctx(wl, conf, traffic, cell, cfg, model, ec, dev, len(devices),
-               CompileCounter())
+               CompileCounter(), block)
 
 
 def buckets(ctx: Ctx) -> List[int]:
@@ -228,8 +234,8 @@ def build_engine(ctx: Ctx, params):
 def make_params(ctx: Ctx, seed: int):
     import jax
     from bench import weights
-    params = weights.make(ctx.model, seed, ctx.config.get("dtype",
-                                                          "bfloat16"))
+    params = weights.make(ctx.block.layout(ctx.model), seed,
+                          ctx.config.get("dtype", "bfloat16"))
     jax.block_until_ready(params)
     return params
 
@@ -379,7 +385,8 @@ def serve(ctx: Ctx, eng, seed: int, seconds: float, trace_dir: Optional[str],
         compiles={k: v - compiles0.get(k, 0)
                   for k, v in ctx.counter.counts.items()},
         generator_lag_max_s=max(lag_w, default=0.0),
-        generator_lag_mean_s=float(np.mean(lag_w)) if lag_w else 0.0)
+        generator_lag_mean_s=float(np.mean(lag_w)) if lag_w else 0.0,
+        block=ctx.block)
     # the span's annotation, and so its trace, holds every step begun
     # inside it
     rec.decode_rounds = sum(s[3] for s in steps
@@ -449,11 +456,11 @@ def check(ctx: Ctx, params, sample: List[ReqRec], prompts: Dict[int, object],
         padded = padded_prompt(ctx, prompts[r.uid])
         kw = dict(rank=rank, iters=iters,
                   decode_pad=int(ctx.traffic["output"]["max"]))
-        ref = reference.served_logits(params, ctx.model, padded, r.tokens,
+        ref = ctx.block.served_logits(params, ctx.model, padded, r.tokens,
                                       **kw)
         toks = r.tokens
         if control:
-            ctl = reference.served_logits(params, ctx.model, padded,
+            ctl = ctx.block.served_logits(params, ctx.model, padded,
                                           r.tokens, control=True, **kw)
             toks = list(ctl.argmax(-1))
         g = reference.gaps(ref, toks)
@@ -592,8 +599,9 @@ def main(argv=None, *, require_chip: bool = True, root: Path = ROOT,
                     for k, v in limits.items()}
 
     record = {
-        "workload": rec.workload, "seed": seed, "seconds": args.seconds,
-        "setup_s": setup_s, "check_s": t_chk, "trace_read_s": t_trace,
+        "workload": rec.workload, "block": ctx.config["block"],
+        "seed": seed, "seconds": args.seconds, "setup_s": setup_s,
+        "check_s": t_chk, "trace_read_s": t_trace,
         "trace_stop_s": rec.trace_stop_s,
         "wall_s": time.perf_counter() - T_START,
         "requests": {"measured": len(measured),

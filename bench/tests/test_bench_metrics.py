@@ -7,7 +7,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
-from bench import counts, spec  # noqa: E402
+from bench import spec  # noqa: E402
 from bench.run import Record, ReqRec  # noqa: E402
 
 
@@ -91,14 +91,15 @@ def _traced(prefill_s=0.5, decode_s=2.0):
                          "jit_run(3)": decode_s}, "ops": {}}
     return Record(workload="x", seconds=3.0, model=MODEL, engine_cfg={},
                   device_kind="TPU v5 lite", requests=reqs, steps=steps,
-                  trace=trace, span=(1.0, 3.0))
+                  trace=trace, span=(1.0, 3.0),
+                  block=spec.block_module("dense"))
 
 
 def test_admit_mfu_is_forward_flops_over_admission_device_time():
     rec = _traced()
     assert [r.uid for r in rec.admitted_in_span()] == [1, 2]
-    flops = counts.forward_flops(MODEL, 100) + counts.forward_flops(MODEL,
-                                                                   200)
+    dense = spec.block_module("dense")
+    flops = dense.forward_flops(MODEL, 100) + dense.forward_flops(MODEL, 200)
     assert read("admit_mfu", rec) == pytest.approx(
         100.0 * flops / (0.5 * 197e12))
     # decode time is not admission time
