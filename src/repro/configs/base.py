@@ -29,6 +29,17 @@ class ArchConfig:
     use_bias: bool = False
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
+    # --- attention kinds (mellum2) ---
+    sliding_window: int = 0          # window layers attend to this many rows
+    full_attn_every: int = 0         # with a window: layer i is full iff
+                                     # (i + 1) % full_attn_every == 0
+    # YaRN scaled RoPE of the full-attention layers (0 = plain RoPE);
+    # window layers keep plain RoPE at rope_theta
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     # --- MoE ---
     num_experts: int = 0
     top_k: int = 0
@@ -36,6 +47,13 @@ class ArchConfig:
     n_shared_experts: int = 0
     first_k_dense: int = 0           # leading dense layers (kimi-style)
     capacity_factor: float = 1.25
+    # A chip's share of the experts: the router keeps its published width
+    # ``router_experts`` and this chip holds experts ``expert_first`` …
+    # ``expert_first + num_experts − 1``.  A config that sets it runs the
+    # drop-free held-expert layer (``moe.held_experts_ffn``); 0 keeps the
+    # capacity-factor ``moe_ffn`` with the router as wide as num_experts.
+    router_experts: int = 0
+    expert_first: int = 0
     expert_sharding: str = "1d"      # "1d" = EP only; "2d" = EP x data (1T)
     # --- SSM (mamba2) ---
     ssm_state: int = 0
@@ -77,6 +95,19 @@ class ArchConfig:
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // max(self.num_heads, 1)
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """``"window"`` or ``"full"`` for each decoder layer."""
+        if not self.sliding_window:
+            return ("full",) * self.num_layers
+        n = self.full_attn_every
+        return tuple("full" if n and (i + 1) % n == 0 else "window"
+                     for i in range(self.num_layers))
 
     @property
     def d_inner(self) -> int:
@@ -132,6 +163,12 @@ class ArchConfig:
             kw.update(cross_attn_period=2, num_image_tokens=16)
         if self.enc_layers:
             kw.update(num_layers=4, enc_layers=2, num_audio_frames=32)
+        if self.router_experts:
+            kw.update(num_experts=4, router_experts=8, top_k=2)
+        if self.sliding_window:
+            # one whole period of the layer pattern, a window shorter than
+            # the smoke prompts
+            kw.update(num_layers=self.full_attn_every or 2, sliding_window=8)
         return self.replace(**kw)
 
 
@@ -180,8 +217,8 @@ def _load_all() -> None:
     # Import every per-arch module once; each calls register().
     from . import (deepseek_7b, gemma_2b, granite_3_2b,  # noqa: F401
                    kimi_k2, llama2_7b, llama32_vision_11b, mamba2_780m,
-                   olmoe_1b_7b, seamless_m4t_medium, starcoder2_7b,
-                   zamba2_1_2b)
+                   mellum2_12b, olmoe_1b_7b, seamless_m4t_medium,
+                   starcoder2_7b, zamba2_1_2b)
 
 
 def cells(arch: ArchConfig) -> Tuple[str, ...]:

@@ -198,13 +198,15 @@ def main(argv: Optional[Sequence[str]] = None
         plen = -(-args.prompt_len // max(1, args.sched_bucket)) \
             * max(1, args.sched_bucket)
         kvw = cfg.num_kv_heads * cfg.resolved_head_dim
+        from ..models.decomposed_kv import factorized_layers
+        n_fac = len(factorized_layers(cfg))  # layers whose K/V is factorized
         slots = max(1, args.slots)
         nbs, nb = {slots}, 1             # nb = min(pow2(admitted), slots)
         while nb < slots:
             nbs.add(nb)
             nb *= 2
         pre = tune.pretune(
-            {"lanczos_reorth": [(cfg.num_layers * n, plen, kvw)
+            {"lanczos_reorth": [(n_fac * n, plen, kvw)
                                 for n in sorted(nbs)]},
             fix={"backend": dengine.resolved_backend})
         for key, res in pre.items():

@@ -253,20 +253,26 @@ def decomposed_fns(cfg: ArchConfig, engine) -> DecomposedFns:
 
     The engine (a ``repro.engine.DecomposeEngine``) is the ONLY source of
     decomposition for everything returned here — consumers never touch
-    ranks, hooks, or backends directly.  Dense family only (the engine's
-    decomposed paths are implemented for the dense transformer).
+    ranks, hooks, or backends directly.  The decomposed-KV entries take
+    any config that ``decomposed_kv.unsupported`` passes; the
+    decomposed-activation ones (``forward``/``logit_kl``) run the dense
+    transformer only.
     """
-    assert cfg.family == "dense", "decomposed execution: dense family"
     from . import decomposed as D
     from . import decomposed_kv as DK
+    why = DK.unsupported(cfg)
+    if why is not None:
+        raise ValueError(f"decomposed execution: {why}")
     runtime = D.DecomposedRuntime(engine=engine) \
         if engine.config.policy is not None else None
 
     def forward(params, tokens, wfactors=None):
+        assert cfg.family == "dense", "decomposed activations: dense family"
         assert runtime is not None, "engine has no decomposition policy"
         return D.forward(params, cfg, tokens, runtime, wfactors)
 
     def logit_kl(params, tokens, wfactors=None):
+        assert cfg.family == "dense", "decomposed activations: dense family"
         assert runtime is not None, "engine has no decomposition policy"
         return D.logit_kl(params, cfg, tokens, runtime, wfactors)
 

@@ -35,6 +35,20 @@ tail rows sit in fixed-size page pools addressed by per-slot block tables,
 enabling refcounted SHARING of frozen prefix pages across requests
 (serving.paged) while replaying the slab arithmetic bit-for-bit.
 
+Models that mix layer kinds (mellum2: sliding-window and full-attention
+layers, a drop-free held-expert MLP) keep two kinds of per-layer state in
+one cache: the factors and dense tail above for the full-attention layers
+only (``factorized_layers``), and for each window layer a RING of its last
+``sliding_window`` RoPE'd K/V rows, ``ring.k``/``ring.v`` [nw, B, W, kvh,
+hd] — the row of position p sits at ``p mod W``.  Prefill factorizes only
+the full layers' K/V and writes each window layer's last min(len, W) rows;
+decode writes ring row ``pos mod W`` and masks rows outside the window; a
+splice carries both kinds and a fold touches only the factors.  The layers
+scan one PERIOD of the layer pattern at a time (``cfg.full_attn_every``
+layers, unrolled by kind).  Window attention and ring writes sit in the
+named scope ``dcom.window``, the expert layer in ``dcom.moe``.  The dense
+family's arrays and programs are those of the pure-dense path.
+
 Sharding invariants (mesh-parallel serving, DESIGN.md §9): every op in this
 module is BATCH-LOCAL — the tail write is a vmapped
 ``dynamic_update_slice`` along each slot's own row, ``compress_tail``'s
@@ -66,12 +80,67 @@ TAIL = 128                      # dense recent-token buffer length
 _DEFAULT_ENGINE = DecomposeEngine(EngineConfig())
 
 
+def unsupported(cfg) -> Optional[str]:
+    """Why the decomposed-KV path cannot serve ``cfg`` (None: it can).  It
+    serves the dense family, and expert models whose experts run drop-free
+    (``router_experts`` set, no leading dense layers): the capacity-factor
+    ``moe_ffn`` drops tokens by what they are batched with, so admissions
+    of different batch sizes would serve different tokens."""
+    if cfg.family == "dense":
+        return None
+    if cfg.family == "moe" and cfg.router_experts and not cfg.first_k_dense:
+        if "full" not in _period_kinds(cfg):
+            return f"{cfg.name} has no full-attention layer to factorize"
+        return None
+    return (f"decomposed KV serves the dense family and expert models with "
+            f"a drop-free held-expert layer (router_experts); {cfg.name} is "
+            f"family {cfg.family!r}")
+
+
+def paged_unsupported(cfg) -> Optional[str]:
+    """Why ``paged=True`` cannot serve ``cfg`` (None: it can): the page
+    pools hold one kind of per-layer state for a dense layer stack."""
+    if not _mixed(cfg):
+        return None
+    return (f"paged decomposed KV holds one kind of per-layer state for a "
+            f"dense layer stack; {cfg.name} ({len(window_layers(cfg))} "
+            f"window layers beside {len(factorized_layers(cfg))} factorized "
+            f"ones, expert MLPs) is served on the slab: paged=False")
+
+
+def _mixed(cfg) -> bool:
+    return cfg.family != "dense"
+
+
+def _period_kinds(cfg) -> Tuple[str, ...]:
+    """The layer kinds of one period of the layer pattern."""
+    n = cfg.full_attn_every if cfg.sliding_window and cfg.full_attn_every \
+        else 1
+    kinds = cfg.layer_kinds
+    if cfg.num_layers % n or kinds != kinds[:n] * (cfg.num_layers // n):
+        raise ValueError(f"{cfg.name}: {cfg.num_layers} layers are not "
+                         f"whole periods of {n}")
+    return kinds[:n]
+
+
+def factorized_layers(cfg) -> Tuple[int, ...]:
+    """Indices of the layers whose K/V is factorized (full attention)."""
+    return tuple(i for i, k in enumerate(cfg.layer_kinds) if k == "full")
+
+
+def window_layers(cfg) -> Tuple[int, ...]:
+    """Indices of the layers that keep a ring of window rows."""
+    return tuple(i for i, k in enumerate(cfg.layer_kinds) if k == "window")
+
+
 def init_cache(cfg, batch: int, frozen_len: int, rank: int,
                tail: int = TAIL) -> Params:
     kvw = cfg.num_kv_heads * cfg.resolved_head_dim
     nl, dt = cfg.num_layers, cfg.jax_dtype
+    if _mixed(cfg):
+        nl = len(factorized_layers(cfg))
     z = jnp.zeros
-    return {
+    cache = {
         "k_u": z((nl, batch, frozen_len, rank), dt),
         "k_vt": z((nl, batch, rank, kvw), dt),
         "v_u": z((nl, batch, frozen_len, rank), dt),
@@ -81,13 +150,21 @@ def init_cache(cfg, batch: int, frozen_len: int, rank: int,
                  "v": z((nl, batch, tail, cfg.num_kv_heads,
                          cfg.resolved_head_dim), dt)},
     }
+    nw = len(window_layers(cfg)) if _mixed(cfg) else 0
+    if nw:
+        shape = (nw, batch, cfg.sliding_window, cfg.num_kv_heads,
+                 cfg.resolved_head_dim)
+        cache["ring"] = {"k": z(shape, dt), "v": z(shape, dt)}
+    return cache
 
 
 def prefill_dkv(p: Params, cfg, tokens: Array, rank: int,
                 tail: int = TAIL, exact: bool = False,
                 engine: Optional[DecomposeEngine] = None
                 ) -> Tuple[Array, Params]:
-    """Dense-family prefill that emits a decomposed KV cache.
+    """Prefill that emits a decomposed KV cache.  A model of mixed layer
+    kinds (:func:`_prefill_mixed`) also returns a third array: the
+    token→expert picks of the prompt rows, per router expert.
 
     K/V factorization goes through :meth:`DecomposeEngine.decompose_kv`
     (Lanczos via the engine's backend; ``exact`` switches to direct SVD for
@@ -102,6 +179,8 @@ def prefill_dkv(p: Params, cfg, tokens: Array, rank: int,
         raise ValueError(f"prefill_dkv needs rank >= 1, got {rank} "
                          "(is the engine's kv_rank configured?)")
     engine = engine or _DEFAULT_ENGINE
+    if _mixed(cfg):
+        return _prefill_mixed(p, cfg, tokens, rank, tail, exact, engine)
     b, s = tokens.shape
     with jax.named_scope("dcom.forward"):
         logits, dense_cache = T.prefill(p, cfg, tokens, s)
@@ -180,6 +259,8 @@ def decode_step_dkv(p: Params, cfg, token: Array, cache: Params,
     slot's tail write position is its own ``pos − frozen_len``.
     """
     frozen_len = _frozen_vec(frozen_len, pos)
+    if _mixed(cfg):
+        return _decode_step_mixed(p, cfg, token, cache, pos, frozen_len)
     x = p["embed"]["w"][token][:, None, :] * jnp.asarray(
         cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0, cfg.jax_dtype)
     kvh = cfg.num_kv_heads
@@ -338,11 +419,14 @@ def compress_tail(cache: Params, cfg, rank: int,
     fm = fold_m[None, :, None, None, None]
     new_tail = {k: jnp.where(fm, jnp.zeros_like(v), v)
                 for k, v in cache["tail"].items()}
-    return {"k_u": k_u.astype(cache["k_u"].dtype),
-            "k_vt": k_vt.astype(cache["k_vt"].dtype),
-            "v_u": v_u.astype(cache["v_u"].dtype),
-            "v_vt": v_vt.astype(cache["v_vt"].dtype),
-            "tail": new_tail}
+    out = {"k_u": k_u.astype(cache["k_u"].dtype),
+           "k_vt": k_vt.astype(cache["k_vt"].dtype),
+           "v_u": v_u.astype(cache["v_u"].dtype),
+           "v_vt": v_vt.astype(cache["v_vt"].dtype),
+           "tail": new_tail}
+    if "ring" in cache:                 # window rows are never folded
+        out["ring"] = cache["ring"]
+    return out
 
 
 def splice_dkv(live: Params, fresh: Params, slot_indices,
@@ -381,10 +465,209 @@ def splice_dkv(live: Params, fresh: Params, slot_indices,
             old = pad_to(live[key], 2, r)
             new = pad_to(fresh[key], 2, r)
             out[key] = old.at[:, idx].set(new[:, src].astype(old.dtype))
-        out["tail"] = {k: live["tail"][k].at[:, idx].set(
-            fresh["tail"][k][:, src].astype(live["tail"][k].dtype))
-            for k in live["tail"]}
+        for kind in ("tail", "ring") if "ring" in live else ("tail",):
+            out[kind] = {k: live[kind][k].at[:, idx].set(
+                fresh[kind][k][:, src].astype(live[kind][k].dtype))
+                for k in live[kind]}
         return out
+
+
+# ---------------------------------------------------------------------------
+# Mixed layer kinds: window rings beside the factorized full layers
+# ---------------------------------------------------------------------------
+
+def _by_period(tree, n: int):
+    """Leaves [L, …] → [L / n, n, …]: one row per period."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), tree)
+
+
+def _flat_periods(tree):
+    """Leaves [P, n, …] → [P·n, …]."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), tree)
+
+
+def _at(tree, j: int):
+    return jax.tree_util.tree_map(lambda a: a[j], tree)
+
+
+def _qkv(lp, h, cfg, positions, rope):
+    """Projections of one layer, q and k rotated by the kind's RoPE."""
+    freqs, scale = rope
+    q = L._split_heads(L.dense(lp["attn"]["wq"], h), cfg.num_heads)
+    k = L._split_heads(L.dense(lp["attn"]["wk"], h), cfg.num_kv_heads)
+    v = L._split_heads(L.dense(lp["attn"]["wv"], h), cfg.num_kv_heads)
+    q = L.apply_rope(q, positions, cfg.rope_theta, freqs, scale)
+    k = L.apply_rope(k, positions, cfg.rope_theta, freqs, scale)
+    return q, k, v
+
+
+def _ring_of(a: Array, w: int) -> Array:
+    """The ring of a prompt's rows a [B, S, …]: its last min(S, w) rows,
+    the row of position p at ``p mod w`` (zeros where S < w)."""
+    s = a.shape[1]
+    if s < w:
+        return jnp.pad(a, [(0, 0), (0, w - s)] + [(0, 0)] * (a.ndim - 2))
+    return jnp.roll(a[:, s - w:], s % w, axis=1)
+
+
+def _ring_attention(q: Array, rk: Array, rv: Array, pos: Array,
+                    cfg) -> Array:
+    """One-token attention over a window ring: q [B, 1, nh, d], ring
+    k/v [B, W, kvh, d] → [B, 1, nh·d].  Ring row j holds position
+    ``pos − ((pos − j) mod W)``, valid where that is not negative."""
+    b, w = rk.shape[:2]
+    hd = cfg.resolved_head_dim
+    j = jnp.arange(w)[None, :]
+    row_pos = pos[:, None] - (pos[:, None] - j) % w              # [B, W]
+    sc = L._gqa_scores(q, rk) * (hd ** -0.5)                     # [B,nh,1,W]
+    sc = jnp.where((row_pos >= 0)[:, None, None, :], sc, -1e30)
+    pr = jax.nn.softmax(sc, axis=-1).astype(rv.dtype)
+    return L._gqa_pv(pr, rv).reshape(b, 1, cfg.num_heads * hd)
+
+
+def _expert_mlp(lp, x, cfg):
+    from . import moe
+    h = T._norm(lp["mlp_norm"], x, cfg)
+    y, _, picks = moe.held_experts_ffn(lp["moe"], h, cfg)
+    return x + y, picks
+
+
+def _prefill_mixed(p: Params, cfg, tokens: Array, rank: int, tail: int,
+                   exact: bool, engine) -> Tuple[Array, Params, Array]:
+    """Prefill of a model of mixed layer kinds: the forward pass in scopes
+    ``dcom.forward`` (norms, projections, full attention, head),
+    ``dcom.window`` (window attention and the ring write) and
+    ``dcom.moe``; then the full layers' K and V, and only theirs, through
+    :meth:`DecomposeEngine.decompose_kv` in ``dcom.lanczos``.
+
+    Returns (logits [B, V], cache, picks [router width] int32: the
+    token→expert picks of the rows whose token is not the engine's pad
+    id 0, summed over layers)."""
+    b, s = tokens.shape
+    kinds = _period_kinds(cfg)
+    ropes = {k: L.rope_of(cfg, k) for k in set(kinds)}
+    w = cfg.sliding_window
+    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    positions = jnp.broadcast_to(jnp.arange(s), tokens.shape)
+    real = (tokens != 0).reshape(-1).astype(jnp.int32)          # [B·S]
+    with jax.named_scope("dcom.forward"):
+        x = p["embed"]["w"][tokens] * jnp.asarray(
+            cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0,
+            cfg.jax_dtype)
+
+    def period(carry, lp):
+        x, picks = carry
+        full, ring = [], []
+        for j, kind in enumerate(kinds):
+            lj = _at(lp, j)
+            with jax.named_scope("dcom.forward"):
+                h = T._norm(lj["attn_norm"], x, cfg)
+                q, k, v = _qkv(lj, h, cfg, positions, ropes[kind])
+            if kind == "window":
+                with jax.named_scope("dcom.window"):
+                    a = L.attend(q, k, v, positions, window=w,
+                                 out_dtype=x.dtype)
+                    ring.append({"k": _ring_of(k, w), "v": _ring_of(v, w)})
+            else:
+                with jax.named_scope("dcom.forward"):
+                    a = L.attend(q, k, v, positions, out_dtype=x.dtype)
+                full.append({"k": k.astype(cfg.jax_dtype),
+                             "v": v.astype(cfg.jax_dtype)})
+            with jax.named_scope("dcom.forward"):
+                x = x + L.dense(lj["attn"]["wo"], a)
+            x, e = _expert_mlp(lj, x, cfg)
+            with jax.named_scope("dcom.moe"):
+                picks = picks.at[e].add(real[:, None])
+        stack = lambda rows: jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *rows) if rows else None
+        return (x, picks), (stack(full), stack(ring))
+
+    picks0 = jnp.zeros((cfg.router_width,), jnp.int32)
+    (x, picks), (full, ring) = L.xscan(
+        period, (x, picks0), _by_period(p["layers"], len(kinds)))
+    with jax.named_scope("dcom.forward"):
+        logits = T.logits_head(p, x[:, -1:, :], cfg)[:, 0]
+    full = _flat_periods(full)                       # [nf, B, S, kvh, hd]
+    nf = full["k"].shape[0]
+
+    def one(kv):
+        with jax.named_scope("dcom.lanczos"):
+            flat = kv.reshape(nf * b, s, kvh * hd)
+            u, vt = engine.decompose_kv(flat, rank, exact=exact)
+            r_eff = u.shape[-1]
+            return (u.reshape(nf, b, s, r_eff),
+                    vt.reshape(nf, b, r_eff, kvh * hd))
+
+    k_u, k_vt = one(full["k"])
+    v_u, v_vt = one(full["v"])
+    z = jnp.zeros((nf, b, tail, kvh, hd), cfg.jax_dtype)
+    cache = {"k_u": k_u, "k_vt": k_vt, "v_u": v_u, "v_vt": v_vt,
+             "tail": {"k": z, "v": z}}
+    if ring is not None:
+        cache["ring"] = _flat_periods(ring)
+    return logits, cache, picks
+
+
+def _decode_step_mixed(p: Params, cfg, token: Array, cache: Params,
+                       pos: Array, frozen_len: Array
+                       ) -> Tuple[Array, Params]:
+    """One-token decode of a model of mixed layer kinds: window layers
+    attend to their ring (row ``pos mod W`` written first), full layers
+    through their factors and dense tail as the dense path does."""
+    kinds = _period_kinds(cfg)
+    n = len(kinds)
+    ropes = {k: L.rope_of(cfg, k) for k in set(kinds)}
+    w = cfg.sliding_window
+    nfp = kinds.count("full")
+    x = p["embed"]["w"][token][:, None, :] * jnp.asarray(
+        cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0, cfg.jax_dtype)
+    slot = pos - frozen_len                          # tail write position
+    upd = lambda buf, new, at: jax.vmap(
+        lambda bb, nn, ss: jax.lax.dynamic_update_slice_in_dim(
+            bb, nn, ss, axis=0))(buf, new.astype(buf.dtype), at)
+    fac = {k: _by_period(cache[k], nfp)
+           for k in ("k_u", "k_vt", "v_u", "v_vt", "tail")}
+    rings = _by_period(cache["ring"], n - nfp) if n > nfp else None
+
+    def period(x, inp):
+        lp, f, rg = inp
+        tails, new_rings = [], []
+        fi = wi = 0
+        for j, kind in enumerate(kinds):
+            lj = _at(lp, j)
+            h = T._norm(lj["attn_norm"], x, cfg)
+            q, k, v = _qkv(lj, h, cfg, pos[:, None], ropes[kind])
+            if kind == "window":
+                with jax.named_scope("dcom.window"):
+                    r = _at(rg, wi)
+                    r = {"k": upd(r["k"], k, pos % w),
+                         "v": upd(r["v"], v, pos % w)}
+                    a = _ring_attention(q, r["k"], r["v"], pos, cfg)
+                new_rings.append(r)
+                wi += 1
+            else:
+                t = _at(f["tail"], fi)
+                t = {"k": upd(t["k"], k, slot), "v": upd(t["v"], v, slot)}
+                layer_c = {key: f[key][fi]
+                           for key in ("k_u", "k_vt", "v_u", "v_vt")}
+                a = _lowrank_attention(q, layer_c, t, pos, frozen_len, cfg)
+                tails.append(t)
+                fi += 1
+            x = x + L.dense(lj["attn"]["wo"], a.astype(x.dtype))
+            x, _ = _expert_mlp(lj, x, cfg)
+        stack = lambda rows: jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *rows) if rows else None
+        return x, (stack(tails), stack(new_rings))
+
+    x, (tails, new_rings) = L.xscan(
+        period, x, (_by_period(p["layers"], n), fac, rings))
+    new_cache = dict(cache)
+    new_cache["tail"] = _flat_periods(tails)
+    if new_rings is not None:
+        new_cache["ring"] = _flat_periods(new_rings)
+    return T.logits_head(p, x, cfg)[:, 0], new_cache
 
 
 # ---------------------------------------------------------------------------

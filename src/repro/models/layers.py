@@ -130,13 +130,58 @@ def rope_frequencies(head_dim: int, theta: float) -> Array:
                             / head_dim))
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x [..., S, n, d]; positions [..., S] (int).  Rotates pairs (even, odd)."""
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> Array:
+    """YaRN inverse frequencies [d/2], as the Hugging Face ``rope_type:
+    "yarn"``: dimension pairs that turn more than ``beta_fast`` times over
+    ``original_max_pos`` positions keep θ^(−2i/d), those that turn fewer
+    than ``beta_slow`` times are divided by ``factor``, and a linear ramp
+    between the two (corrections floored and ceiled) blends them."""
+    import math
+    d = head_dim
+
+    def corr(rot):
+        return d * math.log(original_max_pos / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(corr(beta_fast)), 0)
+    hi = min(math.ceil(corr(beta_slow)), d - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    extra = rope_frequencies(d, theta)
+    keep = 1.0 - ramp                       # share of the unscaled frequency
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def rope_of(cfg, kind: str) -> Tuple[Array, float]:
+    """(inverse frequencies [d/2], cos/sin scale) of one layer kind:
+    YaRN for ``"full"`` layers when the config sets it, else plain RoPE at
+    ``rope_theta`` with scale 1."""
+    d = cfg.resolved_head_dim
+    if kind == "full" and cfg.yarn_factor:
+        return (yarn_frequencies(d, cfg.rope_theta, cfg.yarn_factor,
+                                 cfg.yarn_original_max_pos,
+                                 cfg.yarn_beta_fast, cfg.yarn_beta_slow),
+                cfg.yarn_attention_factor)
+    return rope_frequencies(d, cfg.rope_theta), 1.0
+
+
+def apply_rope(x: Array, positions: Array, theta: float,
+               freqs: Array = None, scale=1.0) -> Array:
+    """x [..., S, n, d]; positions [..., S] (int).  Rotates pairs (even, odd).
+    ``freqs`` (inverse frequencies [d/2]) replaces θ's plain ones, and cos
+    and sin are multiplied by ``scale`` (YaRN's attention factor)."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                       # [d/2]
+    if freqs is None:
+        freqs = rope_frequencies(d, theta)                   # [d/2]
     ang = positions.astype(jnp.float32)[..., None] * freqs   # [..., S, d/2]
     cos = jnp.cos(ang)[..., None, :]                          # [..., S, 1, d/2]
     sin = jnp.sin(ang)[..., None, :]
+    if not (isinstance(scale, float) and scale == 1.0):
+        cos, sin = cos * scale, sin * scale
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., 0::2], x32[..., 1::2]
     o1 = x1 * cos - x2 * sin
@@ -194,10 +239,12 @@ def _gqa_pv(p: Array, v: Array) -> Array:
 
 def attend(q: Array, k: Array, v: Array, positions: Array, *,
            causal: bool = True, chunk: int = 0,
-           out_dtype=None) -> Array:
+           out_dtype=None, window=0) -> Array:
     """Softmax attention over precomputed q [B,S,nh,d], k/v [B,T,kvh,d],
     scanned over query chunks so the [qc, T] score block is the only S²
-    activation (flash-style memory).  Returns [B, S, nh·d]."""
+    activation (flash-style memory).  Returns [B, S, nh·d].  A causal
+    ``window`` (static or traced int, 0 = none) hides key j from query i
+    unless ``i − j < window``."""
     b, s, nh, hd = q.shape
     out_dtype = out_dtype or q.dtype
     scale = hd ** -0.5
@@ -218,6 +265,9 @@ def attend(q: Array, k: Array, v: Array, positions: Array, *,
             pos_blk = jax.lax.dynamic_slice_in_dim(positions, qi * qc, qc,
                                                    axis=-1)
             mask = pos_blk[..., None] >= positions[..., None, :]  # [B, qc, T]
+            if not (isinstance(window, int) and window == 0):
+                mask = mask & (pos_blk[..., None] - positions[..., None, :]
+                               < window)
             sc = jnp.where(mask[:, None, :, :], sc,
                            jnp.asarray(-1e30, sc.dtype))
         pr = jax.nn.softmax(sc, axis=-1).astype(v.dtype)   # bf16 probs
@@ -235,17 +285,41 @@ def attend(q: Array, k: Array, v: Array, positions: Array, *,
 
 
 def causal_attention(p: Params, x: Array, cfg, positions: Array,
-                     chunk: int = 0, causal: bool = True) -> Array:
-    """Standard self-attention block body (projections + attend + out-proj)."""
+                     chunk: int = 0, causal: bool = True,
+                     kind=None) -> Array:
+    """Standard self-attention block body (projections + attend + out-proj).
+    ``kind`` — ``(inverse frequencies, scale, window)``, each static or
+    traced per layer (``layer_kinds_table``) — sets the layer's RoPE and
+    causal window; None is plain RoPE over the whole context."""
     nh, kvh = cfg.num_heads, cfg.num_kv_heads
+    freqs, scale, window = kind if kind is not None else (None, 1.0, 0)
     q = _split_heads(dense(p["wq"], x), nh)
     k = _split_heads(dense(p["wk"], x), kvh)
     v = _split_heads(dense(p["wv"], x), kvh)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, freqs, scale)
+    k = apply_rope(k, positions, cfg.rope_theta, freqs, scale)
     out = attend(q, k, v, positions, causal=causal, chunk=chunk,
-                 out_dtype=x.dtype)
+                 out_dtype=x.dtype, window=window)
     return dense(p["wo"], out)
+
+
+#: a window that hides nothing: stands for a full-attention layer in a
+#: per-layer table of windows
+NO_WINDOW = 1 << 30
+
+
+def layer_kinds_table(cfg, layers=None):
+    """Per-layer ``(inverse frequencies [L, d/2], scale [L], window [L])``
+    of ``cfg.layer_kinds`` (or of those of ``layers``), for a layer scan
+    whose layers differ in kind."""
+    kinds = [cfg.layer_kinds[i] for i in (layers if layers is not None
+                                          else range(cfg.num_layers))]
+    tabs = {k: rope_of(cfg, k) for k in set(kinds)}
+    freqs = jnp.stack([tabs[k][0] for k in kinds])
+    scale = jnp.asarray([tabs[k][1] for k in kinds], jnp.float32)
+    window = jnp.asarray([cfg.sliding_window if k == "window" else NO_WINDOW
+                          for k in kinds], jnp.int32)
+    return freqs, scale, window
 
 
 def cross_attention(p: Params, x: Array, memory_kv: Tuple[Array, Array],
@@ -276,17 +350,19 @@ def init_kv_cache(batch: int, max_len: int, kvh: int, hd: int, dtype):
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def decode_attention(p: Params, x: Array, cache: Params, pos: Array, cfg
-                     ) -> Tuple[Array, Params]:
-    """One-token attention: x [B, 1, H], cache k/v [B, T, kvh, d], pos [B]."""
+def decode_attention(p: Params, x: Array, cache: Params, pos: Array, cfg,
+                     kind=None) -> Tuple[Array, Params]:
+    """One-token attention: x [B, 1, H], cache k/v [B, T, kvh, d], pos [B].
+    ``kind`` as in :func:`causal_attention`."""
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    freqs, scale, window = kind if kind is not None else (None, 1.0, 0)
     b = x.shape[0]
     t = cache["k"].shape[1]
     q = _split_heads(dense(p["wq"], x), nh)            # [B, 1, nh, d]
     k_new = _split_heads(dense(p["wk"], x), kvh)
     v_new = _split_heads(dense(p["wv"], x), kvh)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k_new = apply_rope(k_new, pos[:, None], cfg.rope_theta)
+    q = apply_rope(q, pos[:, None], cfg.rope_theta, freqs, scale)
+    k_new = apply_rope(k_new, pos[:, None], cfg.rope_theta, freqs, scale)
 
     def upd(c, new):
         return jax.vmap(
@@ -297,6 +373,8 @@ def decode_attention(p: Params, x: Array, cache: Params, pos: Array, cfg
 
     sc = _gqa_scores(q, k) * (hd ** -0.5)              # [B, nh, 1, T]
     valid = jnp.arange(t)[None, :] <= pos[:, None]     # [B, T]
+    if kind is not None:
+        valid = valid & (pos[:, None] - jnp.arange(t)[None, :] < window)
     sc = jnp.where(valid[:, None, None, :], sc, -1e30)
     pr = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
     out = _gqa_pv(pr, v).astype(x.dtype).reshape(b, 1, nh * hd)

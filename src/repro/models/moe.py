@@ -14,6 +14,15 @@ carry no FLOPs):
 
 kimi-k2 extras: ``first_k_dense`` leading dense blocks and
 ``n_shared_experts`` always-on shared expert(s) added to the MoE output.
+
+A config with ``router_experts`` (mellum2-12b) holds a share of the experts
+and runs :func:`held_experts_ffn` instead: it routes over the router's full
+width, computes the gated outputs of the experts this chip holds, and drops
+no token — sorted token→expert runs through a grouped matmul
+(``jax.lax.ragged_dot``), so a token's output does not depend on what it
+was batched with.  Such configs may also mix window and full-attention
+layers (``cfg.layer_kinds``); the dense-KV path here then masks each
+layer's window and uses its kind's RoPE.
 """
 from __future__ import annotations
 
@@ -52,7 +61,7 @@ def moe_ffn_init(key, cfg) -> Params:
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
 
     p = {
-        "router": {"w": w(ks[0], (h, e))},
+        "router": {"w": w(ks[0], (h, cfg.router_width))},
         "w_gate": w(ks[1], (e, h, f)),
         "w_up": w(ks[2], (e, h, f)),
         "w_down": (jax.random.normal(ks[3], (e, f, h), jnp.float32)
@@ -121,6 +130,68 @@ def moe_ffn(p: Params, x: Array, cfg) -> Tuple[Array, Array]:
     if "shared" in p:
         y = y + L.mlp(p["shared"], x, cfg.activation)
     return y, aux
+
+
+def route(p: Params, xf: Array, cfg) -> Tuple[Array, Array, Array]:
+    """Softmax router over its full width, top-k, gates renormalized over
+    the k: xf [T, H] → (gates [T, k] f32, expert ids [T, k], probs [T, E])."""
+    logits = jnp.einsum("th,he->te", xf.astype(jnp.float32),
+                        p["router"]["w"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, eidx = jax.lax.top_k(probs, cfg.top_k)
+    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    return gates, eidx, probs
+
+
+def held_experts_ffn(p: Params, x: Array, cfg
+                     ) -> Tuple[Array, Array, Array]:
+    """The held experts' share of the expert layer, dropping nothing.
+
+    x [B, S, H] → (y [B, S, H], aux loss, expert ids [B·S, k]).  Each of
+    the T·k token→expert picks whose expert this chip holds
+    (``expert_first`` … ``+ num_experts``) is sorted into its expert's
+    run; the picks of experts held elsewhere sort after every run and are
+    computed by no one here.  Gate, up and down are grouped matmuls over
+    the runs (``jax.lax.ragged_dot``; rows past the last run belong to
+    no group and give zeros), and each token sums its held
+    picks' outputs times their gates.  Everything is in the named scope
+    ``dcom.moe``."""
+    with jax.named_scope("dcom.moe"):
+        b, s, h = x.shape
+        t, k, held = b * s, cfg.top_k, cfg.num_experts
+        xf = x.reshape(t, h)
+        gates, eidx, probs = route(p, xf, cfg)
+        e = cfg.router_width
+        ce = jnp.mean(jax.nn.one_hot(eidx[:, 0], e, dtype=jnp.float32), 0)
+        aux = e * jnp.sum(jnp.mean(probs, axis=0) * ce) * AUX_LOSS_COEF
+
+        local = (eidx - cfg.expert_first).reshape(t * k)
+        mine = (local >= 0) & (local < held)
+        run = jnp.where(mine, local, held)           # away picks sort last
+        order = jnp.argsort(run, stable=True)
+        sizes = jnp.bincount(run, length=held + 1)[:held].astype(jnp.int32)
+        rows = xf[order // k]                        # [T·k, H] in run order
+        act = L.activation_fn(cfg.activation)
+        hid = act(jax.lax.ragged_dot(rows, p["w_gate"], sizes)) \
+            * jax.lax.ragged_dot(rows, p["w_up"], sizes)
+        out = jax.lax.ragged_dot(hid, p["w_down"], sizes)     # [T·k, H]
+        out = out[jnp.argsort(order)].astype(jnp.float32)     # pick order
+        g = jnp.where(mine, gates.reshape(t * k), 0.0)
+        y = jnp.sum(jnp.where(mine[:, None], out, 0.0).reshape(t, k, h)
+                    * g.reshape(t, k, 1), axis=1)
+        y = y.reshape(b, s, h).astype(x.dtype)
+        if "shared" in p:
+            y = y + L.mlp(p["shared"], x, cfg.activation)
+    return y, aux, eidx
+
+
+def expert_layer(p: Params, x: Array, cfg) -> Tuple[Array, Array]:
+    """The config's expert layer: the drop-free held share when the config
+    names its router width, else the capacity-factor ``moe_ffn``."""
+    if cfg.router_experts:
+        y, aux, _ = held_experts_ffn(p, x, cfg)
+        return y, aux
+    return moe_ffn(p, x, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -239,12 +310,23 @@ def init_moe_block(key, cfg) -> Params:
     }
 
 
-def moe_block(p: Params, x: Array, positions: Array, cfg) -> Tuple[Array, Array]:
+def moe_block(p: Params, x: Array, positions: Array, cfg,
+              kind=None) -> Tuple[Array, Array]:
     x = x + L.causal_attention(p["attn"], L.rmsnorm(p["attn_norm"], x,
                                                     cfg.norm_eps),
-                               cfg, positions)
-    y, aux = moe_ffn(p["moe"], L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps), cfg)
+                               cfg, positions, kind=kind)
+    y, aux = expert_layer(p["moe"], L.rmsnorm(p["mlp_norm"], x,
+                                              cfg.norm_eps), cfg)
     return x + y, aux
+
+
+def _kinds(cfg, n_layers: int):
+    """Scan inputs of each expert layer's attention kind (None where every
+    layer is plain full attention, so that scan is unchanged)."""
+    if not cfg.sliding_window and not cfg.yarn_factor:
+        return None
+    return L.layer_kinds_table(
+        cfg, range(cfg.num_layers - n_layers, cfg.num_layers))
 
 
 def init(key, cfg) -> Params:
@@ -276,12 +358,14 @@ def forward(p: Params, cfg, tokens: Array) -> Tuple[Array, Array]:
             x, p["dense_layers"])
 
     body = L.ckpt(moe_block, cfg, static_argnums=(3,))
+    kinds = _kinds(cfg, cfg.num_layers - cfg.first_k_dense)
 
-    def scan_fn(x, lp):
-        x, aux = body(lp, x, positions, cfg)
+    def scan_fn(x, inp):
+        lp, kind = inp
+        x, aux = body(lp, x, positions, cfg, kind)
         return x, aux
 
-    x, auxs = L.xscan(scan_fn, x, p["layers"])
+    x, auxs = L.xscan(scan_fn, x, (p["layers"], kinds))
     logits = T.logits_head(p, x, cfg)
     return logits, jnp.sum(auxs)
 
@@ -316,11 +400,12 @@ def prefill(p: Params, cfg, tokens: Array, max_len: Optional[int] = None
     pad = [(0, 0), (0, t - s), (0, 0), (0, 0)]
     cache: Params = {}
 
-    def kv_of(lp, x):
+    def kv_of(lp, x, kind=None):
+        freqs, scale, _ = kind if kind is not None else (None, 1.0, 0)
         h = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
         k = L.apply_rope(L._split_heads(L.dense(lp["attn"]["wk"], h),
                                         cfg.num_kv_heads), positions,
-                         cfg.rope_theta)
+                         cfg.rope_theta, freqs, scale)
         v = L._split_heads(L.dense(lp["attn"]["wv"], h), cfg.num_kv_heads)
         return {"k": jnp.pad(k.astype(cfg.jax_dtype), pad),
                 "v": jnp.pad(v.astype(cfg.jax_dtype), pad)}
@@ -331,12 +416,14 @@ def prefill(p: Params, cfg, tokens: Array, max_len: Optional[int] = None
             return T.block(lp, x, positions, cfg), kv
         x, cache["dense"] = L.xscan(scan_d, x, p["dense_layers"])
 
-    def scan_m(x, lp):
-        kv = kv_of(lp, x)
-        x, _ = moe_block(lp, x, positions, cfg)
+    def scan_m(x, inp):
+        lp, kind = inp
+        kv = kv_of(lp, x, kind)
+        x, _ = moe_block(lp, x, positions, cfg, kind)
         return x, kv
 
-    x, cache["moe"] = L.xscan(scan_m, x, p["layers"])
+    kinds = _kinds(cfg, cfg.num_layers - cfg.first_k_dense)
+    x, cache["moe"] = L.xscan(scan_m, x, (p["layers"], kinds))
     logits = T.logits_head(p, x[:, -1:, :], cfg)[:, 0]
     return logits, cache
 
@@ -360,13 +447,15 @@ def decode_step(p: Params, cfg, token: Array, cache: Params, pos: Array
                                               cache["dense"]))
 
     def scan_m(x, inp):
-        lp, c = inp
+        lp, c, kind = inp
         h = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
-        a, c = L.decode_attention(lp["attn"], h, c, pos, cfg)
+        a, c = L.decode_attention(lp["attn"], h, c, pos, cfg, kind)
         x = x + a
-        y, _ = moe_ffn(lp["moe"], L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps),
-                       cfg)
+        y, _ = expert_layer(lp["moe"], L.rmsnorm(lp["mlp_norm"], x,
+                                                 cfg.norm_eps), cfg)
         return x + y, c
 
-    x, new_cache["moe"] = L.xscan(scan_m, x, (p["layers"], cache["moe"]))
+    kinds = _kinds(cfg, cfg.num_layers - cfg.first_k_dense)
+    x, new_cache["moe"] = L.xscan(scan_m, x, (p["layers"], cache["moe"],
+                                              kinds))
     return T.logits_head(p, x, cfg)[:, 0], new_cache

@@ -576,20 +576,28 @@ class Engine:
             or bool(self._reserved.any()) or bool(self._pool)
 
     # -- internals ---------------------------------------------------------
-    def _sample_host(self, logits: Array, stream: int = 0) -> np.ndarray:
+    def _sample_host(self, logits: Array, stream: int = 0,
+                     also: Optional[Array] = None):
         """Host-side sampling (admission first tokens, single-step decode).
         Keyed samplers get ``fold_in(fold_in(key, stream), round)`` —
         stream 0 is the decode stream the fused loop folds on device,
         stream 1 the admission stream — so both decode paths and every
-        block interleaving draw the same tokens."""
+        block interleaving draw the same tokens.  ``also``, a device array
+        the same launch produced, comes back in the same readback: the
+        call then returns ``(tokens, also)``."""
         # the ONE sanctioned device→host sync in the engine: emitted
         # tokens must land in host lists, so the readback is the point
         with self.trace.span(_READBACK_SPAN[stream]):
             if getattr(self.sampler, "takes_key", False):
                 k = jax.random.fold_in(
                     jax.random.fold_in(self._key, stream), self._round)
-                return np.asarray(self.sampler(logits, 1, k))  # dcomlint: disable=J2
-            return np.asarray(self.sampler(logits, 1))  # dcomlint: disable=J2
+                tok = self.sampler(logits, 1, k)
+            else:
+                tok = self.sampler(logits, 1)
+            if also is None:
+                return np.asarray(tok)  # dcomlint: disable=J2
+            tok, also = jax.device_get((tok, also))  # dcomlint: disable=J2
+            return np.asarray(tok), np.asarray(also)
 
     def _stops(self, req: Request) -> frozenset:
         eos = req.eos_id if req.eos_id is not None else self.eos_id

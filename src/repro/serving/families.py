@@ -199,9 +199,10 @@ def _jitted_dkv_prefill(cfg: ArchConfig, backend: str, expansion: int,
     con = _constrain(mesh)
 
     def prefill(p, tk):
-        lg, c = DK.prefill_dkv(p, cfg, tk, rank, tail=tail, exact=exact,
-                               engine=eng)
-        return lg, con(c)
+        # (logits, cache[, expert picks]) — see DK.prefill_dkv
+        lg, c, *picks = DK.prefill_dkv(p, cfg, tk, rank, tail=tail,
+                                       exact=exact, engine=eng)
+        return (lg, con(c), *picks)
 
     return jax.jit(prefill)
 
@@ -504,17 +505,31 @@ class AudioServing(ServingFamily):
 
 @register_family("transformer-dkv")
 class TransformerDKVServing(ServingFamily):
-    """The paper's low-rank decomposed-KV serving path (dense family
-    only): prefill decomposes K/V through the DecomposeEngine, decode
-    contracts through the factors, per-slot dense tails fold back via
-    ``compress_tail``, and ``paged=True`` swaps the slab for
-    ``serving.paged``'s page pools + prefix cache.  Byte-identical to
-    the pre-protocol engine — every method here is the old engine code
-    moved behind the protocol."""
+    """The paper's low-rank decomposed-KV serving path: prefill
+    decomposes K/V through the DecomposeEngine, decode contracts through
+    the factors, per-slot dense tails fold back via ``compress_tail``,
+    and ``paged=True`` swaps the slab for ``serving.paged``'s page pools
+    + prefix cache.  Byte-identical to the pre-protocol engine — every
+    method here is the old engine code moved behind the protocol.
+
+    It serves what ``decomposed_kv.unsupported`` passes: the dense family
+    and drop-free expert models, whose window layers keep rings beside
+    the factorized full layers (slab only: paging holds one kind of
+    per-layer state).  For those the gauge ``serving_dkv_layers{kind}``
+    counts the factorized and window layers, and the counter
+    ``serving_moe_assignments_total{share}`` the token→expert picks of
+    admitted prompts whose expert this chip holds (``held``) or another
+    (``away``), read back with the first tokens."""
     paged_capable = True
 
     def __init__(self, eng, paged: bool = False):
-        assert eng.cfg.family == "dense", "decomposed KV: dense family"
+        from ..models import decomposed_kv as DK
+        why = DK.unsupported(eng.cfg)
+        if why is not None:
+            raise ValueError(f"decomposed KV: {why}")
+        why = DK.paged_unsupported(eng.cfg) if paged else None
+        if why is not None:
+            raise ValueError(why)
         self.eng = eng
         ec = eng.dengine.config
         self._decode_dkv = _jitted_dkv_decode(eng.cfg, eng.mesh)
@@ -524,6 +539,16 @@ class TransformerDKVServing(ServingFamily):
         self._compress_dkv = _jitted_dkv_compress(eng.cfg, eng.dkv_rank,
                                                   eng.mesh)
         self._splice_dkv, _ = _jitted_splices(eng.mesh)
+        reg = eng.obs.registry
+        for kind, n in (("factorized", len(DK.factorized_layers(eng.cfg))),
+                        ("window", len(DK.window_layers(eng.cfg)))):
+            reg.gauge("serving_dkv_layers", "decoder layers by the state "
+                      "the decomposed cache keeps", kind=kind).set(n)
+        self._picks = {share: reg.counter(
+            "serving_moe_assignments_total", "token→expert picks of "
+            "admitted prompts, by whether this chip holds the expert",
+            share=share) for share in ("held", "away")} \
+            if eng.cfg.router_experts else None
         if paged:
             assert eng.admission == "per_slot", "paged serving is per-slot"
             from .paged import PagedDKV
@@ -646,7 +671,8 @@ class TransformerDKVServing(ServingFamily):
             nb = min(_pow2(len(batch)), max(eng.slots, 1))
             toks = eng._toks(batch, nb, plen, lambda j: j)
         with eng.trace.span("admit.launch"):
-            logits, fresh = self._prefill_dkv(eng.params, np.asarray(toks))
+            logits, fresh, *picks = self._prefill_dkv(eng.params,
+                                                      np.asarray(toks))
         eng.stats.prefill_batches += 1
 
         def complete():
@@ -659,13 +685,26 @@ class TransformerDKVServing(ServingFamily):
                     fresh["k_u"].shape[-1], tail=eng.dkv_tail))
             eng.cache = self._splice_dkv(eng.cache, fresh, idx, src)
             eng.rank_eff[slots_idx] = fresh["k_u"].shape[-1]
-            nxt = eng._sample_host(logits, stream=1)[:len(batch)]
-            return nxt, np.full(len(batch), plen, np.int32)
+            if picks:
+                nxt, per = eng._sample_host(logits, stream=1, also=picks[0])
+                self._count_picks(per)
+            else:
+                nxt = eng._sample_host(logits, stream=1)
+            return nxt[:len(batch)], np.full(len(batch), plen, np.int32)
 
         return PrefillTicket(requests=list(batch), slots=list(slots_idx),
                              plen=plen, probe=(logits, fresh),
                              complete=complete, cancel=lambda: None,
                              t_dispatch=time.perf_counter())
+
+    def _count_picks(self, per: np.ndarray) -> None:
+        """Count one admission's token→expert picks (``per``: picks per
+        router expert) as held here or away."""
+        cfg = self.eng.cfg
+        held = int(per[cfg.expert_first:
+                       cfg.expert_first + cfg.num_experts].sum())
+        self._picks["held"].inc(held)
+        self._picks["away"].inc(int(per.sum()) - held)
 
     def _dispatch_paged(self, batch: List[Any], slots_idx: List[int],
                         plen: int,
@@ -866,8 +905,8 @@ class TransformerDKVServing(ServingFamily):
         with eng.trace.span("admit.prepare"):
             toks = eng._toks(batch, eng.slots, plen, lambda j: slots_idx[j])
         with eng.trace.span("admit.launch"):
-            logits, eng.cache = self._prefill_dkv(eng.params,
-                                                  np.asarray(toks))
+            logits, eng.cache, *_ = self._prefill_dkv(eng.params,
+                                                      np.asarray(toks))
         eng.rank_eff[slots_idx] = eng.cache["k_u"].shape[-1]
         return logits
 

@@ -108,3 +108,59 @@ def test_reorth_compiles_at_deepseek_serve_shape(one_chip, side):
     assert (s_pad, h_pad) == (PROMPT, DEEPSEEK_KVW)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 4 * batch * s_pad * h_pad
+
+
+# -- mellum2-12b (16 of 64 experts held): the expert layer and decode ------
+
+MELLUM = get_arch("mellum2-12b").replace(num_experts=16)
+
+
+@pytest.mark.parametrize("tokens", [4 * 3072, 8], ids=["prefill", "decode"])
+def test_held_experts_compile_at_mellum_widths(one_chip, tokens):
+    """The drop-free held-expert layer at the prefill launch of four
+    3072-token prompts and at a decode round of 8 slots: XLA's grouped
+    matmul (``ragged-dot``) for gate, up and down, over 16 held experts of
+    2304 x 896 routed over 64."""
+    from repro.models import moe
+    cfg = MELLUM
+    p = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: moe.moe_ffn_init(jax.random.PRNGKey(0), cfg)))
+    assert p["router"]["w"].shape == (2304, 64)
+    assert p["w_gate"].shape == (16, 2304, 896)
+    x = jax.ShapeDtypeStruct((1, tokens, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(lambda p, x: moe.held_experts_ffn(p, x, cfg)[0]
+                       ).lower(p, x).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 3
+
+
+def test_mixed_decode_block_compiles_at_mellum_widths(one_chip):
+    """The fused 8-step decode block over the mixed cache: 8 slots, 21
+    window rings of 1024 rows beside 7 factorized layers of 3072 prefix
+    rows at rank 64 and a 128-row tail; weights and cache fit one chip's
+    16 GB."""
+    from repro.models import api
+    from repro.models import decomposed_kv as DK
+    from repro.serving import greedy_sampler
+    cfg, b = MELLUM, 8
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(sds, api.abstract_params(cfg))
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: DK.init_cache(cfg, b, 3072, RANK, tail=128)))
+    assert cache["ring"]["k"].shape == (21, b, 1024, 4, 128)
+    assert cache["k_u"].shape == (7, b, 3072, RANK)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def run(p, t, c, pos, fl, n, stops, key, r0):
+        return DK.decode_block_dkv(p, cfg, t, c, pos, fl, n, stops, key, r0,
+                                   sampler=greedy_sampler, max_block=8)
+
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(run, donate_argnums=(2,)).lower(
+        params, i32(b), cache, i32(b), i32(b), i32(), i32(b, 2), key,
+        i32()).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert "ragged-dot-none" in compiled.as_text()
