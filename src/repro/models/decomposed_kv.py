@@ -45,7 +45,13 @@ the full layers' K/V and writes each window layer's last min(len, W) rows;
 decode writes ring row ``pos mod W`` and masks rows outside the window; a
 splice carries both kinds and a fold touches only the factors.  The layers
 scan one PERIOD of the layer pattern at a time (``cfg.full_attn_every``
-layers, unrolled by kind).  Window attention and ring writes sit in the
+layers, unrolled by kind).  The expert weights are not scanned: the
+grouped matmul (``ragged_dot``) is a custom call that takes no fused
+slice, so a per-period or per-layer slice of them would be copied out
+before every call.  The period body gets the whole [L, held, …] stacks
+as loop constants and hands ``moe.held_experts_ffn`` the layer's index;
+it views a stack as [L·held, …] groups (a bitcast) and puts the layer's
+runs at the layer's groups.  Window attention and ring writes sit in the
 named scope ``dcom.window``, the expert layer in ``dcom.moe``.  The dense
 family's arrays and programs are those of the pure-dense path.
 
@@ -527,10 +533,22 @@ def _ring_attention(q: Array, rk: Array, rv: Array, pos: Array,
     return L._gqa_pv(pr, rv).reshape(b, 1, cfg.num_heads * hd)
 
 
-def _expert_mlp(lp, x, cfg):
+def _expert_stacks(layers: Params) -> Tuple[Params, Params]:
+    """Layer weights [L, …] → (the same without the expert weights, the
+    expert weights' whole stacks [L, held, …]).  The stacks stay out of
+    the period scan's ``xs``: a scan would slice them per period and per
+    layer, and the grouped matmul, a custom call, takes no fused slice,
+    so each slice would be a copy."""
+    rest = dict(layers["moe"])
+    stacks = {w: rest.pop(w) for w in ("w_gate", "w_up", "w_down")}
+    return dict(layers, moe=rest), stacks
+
+
+def _expert_mlp(lp, x, cfg, stacks: Params, layer: Array):
     from . import moe
     h = T._norm(lp["mlp_norm"], x, cfg)
-    y, _, picks = moe.held_experts_ffn(lp["moe"], h, cfg)
+    y, _, picks = moe.held_experts_ffn(dict(lp["moe"], **stacks), h, cfg,
+                                       layer)
     return x + y, picks
 
 
@@ -557,8 +575,10 @@ def _prefill_mixed(p: Params, cfg, tokens: Array, rank: int, tail: int,
             cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0,
             cfg.jax_dtype)
 
-    def period(carry, lp):
-        x, picks = carry
+    layers, stacks = _expert_stacks(p["layers"])
+
+    def period(carry, inp):
+        (x, picks), (lp, i) = carry, inp
         full, ring = [], []
         for j, kind in enumerate(kinds):
             lj = _at(lp, j)
@@ -577,7 +597,7 @@ def _prefill_mixed(p: Params, cfg, tokens: Array, rank: int, tail: int,
                              "v": v.astype(cfg.jax_dtype)})
             with jax.named_scope("dcom.forward"):
                 x = x + L.dense(lj["attn"]["wo"], a)
-            x, e = _expert_mlp(lj, x, cfg)
+            x, e = _expert_mlp(lj, x, cfg, stacks, i * len(kinds) + j)
             with jax.named_scope("dcom.moe"):
                 picks = picks.at[e].add(real[:, None])
         stack = lambda rows: jax.tree_util.tree_map(
@@ -586,7 +606,8 @@ def _prefill_mixed(p: Params, cfg, tokens: Array, rank: int, tail: int,
 
     picks0 = jnp.zeros((cfg.router_width,), jnp.int32)
     (x, picks), (full, ring) = L.xscan(
-        period, (x, picks0), _by_period(p["layers"], len(kinds)))
+        period, (x, picks0), (_by_period(layers, len(kinds)),
+                              jnp.arange(cfg.num_layers // len(kinds))))
     with jax.named_scope("dcom.forward"):
         logits = T.logits_head(p, x[:, -1:, :], cfg)[:, 0]
     full = _flat_periods(full)                       # [nf, B, S, kvh, hd]
@@ -630,9 +651,10 @@ def _decode_step_mixed(p: Params, cfg, token: Array, cache: Params,
     fac = {k: _by_period(cache[k], nfp)
            for k in ("k_u", "k_vt", "v_u", "v_vt", "tail")}
     rings = _by_period(cache["ring"], n - nfp) if n > nfp else None
+    layers, stacks = _expert_stacks(p["layers"])
 
     def period(x, inp):
-        lp, f, rg = inp
+        lp, i, f, rg = inp
         tails, new_rings = [], []
         fi = wi = 0
         for j, kind in enumerate(kinds):
@@ -656,13 +678,14 @@ def _decode_step_mixed(p: Params, cfg, token: Array, cache: Params,
                 tails.append(t)
                 fi += 1
             x = x + L.dense(lj["attn"]["wo"], a.astype(x.dtype))
-            x, _ = _expert_mlp(lj, x, cfg)
+            x, _ = _expert_mlp(lj, x, cfg, stacks, i * n + j)
         stack = lambda rows: jax.tree_util.tree_map(
             lambda *a: jnp.stack(a), *rows) if rows else None
         return x, (stack(tails), stack(new_rings))
 
     x, (tails, new_rings) = L.xscan(
-        period, x, (_by_period(p["layers"], n), fac, rings))
+        period, x, (_by_period(layers, n), jnp.arange(cfg.num_layers // n),
+                    fac, rings))
     new_cache = dict(cache)
     new_cache["tail"] = _flat_periods(tails)
     if new_rings is not None:

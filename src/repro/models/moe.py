@@ -143,7 +143,7 @@ def route(p: Params, xf: Array, cfg) -> Tuple[Array, Array, Array]:
     return gates, eidx, probs
 
 
-def held_experts_ffn(p: Params, x: Array, cfg
+def held_experts_ffn(p: Params, x: Array, cfg, layer: Optional[Array] = None
                      ) -> Tuple[Array, Array, Array]:
     """The held experts' share of the expert layer, dropping nothing.
 
@@ -155,7 +155,15 @@ def held_experts_ffn(p: Params, x: Array, cfg
     the runs (``jax.lax.ragged_dot``; rows past the last run belong to
     no group and give zeros), and each token sums its held
     picks' outputs times their gates.  Everything is in the named scope
-    ``dcom.moe``."""
+    ``dcom.moe``.
+
+    With ``layer`` (a traced int) ``p["w_gate"|"w_up"|"w_down"]`` are the
+    whole stacks of every layer, [L, held, …] or [L·held, …], and the
+    layer's runs sit at groups ``layer·held`` … ``+ held``, every other
+    group empty.  A layer scan passes the stacks as loop constants this
+    way because the grouped matmul is a custom call that takes no fused
+    slice: a stack sliced per layer would be copied out before every
+    call.  Without ``layer`` the weights are one layer's, at offset 0."""
     with jax.named_scope("dcom.moe"):
         b, s, h = x.shape
         t, k, held = b * s, cfg.top_k, cfg.num_experts
@@ -165,16 +173,26 @@ def held_experts_ffn(p: Params, x: Array, cfg
         ce = jnp.mean(jax.nn.one_hot(eidx[:, 0], e, dtype=jnp.float32), 0)
         aux = e * jnp.sum(jnp.mean(probs, axis=0) * ce) * AUX_LOSS_COEF
 
+        w_gate, w_up, w_down = (p[w].reshape((-1,) + p[w].shape[-2:])
+                                for w in ("w_gate", "w_up", "w_down"))
+        groups = w_gate.shape[0]                     # held, or L·held
+        first = 0 if layer is None else layer * held
         local = (eidx - cfg.expert_first).reshape(t * k)
         mine = (local >= 0) & (local < held)
-        run = jnp.where(mine, local, held)           # away picks sort last
+        run = jnp.where(mine, first + local, groups)  # away picks sort last
         order = jnp.argsort(run, stable=True)
-        sizes = jnp.bincount(run, length=held + 1)[:held].astype(jnp.int32)
-        rows = xf[order // k]                        # [T·k, H] in run order
+        sizes = jnp.bincount(run, length=groups + 1)[:groups].astype(
+            jnp.int32)
+        # [T·k, H] in run order.  A per-row dynamic index, not the plain
+        # row gather xf[order // k]: for that one the TPU compiler can ask
+        # more scoped VMEM than its limit and refuse the program (the
+        # prefill of one 1536-token prompt at mellum2-12b's widths).
+        rows = jax.vmap(lambda i: jax.lax.dynamic_index_in_dim(
+            xf, i, keepdims=False))(order // k)
         act = L.activation_fn(cfg.activation)
-        hid = act(jax.lax.ragged_dot(rows, p["w_gate"], sizes)) \
-            * jax.lax.ragged_dot(rows, p["w_up"], sizes)
-        out = jax.lax.ragged_dot(hid, p["w_down"], sizes)     # [T·k, H]
+        hid = act(jax.lax.ragged_dot(rows, w_gate, sizes)) \
+            * jax.lax.ragged_dot(rows, w_up, sizes)
+        out = jax.lax.ragged_dot(hid, w_down, sizes)          # [T·k, H]
         out = out[jnp.argsort(order)].astype(jnp.float32)     # pick order
         g = jnp.where(mine, gates.reshape(t * k), 0.0)
         y = jnp.sum(jnp.where(mine[:, None], out, 0.0).reshape(t, k, h)

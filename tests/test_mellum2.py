@@ -124,6 +124,38 @@ def test_no_token_is_dropped_by_its_batch():
     assert bool(jnp.all(jnp.abs(y.astype(jnp.float32)).sum(-1) > 0))
 
 
+@pytest.mark.parametrize("batch", ["spread", "one_token", "all_away"])
+@pytest.mark.parametrize("layer", [0, 3, 7])
+def test_the_stacked_call_is_the_layers_own(layer, batch):
+    """Handed every layer's expert weights as one stack and a traced layer
+    index, the held layer computes what the one-layer call computes on
+    that layer's weights: with picks on every held expert, with one token
+    (held experts that get no pick), and with every pick away."""
+    cfg, n = F32, F32.num_layers
+    per = [moe.moe_ffn_init(jax.random.PRNGKey(10 + i), cfg)
+           for i in range(n)]
+    p = per[layer]
+    shape = (1, 1) if batch == "one_token" else (4, 16)
+    x = jax.random.normal(jax.random.PRNGKey(11), shape + (cfg.d_model,))
+    if batch == "all_away":            # experts 4 and 5 are held elsewhere
+        p = dict(p, router={"w": p["router"]["w"].at[:, 4:6].add(50.0)})
+        x = jnp.abs(x)
+    stacks = {w: jnp.stack([q[w] for q in per])
+              for w in ("w_gate", "w_up", "w_down")}
+    assert stacks["w_gate"].shape == (n, cfg.num_experts, cfg.d_model,
+                                      cfg.moe_d_ff)
+    stacked = jax.jit(lambda p, x, i: moe.held_experts_ffn(p, x, cfg, i))
+    got, aux, picks = stacked(dict(p, **stacks), x, jnp.int32(layer))
+    want, aux1, picks1 = moe.held_experts_ffn(p, x, cfg)
+    held = np.unique(np.asarray(picks)[np.asarray(picks) < cfg.num_experts])
+    assert len(held) == {"spread": 4, "one_token": 1, "all_away": 0}[batch]
+    np.testing.assert_array_equal(np.asarray(picks), np.asarray(picks1))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(float(aux), float(aux1), rtol=1e-6)
+    assert (np.asarray(got) == 0).all() == (batch == "all_away")
+
+
 def test_ring_wraps_around_past_the_window():
     """At full rank (exact SVD) the decomposed path is the dense-KV window
     path: prefill 24 rows into a 16-row ring, then decode 20 tokens, so

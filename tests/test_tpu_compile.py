@@ -9,6 +9,7 @@ inside a module fixture (never at import): only one process may load the
 TPU library, and the suite runs under several pytest-xdist workers.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,4 +164,47 @@ def test_mixed_decode_block_compiles_at_mellum_widths(one_chip):
         i32()).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
-    assert "ragged-dot-none" in compiled.as_text()
+    text = compiled.as_text()
+    assert "ragged-dot-none" in text
+    assert _expert_weight_copies(text, cfg) == []
+
+
+#: an instruction of compiled HLO text: its name, result shape and opcode
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?(\S+)\s*=\s*[a-z][a-z0-9]*"
+                    r"\[([0-9,]*)\]\S*\s+([a-z][a-z0-9-]*)\(", re.M)
+
+
+def _expert_weight_copies(text: str, cfg):
+    """The instructions of a compiled program that make an array of expert
+    weights ([…, H, F] or […, F, H]) other than by naming it: anything but
+    a parameter, a tuple element or a bitcast is a copy of the weights."""
+    h, f = cfg.d_model, cfg.moe_d_ff
+    found = []
+    for name, dims, op in _INSTR.findall(text):
+        tail = tuple(int(d) for d in dims.split(",")[-2:] if d)
+        if tail in ((h, f), (f, h)) and op not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            found.append(f"{name} = [{dims}] {op}")
+    return found
+
+
+@pytest.mark.parametrize("batch, prompt", [(1, 1536), (4, 3072)])
+def test_mixed_prefill_compiles_at_mellum_widths(one_chip, batch, prompt):
+    """The mixed prefill over two periods (8 layers) at published widths,
+    for one 1536-token prompt (whose row gather the compiler once refused
+    for scoped VMEM) and the cell's largest admission: the grouped
+    matmuls read the layer stacks of expert weights where they lie, with
+    no slice of them copied out."""
+    from repro.models import api
+    from repro.models import decomposed_kv as DK
+    cfg = MELLUM.replace(num_layers=8)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(sds, api.abstract_params(cfg))
+    assert params["layers"]["moe"]["w_down"].shape == (8, 16, 896, 2304)
+    toks = jax.ShapeDtypeStruct((batch, prompt), jnp.int32,
+                                sharding=one_chip)
+    compiled = jax.jit(lambda p, t: DK.prefill_dkv(p, cfg, t, RANK)
+                       ).lower(params, toks).compile()
+    text = compiled.as_text()
+    assert "ragged-dot-none" in text
+    assert _expert_weight_copies(text, cfg) == []
