@@ -35,25 +35,29 @@ tail rows sit in fixed-size page pools addressed by per-slot block tables,
 enabling refcounted SHARING of frozen prefix pages across requests
 (serving.paged) while replaying the slab arithmetic bit-for-bit.
 
-Models that mix layer kinds (mellum2: sliding-window and full-attention
-layers, a drop-free held-expert MLP) keep two kinds of per-layer state in
-one cache: the factors and dense tail above for the full-attention layers
-only (``factorized_layers``), and for each window layer a RING of its last
-``sliding_window`` RoPE'd K/V rows, ``ring.k``/``ring.v`` [nw, B, W, kvh,
-hd] — the row of position p sits at ``p mod W``.  Prefill factorizes only
-the full layers' K/V and writes each window layer's last min(len, W) rows;
-decode writes ring row ``pos mod W`` and masks rows outside the window; a
-splice carries both kinds and a fold touches only the factors.  The layers
+Layer kinds.  Each layer attends by its kind (``cfg.layer_kinds``): a
+``"full"`` layer through its factors and dense tail as above, a
+``"window"`` layer to a RING of its last ``sliding_window`` RoPE'd K/V
+rows, ``ring.k``/``ring.v`` [nw, B, W, kvh, hd] — the row of position p
+sits at ``p mod W``.  The cache holds factors and a tail for the full
+layers only (``factorized_layers``) and a ring for each window layer
+(``window_layers``).  Prefill factorizes only the full layers' K/V and
+writes each window layer's last min(len, W) rows; decode writes ring row
+``pos mod W`` and masks rows outside the window; a splice carries both
+kinds and a fold touches only the factors.  The MLP follows the
+configuration: a dense MLP, or, with ``router_experts`` set, the
+drop-free held-expert layer.  One prefill body and one decode-step body
 scan one PERIOD of the layer pattern at a time (``cfg.full_attn_every``
-layers, unrolled by kind).  The expert weights are not scanned: the
-grouped matmul (``ragged_dot``) is a custom call that takes no fused
-slice, so a per-period or per-layer slice of them would be copied out
-before every call.  The period body gets the whole [L, held, …] stacks
-as loop constants and hands ``moe.held_experts_ffn`` the layer's index;
-it views a stack as [L·held, …] groups (a bitcast) and puts the layer's
-runs at the layer's groups.  Window attention and ring writes sit in the
-named scope ``dcom.window``, the expert layer in ``dcom.moe``.  The dense
-family's arrays and programs are those of the pure-dense path.
+layers where there are windows, else one), unrolled by kind: a dense
+model is the period ``("full",)`` with a dense MLP.  The expert weights
+are not scanned: the grouped matmul (``ragged_dot``) is a custom call
+that takes no fused slice, so a per-period or per-layer slice of them
+would be copied out before every call.  The period body gets the whole
+[L, held, …] stacks as loop constants and hands
+``moe.held_experts_ffn`` the layer's index; it views a stack as
+[L·held, …] groups (a bitcast) and puts the layer's runs at the layer's
+groups.  Window attention and ring writes sit in the named scope
+``dcom.window``, the expert layer in ``dcom.moe``.
 
 Sharding invariants (mesh-parallel serving, DESIGN.md §9): every op in this
 module is BATCH-LOCAL — the tail write is a vmapped
@@ -67,6 +71,7 @@ cost 2× the saved U reads).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -91,31 +96,30 @@ def unsupported(cfg) -> Optional[str]:
     serves the dense family, and expert models whose experts run drop-free
     (``router_experts`` set, no leading dense layers): the capacity-factor
     ``moe_ffn`` drops tokens by what they are batched with, so admissions
-    of different batch sizes would serve different tokens."""
-    if cfg.family == "dense":
-        return None
-    if cfg.family == "moe" and cfg.router_experts and not cfg.first_k_dense:
-        if "full" not in _period_kinds(cfg):
-            return f"{cfg.name} has no full-attention layer to factorize"
-        return None
-    return (f"decomposed KV serves the dense family and expert models with "
-            f"a drop-free held-expert layer (router_experts); {cfg.name} is "
-            f"family {cfg.family!r}")
+    of different batch sizes would serve different tokens.  Either needs
+    a full-attention layer to factorize."""
+    if cfg.family != "dense" and not (
+            cfg.family == "moe" and cfg.router_experts
+            and not cfg.first_k_dense):
+        return (f"decomposed KV serves the dense family and expert models "
+                f"with a drop-free held-expert layer (router_experts); "
+                f"{cfg.name} is family {cfg.family!r}")
+    if "full" not in _period_kinds(cfg):
+        return f"{cfg.name} has no full-attention layer to factorize"
+    return None
 
 
 def paged_unsupported(cfg) -> Optional[str]:
     """Why ``paged=True`` cannot serve ``cfg`` (None: it can): the page
-    pools hold one kind of per-layer state for a dense layer stack."""
-    if not _mixed(cfg):
+    pools hold factors and a dense tail for a stack of full-attention
+    layers with dense MLPs."""
+    if not window_layers(cfg) and not cfg.router_experts:
         return None
-    return (f"paged decomposed KV holds one kind of per-layer state for a "
-            f"dense layer stack; {cfg.name} ({len(window_layers(cfg))} "
-            f"window layers beside {len(factorized_layers(cfg))} factorized "
-            f"ones, expert MLPs) is served on the slab: paged=False")
-
-
-def _mixed(cfg) -> bool:
-    return cfg.family != "dense"
+    return (f"paged decomposed KV holds factors and a dense tail for a "
+            f"stack of full-attention layers with dense MLPs; {cfg.name} "
+            f"({len(window_layers(cfg))} window layers beside "
+            f"{len(factorized_layers(cfg))} factorized ones, expert MLPs) "
+            f"is served on the slab: paged=False")
 
 
 def _period_kinds(cfg) -> Tuple[str, ...]:
@@ -141,70 +145,68 @@ def window_layers(cfg) -> Tuple[int, ...]:
 
 def init_cache(cfg, batch: int, frozen_len: int, rank: int,
                tail: int = TAIL) -> Params:
-    kvw = cfg.num_kv_heads * cfg.resolved_head_dim
-    nl, dt = cfg.num_layers, cfg.jax_dtype
-    if _mixed(cfg):
-        nl = len(factorized_layers(cfg))
+    kvh, hd, dt = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.jax_dtype
+    nf, nw = len(factorized_layers(cfg)), len(window_layers(cfg))
     z = jnp.zeros
+    rows = lambda n, t: z((n, batch, t, kvh, hd), dt)
     cache = {
-        "k_u": z((nl, batch, frozen_len, rank), dt),
-        "k_vt": z((nl, batch, rank, kvw), dt),
-        "v_u": z((nl, batch, frozen_len, rank), dt),
-        "v_vt": z((nl, batch, rank, kvw), dt),
-        "tail": {"k": z((nl, batch, tail, cfg.num_kv_heads,
-                         cfg.resolved_head_dim), dt),
-                 "v": z((nl, batch, tail, cfg.num_kv_heads,
-                         cfg.resolved_head_dim), dt)},
+        "k_u": z((nf, batch, frozen_len, rank), dt),
+        "k_vt": z((nf, batch, rank, kvh * hd), dt),
+        "v_u": z((nf, batch, frozen_len, rank), dt),
+        "v_vt": z((nf, batch, rank, kvh * hd), dt),
+        "tail": {"k": rows(nf, tail), "v": rows(nf, tail)},
     }
-    nw = len(window_layers(cfg)) if _mixed(cfg) else 0
     if nw:
-        shape = (nw, batch, cfg.sliding_window, cfg.num_kv_heads,
-                 cfg.resolved_head_dim)
-        cache["ring"] = {"k": z(shape, dt), "v": z(shape, dt)}
+        cache["ring"] = {"k": rows(nw, cfg.sliding_window),
+                         "v": rows(nw, cfg.sliding_window)}
     return cache
 
 
-def prefill_dkv(p: Params, cfg, tokens: Array, rank: int,
-                tail: int = TAIL, exact: bool = False,
-                engine: Optional[DecomposeEngine] = None
-                ) -> Tuple[Array, Params]:
-    """Prefill that emits a decomposed KV cache.  A model of mixed layer
-    kinds (:func:`_prefill_mixed`) also returns a third array: the
-    token→expert picks of the prompt rows, per router expert.
+# ---------------------------------------------------------------------------
+# The layer body, by kind
+# ---------------------------------------------------------------------------
 
-    K/V factorization goes through :meth:`DecomposeEngine.decompose_kv`
-    (Lanczos via the engine's backend; ``exact`` switches to direct SVD for
-    r near full rank, where floating-point Lanczos loses trailing
-    directions — §2.3: Lanczos is the small-rank algorithm).
+def _embed(p: Params, cfg, tokens: Array) -> Array:
+    return p["embed"]["w"][tokens] * jnp.asarray(
+        cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0, cfg.jax_dtype)
 
-    The forward pass and the factorizations sit in the named scopes
-    ``dcom.forward`` and ``dcom.lanczos``: compiled HLO carries them in each
-    instruction's ``op_name``, so a device trace splits admission by them.
-    """
-    if rank < 1:
-        raise ValueError(f"prefill_dkv needs rank >= 1, got {rank} "
-                         "(is the engine's kv_rank configured?)")
-    engine = engine or _DEFAULT_ENGINE
-    if _mixed(cfg):
-        return _prefill_mixed(p, cfg, tokens, rank, tail, exact, engine)
-    b, s = tokens.shape
-    with jax.named_scope("dcom.forward"):
-        logits, dense_cache = T.prefill(p, cfg, tokens, s)
-    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
 
-    def one(kv):
-        with jax.named_scope("dcom.lanczos"):
-            flat = kv.reshape(cfg.num_layers * b, s, kvh * hd)
-            u, vt = engine.decompose_kv(flat, rank, exact=exact)
-            r_eff = u.shape[-1]      # rank caps at min(s, kvw) (exact SVD)
-            return (u.reshape(cfg.num_layers, b, s, r_eff),
-                    vt.reshape(cfg.num_layers, b, r_eff, kvh * hd))
+def _by_period(tree, n: int):
+    """Leaves [L, …] → [L / n, n, …]: one row per period of n layers.  A
+    period of one layer is scanned as it lies: that reshape would cost
+    the compiled program slices and copies of whole weight stacks."""
+    if n == 1:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), tree)
 
-    k_u, k_vt = one(dense_cache["k"])
-    v_u, v_vt = one(dense_cache["v"])
-    z = jnp.zeros((cfg.num_layers, b, tail, kvh, hd), cfg.jax_dtype)
-    return logits, {"k_u": k_u, "k_vt": k_vt, "v_u": v_u, "v_vt": v_vt,
-                    "tail": {"k": z, "v": z}}
+
+def _at(row, j: int, n: int):
+    """Layer j of a period row of n layers (laid out by _by_period)."""
+    return row if n == 1 else jax.tree_util.tree_map(lambda a: a[j], row)
+
+
+def _stack(rows):
+    """Per-layer trees → one tree of [len(rows), …] leaves (None: none)."""
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *rows) \
+        if rows else None
+
+
+def _flat_periods(tree):
+    """Leaves [P, n, …] → [P·n, …]."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), tree)
+
+
+def _qkv(lp, h, cfg, positions, rope):
+    """Projections of one layer, q and k rotated by the kind's RoPE."""
+    freqs, scale = rope
+    q = L._split_heads(L.dense(lp["attn"]["wq"], h), cfg.num_heads)
+    k = L._split_heads(L.dense(lp["attn"]["wk"], h), cfg.num_kv_heads)
+    v = L._split_heads(L.dense(lp["attn"]["wv"], h), cfg.num_kv_heads)
+    q = L.apply_rope(q, positions, cfg.rope_theta, freqs, scale)
+    k = L.apply_rope(k, positions, cfg.rope_theta, freqs, scale)
+    return q, k, v
 
 
 def _frozen_vec(frozen_len, pos: Array) -> Array:
@@ -212,92 +214,267 @@ def _frozen_vec(frozen_len, pos: Array) -> Array:
     return jnp.broadcast_to(jnp.asarray(frozen_len, jnp.int32), pos.shape)
 
 
-def _lowrank_attention(q: Array, c: Params, tail_kv: Params,
-                       pos: Array, frozen_len: Array, cfg) -> Array:
-    """q [B, 1, nh, d]; low-rank prefix + dense tail → out [B, 1, nh·d].
+def _lowrank_attention(q: Array, c: Params, recent: Params, q_pos: Array,
+                       frozen_len: Array, cfg) -> Array:
+    """q [B, S, nh, d] at positions q_pos [B, S]; low-rank prefix + dense
+    recent rows → out [B, S, nh·d].
 
-    ``frozen_len`` is per-slot [B]: prefix rows at or beyond a slot's
-    frozen_len are zero in U but still produce score 0 (not −inf) through
-    the factors, so they are masked out of the softmax explicitly.
+    ``recent`` k/v [B, R, kvh, d] hold the rows after the prefix: row j
+    is position ``frozen_len + j``, visible to a query where that is at
+    most its position (decode: the tail, S = 1; a prefix-hit prefill: the
+    suffix itself, causally).  ``frozen_len`` is per-slot [B]: prefix rows
+    at or beyond a slot's frozen_len are zero in U but still produce score
+    0 (not −inf) through the factors, so they are masked out of the
+    softmax explicitly.
     """
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     g = nh // kvh
-    b = q.shape[0]
+    b, s = q.shape[:2]
     scale = hd ** -0.5
-    qg = q[:, 0].reshape(b, kvh, g, hd).astype(jnp.float32)
+    qg = q.reshape(b, s, kvh, g, hd).astype(jnp.float32)
     t_pre = c["k_u"].shape[1]                     # static prefix row count
 
     # ---- prefix scores through the factors ------------------------------
     k_vt = c["k_vt"].astype(jnp.float32).reshape(b, -1, kvh, hd)
-    inner = jnp.einsum("bkgd,brkd->bkgr", qg, k_vt)          # [B,kvh,g,r]
-    sc_pre = jnp.einsum("bkgr,btr->bkgt", inner,
+    inner = jnp.einsum("bskgd,brkd->bskgr", qg, k_vt)       # [B,S,kvh,g,r]
+    sc_pre = jnp.einsum("bskgr,btr->bskgt", inner,
                         c["k_u"].astype(jnp.float32)) * scale
     pre_valid = jnp.arange(t_pre)[None, :] < frozen_len[:, None]   # [B,T]
-    sc_pre = jnp.where(pre_valid[:, None, None, :], sc_pre, -1e30)
+    sc_pre = jnp.where(pre_valid[:, None, None, None, :], sc_pre, -1e30)
 
-    # ---- tail scores (exact) ---------------------------------------------
-    tk = tail_kv["k"].astype(jnp.float32)                     # [B,tl,kvh,hd]
-    sc_tail = jnp.einsum("bkgd,btkd->bkgt", qg, tk) * scale
-    tail_pos = frozen_len[:, None] + jnp.arange(tk.shape[1])[None, :]
-    valid = tail_pos <= pos[:, None]                          # [B, tl]
-    sc_tail = jnp.where(valid[:, None, None, :], sc_tail, -1e30)
+    # ---- recent-row scores (exact) ----------------------------------------
+    rk = recent["k"].astype(jnp.float32)                      # [B,R,kvh,hd]
+    sc_rec = jnp.einsum("bskgd,btkd->bskgt", qg, rk) * scale
+    row_pos = frozen_len[:, None] + jnp.arange(rk.shape[1])[None, :]
+    valid = row_pos[:, None, :] <= q_pos[:, :, None]          # [B, S, R]
+    sc_rec = jnp.where(valid[:, :, None, None, :], sc_rec, -1e30)
 
     # ---- joint softmax -----------------------------------------------------
-    sc = jnp.concatenate([sc_pre, sc_tail], axis=-1)
-    pr = jax.nn.softmax(sc, axis=-1)
-    p_pre, p_tail = pr[..., :t_pre], pr[..., t_pre:]
+    pr = jax.nn.softmax(jnp.concatenate([sc_pre, sc_rec], axis=-1), axis=-1)
+    p_pre, p_rec = pr[..., :t_pre], pr[..., t_pre:]
 
     # ---- PV through the factors -------------------------------------------
-    tmp = jnp.einsum("bkgt,btr->bkgr", p_pre,
+    tmp = jnp.einsum("bskgt,btr->bskgr", p_pre,
                      c["v_u"].astype(jnp.float32))
     v_vt = c["v_vt"].astype(jnp.float32).reshape(b, -1, kvh, hd)
-    out = jnp.einsum("bkgr,brkd->bkgd", tmp, v_vt)
-    out = out + jnp.einsum("bkgt,btkd->bkgd", p_tail,
-                           tail_kv["v"].astype(jnp.float32))
-    return out.reshape(b, 1, nh * hd)
+    out = jnp.einsum("bskgr,brkd->bskgd", tmp, v_vt)
+    out = out + jnp.einsum("bskgt,btkd->bskgd", p_rec,
+                           recent["v"].astype(jnp.float32))
+    return out.reshape(b, s, nh * hd)
+
+
+def _ring_of(a: Array, w: int) -> Array:
+    """The ring of a prompt's rows a [B, S, …]: its last min(S, w) rows,
+    the row of position p at ``p mod w`` (zeros where S < w)."""
+    s = a.shape[1]
+    if s < w:
+        return jnp.pad(a, [(0, 0), (0, w - s)] + [(0, 0)] * (a.ndim - 2))
+    return jnp.roll(a[:, s - w:], s % w, axis=1)
+
+
+def _ring_attention(q: Array, rk: Array, rv: Array, pos: Array,
+                    cfg) -> Array:
+    """One-token attention over a window ring: q [B, 1, nh, d], ring
+    k/v [B, W, kvh, d] → [B, 1, nh·d].  Ring row j holds position
+    ``pos − ((pos − j) mod W)``, valid where that is not negative."""
+    b, w = rk.shape[:2]
+    hd = cfg.resolved_head_dim
+    j = jnp.arange(w)[None, :]
+    row_pos = pos[:, None] - (pos[:, None] - j) % w              # [B, W]
+    sc = L._gqa_scores(q, rk) * (hd ** -0.5)                     # [B,nh,1,W]
+    sc = jnp.where((row_pos >= 0)[:, None, None, :], sc, -1e30)
+    pr = jax.nn.softmax(sc, axis=-1).astype(rv.dtype)
+    return L._gqa_pv(pr, rv).reshape(b, 1, cfg.num_heads * hd)
+
+
+def _expert_stacks(cfg, layers: Params) -> Tuple[Params, Optional[Params]]:
+    """Layer weights [L, …] → (the same without the expert weights, the
+    expert weights' whole stacks [L, held, …]; None without held
+    experts).  The stacks stay out of the period scan's ``xs``: a scan
+    would slice them per period and per layer, and the grouped matmul, a
+    custom call, takes no fused slice, so each slice would be a copy."""
+    if not cfg.router_experts:
+        return layers, None
+    rest = dict(layers["moe"])
+    stacks = {w: rest.pop(w) for w in ("w_gate", "w_up", "w_down")}
+    return dict(layers, moe=rest), stacks
+
+
+def _mlp(lp, x, cfg, stacks: Optional[Params], layer: Array):
+    """x plus the layer's MLP, and the token→expert picks: the dense MLP
+    (in ``dcom.forward``; no picks) without expert ``stacks``, else the
+    held experts on the whole stacks at the layer's group offset."""
+    if stacks is None:
+        with jax.named_scope("dcom.forward"):
+            return x + L.mlp(lp["mlp"], T._norm(lp["mlp_norm"], x, cfg),
+                             cfg.activation), None
+    from . import moe
+    h = T._norm(lp["mlp_norm"], x, cfg)
+    y, _, picks = moe.held_experts_ffn(dict(lp["moe"], **stacks), h, cfg,
+                                       layer)
+    return x + y, picks
+
+
+# ---------------------------------------------------------------------------
+# Prefill and decode
+# ---------------------------------------------------------------------------
+
+def prefill_dkv(p: Params, cfg, tokens: Array, rank: int,
+                tail: int = TAIL, exact: bool = False,
+                engine: Optional[DecomposeEngine] = None
+                ) -> Tuple[Array, Params]:
+    """Prefill that emits a decomposed KV cache: (logits [B, V], cache),
+    and for a model with held experts a third array, picks [router width]
+    int32: the token→expert picks of the rows whose token is not the
+    engine's pad id 0, summed over layers.
+
+    The forward pass sits in the named scope ``dcom.forward`` (norms,
+    projections, full attention, dense MLP, head), beside ``dcom.window``
+    (window attention and the ring write) and ``dcom.moe``; then the full
+    layers' K and V, and only theirs, go through
+    :meth:`DecomposeEngine.decompose_kv` in ``dcom.lanczos`` (Lanczos via
+    the engine's backend; ``exact`` switches to direct SVD for r near full
+    rank, where floating-point Lanczos loses trailing directions — §2.3:
+    Lanczos is the small-rank algorithm).  Compiled HLO carries the scopes
+    in each instruction's ``op_name``, so a device trace splits admission
+    by them.
+    """
+    if rank < 1:
+        raise ValueError(f"prefill_dkv needs rank >= 1, got {rank} "
+                         "(is the engine's kv_rank configured?)")
+    engine = engine or _DEFAULT_ENGINE
+    b, s = tokens.shape
+    kinds = _period_kinds(cfg)
+    n = len(kinds)
+    ropes = {k: L.rope_of(cfg, k) for k in set(kinds)}
+    w = cfg.sliding_window
+    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    positions = jnp.broadcast_to(jnp.arange(s), tokens.shape)
+    with jax.named_scope("dcom.forward"):
+        x = _embed(p, cfg, tokens)
+    layers, stacks = _expert_stacks(cfg, p["layers"])
+    real = (tokens != 0).reshape(-1).astype(jnp.int32)          # [B·S]
+
+    def period(carry, inp):
+        (x, picks), (lp, i) = carry, inp
+        full, ring = [], []
+        for j, kind in enumerate(kinds):
+            lj = _at(lp, j, n)
+            with jax.named_scope("dcom.forward"):
+                h = T._norm(lj["attn_norm"], x, cfg)
+                q, k, v = _qkv(lj, h, cfg, positions, ropes[kind])
+            if kind == "window":
+                with jax.named_scope("dcom.window"):
+                    a = L.attend(q, k, v, positions, window=w,
+                                 out_dtype=x.dtype)
+                    ring.append({"k": _ring_of(k, w), "v": _ring_of(v, w)})
+            else:
+                with jax.named_scope("dcom.forward"):
+                    a = L.attend(q, k, v, positions, out_dtype=x.dtype)
+                full.append({"k": k.astype(cfg.jax_dtype),
+                             "v": v.astype(cfg.jax_dtype)})
+            with jax.named_scope("dcom.forward"):
+                x = x + L.dense(lj["attn"]["wo"], a)
+            x, e = _mlp(lj, x, cfg, stacks, i * n + j)
+            if e is not None:
+                with jax.named_scope("dcom.moe"):
+                    picks = picks.at[e].add(real[:, None])
+        return (x, picks), (_stack(full), _stack(ring))
+
+    picks0 = None if stacks is None \
+        else jnp.zeros((cfg.router_width,), jnp.int32)
+    # The scan's own ops (weight slices, K/V row writes) are forward
+    # work.  A trace reader takes an op's outermost dcom scope, so the
+    # scan is scoped only where no window or expert scope nests inside.
+    nested = stacks is not None or "window" in kinds
+    with contextlib.nullcontext() if nested \
+            else jax.named_scope("dcom.forward"):
+        (x, picks), (full, ring) = L.xscan(
+            period, (x, picks0), (_by_period(layers, n),
+                                  jnp.arange(cfg.num_layers // n)))
+    with jax.named_scope("dcom.forward"):
+        logits = T.logits_head(p, x[:, -1:, :], cfg)[:, 0]
+    full = _flat_periods(full)                       # [nf, B, S, kvh, hd]
+    nf = full["k"].shape[0]
+
+    def one(kv):
+        with jax.named_scope("dcom.lanczos"):
+            flat = kv.reshape(nf * b, s, kvh * hd)
+            u, vt = engine.decompose_kv(flat, rank, exact=exact)
+            r_eff = u.shape[-1]      # rank caps at min(s, kvw) (exact SVD)
+            return (u.reshape(nf, b, s, r_eff),
+                    vt.reshape(nf, b, r_eff, kvh * hd))
+
+    k_u, k_vt = one(full["k"])
+    v_u, v_vt = one(full["v"])
+    z = jnp.zeros((nf, b, tail, kvh, hd), cfg.jax_dtype)
+    cache = {"k_u": k_u, "k_vt": k_vt, "v_u": v_u, "v_vt": v_vt,
+             "tail": {"k": z, "v": z}}
+    if ring is not None:
+        cache["ring"] = _flat_periods(ring)
+    return (logits, cache) if stacks is None else (logits, cache, picks)
 
 
 def decode_step_dkv(p: Params, cfg, token: Array, cache: Params,
                     pos: Array, frozen_len) -> Tuple[Array, Params]:
-    """One-token decode over the decomposed cache (dense transformer).
+    """One-token decode over the decomposed cache: window layers attend
+    to their ring (row ``pos mod W`` written first), full layers through
+    their factors and dense tail.
 
     ``frozen_len`` is an int (uniform) or a per-slot int32 [B] vector; each
     slot's tail write position is its own ``pos − frozen_len``.
     """
     frozen_len = _frozen_vec(frozen_len, pos)
-    if _mixed(cfg):
-        return _decode_step_mixed(p, cfg, token, cache, pos, frozen_len)
-    x = p["embed"]["w"][token][:, None, :] * jnp.asarray(
-        cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0, cfg.jax_dtype)
-    kvh = cfg.num_kv_heads
+    kinds = _period_kinds(cfg)
+    n, nfp = len(kinds), kinds.count("full")
+    ropes = {k: L.rope_of(cfg, k) for k in set(kinds)}
+    w = cfg.sliding_window
+    x = _embed(p, cfg, token)[:, None, :]
+    upd = lambda buf, new, at: jax.vmap(
+        lambda bb, nn, ss: jax.lax.dynamic_update_slice_in_dim(
+            bb, nn, ss, axis=0))(buf, new.astype(buf.dtype), at)
+    fac = {k: _by_period(cache[k], nfp)
+           for k in ("k_u", "k_vt", "v_u", "v_vt", "tail")}
+    rings = _by_period(cache["ring"], n - nfp) if n > nfp else None
+    layers, stacks = _expert_stacks(cfg, p["layers"])
 
-    def scan_fn(x, inp):
-        lp, ku, kvt, vu, vvt, tail = inp
-        h = T._norm(lp["attn_norm"], x, cfg)
-        q = L._split_heads(L.dense(lp["attn"]["wq"], h), cfg.num_heads)
-        k_new = L._split_heads(L.dense(lp["attn"]["wk"], h), kvh)
-        v_new = L._split_heads(L.dense(lp["attn"]["wv"], h), kvh)
-        q = L.apply_rope(q, pos[:, None], cfg.rope_theta)
-        k_new = L.apply_rope(k_new, pos[:, None], cfg.rope_theta)
+    def period(x, inp):
+        lp, i, f, rg = inp
+        tails, new_rings = [], []
+        fi = wi = 0
+        for j, kind in enumerate(kinds):
+            lj = _at(lp, j, n)
+            h = T._norm(lj["attn_norm"], x, cfg)
+            q, k, v = _qkv(lj, h, cfg, pos[:, None], ropes[kind])
+            if kind == "window":
+                with jax.named_scope("dcom.window"):
+                    r = _at(rg, wi, n - nfp)
+                    r = {"k": upd(r["k"], k, pos % w),
+                         "v": upd(r["v"], v, pos % w)}
+                    a = _ring_attention(q, r["k"], r["v"], pos, cfg)
+                new_rings.append(r)
+                wi += 1
+            else:
+                slot = pos - frozen_len          # tail write position
+                t = _at(f["tail"], fi, nfp)
+                t = {"k": upd(t["k"], k, slot), "v": upd(t["v"], v, slot)}
+                layer_c = {key: _at(f[key], fi, nfp)
+                           for key in ("k_u", "k_vt", "v_u", "v_vt")}
+                a = _lowrank_attention(q, layer_c, t, pos[:, None],
+                                       frozen_len, cfg)
+                tails.append(t)
+                fi += 1
+            x = x + L.dense(lj["attn"]["wo"], a.astype(x.dtype))
+            x, _ = _mlp(lj, x, cfg, stacks, i * n + j)
+        return x, (_stack(tails), _stack(new_rings))
 
-        slot = pos - frozen_len                       # tail write position
-        upd = lambda buf, new: jax.vmap(
-            lambda bb, nn, ss: jax.lax.dynamic_update_slice_in_dim(
-                bb, nn, ss, axis=0))(buf, new.astype(buf.dtype), slot)
-        tail = {"k": upd(tail["k"], k_new), "v": upd(tail["v"], v_new)}
-
-        layer_c = {"k_u": ku, "k_vt": kvt, "v_u": vu, "v_vt": vvt}
-        a = _lowrank_attention(q, layer_c, tail, pos, frozen_len, cfg)
-        x = x + L.dense(lp["attn"]["wo"], a.astype(x.dtype))
-        x = x + L.mlp(lp["mlp"], T._norm(lp["mlp_norm"], x, cfg),
-                      cfg.activation)
-        return x, tail
-
-    x, tails = L.xscan(scan_fn, x,
-                       (p["layers"], cache["k_u"], cache["k_vt"],
-                        cache["v_u"], cache["v_vt"], cache["tail"]))
+    x, (tails, new_rings) = L.xscan(
+        period, x, (_by_period(layers, n), jnp.arange(cfg.num_layers // n),
+                    fac, rings))
     new_cache = dict(cache)
-    new_cache["tail"] = tails
+    new_cache["tail"] = _flat_periods(tails)
+    if new_rings is not None:
+        new_cache["ring"] = _flat_periods(new_rings)
     return T.logits_head(p, x, cfg)[:, 0], new_cache
 
 
@@ -476,221 +653,6 @@ def splice_dkv(live: Params, fresh: Params, slot_indices,
                 fresh[kind][k][:, src].astype(live[kind][k].dtype))
                 for k in live[kind]}
         return out
-
-
-# ---------------------------------------------------------------------------
-# Mixed layer kinds: window rings beside the factorized full layers
-# ---------------------------------------------------------------------------
-
-def _by_period(tree, n: int):
-    """Leaves [L, …] → [L / n, n, …]: one row per period."""
-    return jax.tree_util.tree_map(
-        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), tree)
-
-
-def _flat_periods(tree):
-    """Leaves [P, n, …] → [P·n, …]."""
-    return jax.tree_util.tree_map(
-        lambda a: a.reshape((-1,) + a.shape[2:]), tree)
-
-
-def _at(tree, j: int):
-    return jax.tree_util.tree_map(lambda a: a[j], tree)
-
-
-def _qkv(lp, h, cfg, positions, rope):
-    """Projections of one layer, q and k rotated by the kind's RoPE."""
-    freqs, scale = rope
-    q = L._split_heads(L.dense(lp["attn"]["wq"], h), cfg.num_heads)
-    k = L._split_heads(L.dense(lp["attn"]["wk"], h), cfg.num_kv_heads)
-    v = L._split_heads(L.dense(lp["attn"]["wv"], h), cfg.num_kv_heads)
-    q = L.apply_rope(q, positions, cfg.rope_theta, freqs, scale)
-    k = L.apply_rope(k, positions, cfg.rope_theta, freqs, scale)
-    return q, k, v
-
-
-def _ring_of(a: Array, w: int) -> Array:
-    """The ring of a prompt's rows a [B, S, …]: its last min(S, w) rows,
-    the row of position p at ``p mod w`` (zeros where S < w)."""
-    s = a.shape[1]
-    if s < w:
-        return jnp.pad(a, [(0, 0), (0, w - s)] + [(0, 0)] * (a.ndim - 2))
-    return jnp.roll(a[:, s - w:], s % w, axis=1)
-
-
-def _ring_attention(q: Array, rk: Array, rv: Array, pos: Array,
-                    cfg) -> Array:
-    """One-token attention over a window ring: q [B, 1, nh, d], ring
-    k/v [B, W, kvh, d] → [B, 1, nh·d].  Ring row j holds position
-    ``pos − ((pos − j) mod W)``, valid where that is not negative."""
-    b, w = rk.shape[:2]
-    hd = cfg.resolved_head_dim
-    j = jnp.arange(w)[None, :]
-    row_pos = pos[:, None] - (pos[:, None] - j) % w              # [B, W]
-    sc = L._gqa_scores(q, rk) * (hd ** -0.5)                     # [B,nh,1,W]
-    sc = jnp.where((row_pos >= 0)[:, None, None, :], sc, -1e30)
-    pr = jax.nn.softmax(sc, axis=-1).astype(rv.dtype)
-    return L._gqa_pv(pr, rv).reshape(b, 1, cfg.num_heads * hd)
-
-
-def _expert_stacks(layers: Params) -> Tuple[Params, Params]:
-    """Layer weights [L, …] → (the same without the expert weights, the
-    expert weights' whole stacks [L, held, …]).  The stacks stay out of
-    the period scan's ``xs``: a scan would slice them per period and per
-    layer, and the grouped matmul, a custom call, takes no fused slice,
-    so each slice would be a copy."""
-    rest = dict(layers["moe"])
-    stacks = {w: rest.pop(w) for w in ("w_gate", "w_up", "w_down")}
-    return dict(layers, moe=rest), stacks
-
-
-def _expert_mlp(lp, x, cfg, stacks: Params, layer: Array):
-    from . import moe
-    h = T._norm(lp["mlp_norm"], x, cfg)
-    y, _, picks = moe.held_experts_ffn(dict(lp["moe"], **stacks), h, cfg,
-                                       layer)
-    return x + y, picks
-
-
-def _prefill_mixed(p: Params, cfg, tokens: Array, rank: int, tail: int,
-                   exact: bool, engine) -> Tuple[Array, Params, Array]:
-    """Prefill of a model of mixed layer kinds: the forward pass in scopes
-    ``dcom.forward`` (norms, projections, full attention, head),
-    ``dcom.window`` (window attention and the ring write) and
-    ``dcom.moe``; then the full layers' K and V, and only theirs, through
-    :meth:`DecomposeEngine.decompose_kv` in ``dcom.lanczos``.
-
-    Returns (logits [B, V], cache, picks [router width] int32: the
-    token→expert picks of the rows whose token is not the engine's pad
-    id 0, summed over layers)."""
-    b, s = tokens.shape
-    kinds = _period_kinds(cfg)
-    ropes = {k: L.rope_of(cfg, k) for k in set(kinds)}
-    w = cfg.sliding_window
-    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    positions = jnp.broadcast_to(jnp.arange(s), tokens.shape)
-    real = (tokens != 0).reshape(-1).astype(jnp.int32)          # [B·S]
-    with jax.named_scope("dcom.forward"):
-        x = p["embed"]["w"][tokens] * jnp.asarray(
-            cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0,
-            cfg.jax_dtype)
-
-    layers, stacks = _expert_stacks(p["layers"])
-
-    def period(carry, inp):
-        (x, picks), (lp, i) = carry, inp
-        full, ring = [], []
-        for j, kind in enumerate(kinds):
-            lj = _at(lp, j)
-            with jax.named_scope("dcom.forward"):
-                h = T._norm(lj["attn_norm"], x, cfg)
-                q, k, v = _qkv(lj, h, cfg, positions, ropes[kind])
-            if kind == "window":
-                with jax.named_scope("dcom.window"):
-                    a = L.attend(q, k, v, positions, window=w,
-                                 out_dtype=x.dtype)
-                    ring.append({"k": _ring_of(k, w), "v": _ring_of(v, w)})
-            else:
-                with jax.named_scope("dcom.forward"):
-                    a = L.attend(q, k, v, positions, out_dtype=x.dtype)
-                full.append({"k": k.astype(cfg.jax_dtype),
-                             "v": v.astype(cfg.jax_dtype)})
-            with jax.named_scope("dcom.forward"):
-                x = x + L.dense(lj["attn"]["wo"], a)
-            x, e = _expert_mlp(lj, x, cfg, stacks, i * len(kinds) + j)
-            with jax.named_scope("dcom.moe"):
-                picks = picks.at[e].add(real[:, None])
-        stack = lambda rows: jax.tree_util.tree_map(
-            lambda *a: jnp.stack(a), *rows) if rows else None
-        return (x, picks), (stack(full), stack(ring))
-
-    picks0 = jnp.zeros((cfg.router_width,), jnp.int32)
-    (x, picks), (full, ring) = L.xscan(
-        period, (x, picks0), (_by_period(layers, len(kinds)),
-                              jnp.arange(cfg.num_layers // len(kinds))))
-    with jax.named_scope("dcom.forward"):
-        logits = T.logits_head(p, x[:, -1:, :], cfg)[:, 0]
-    full = _flat_periods(full)                       # [nf, B, S, kvh, hd]
-    nf = full["k"].shape[0]
-
-    def one(kv):
-        with jax.named_scope("dcom.lanczos"):
-            flat = kv.reshape(nf * b, s, kvh * hd)
-            u, vt = engine.decompose_kv(flat, rank, exact=exact)
-            r_eff = u.shape[-1]
-            return (u.reshape(nf, b, s, r_eff),
-                    vt.reshape(nf, b, r_eff, kvh * hd))
-
-    k_u, k_vt = one(full["k"])
-    v_u, v_vt = one(full["v"])
-    z = jnp.zeros((nf, b, tail, kvh, hd), cfg.jax_dtype)
-    cache = {"k_u": k_u, "k_vt": k_vt, "v_u": v_u, "v_vt": v_vt,
-             "tail": {"k": z, "v": z}}
-    if ring is not None:
-        cache["ring"] = _flat_periods(ring)
-    return logits, cache, picks
-
-
-def _decode_step_mixed(p: Params, cfg, token: Array, cache: Params,
-                       pos: Array, frozen_len: Array
-                       ) -> Tuple[Array, Params]:
-    """One-token decode of a model of mixed layer kinds: window layers
-    attend to their ring (row ``pos mod W`` written first), full layers
-    through their factors and dense tail as the dense path does."""
-    kinds = _period_kinds(cfg)
-    n = len(kinds)
-    ropes = {k: L.rope_of(cfg, k) for k in set(kinds)}
-    w = cfg.sliding_window
-    nfp = kinds.count("full")
-    x = p["embed"]["w"][token][:, None, :] * jnp.asarray(
-        cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0, cfg.jax_dtype)
-    slot = pos - frozen_len                          # tail write position
-    upd = lambda buf, new, at: jax.vmap(
-        lambda bb, nn, ss: jax.lax.dynamic_update_slice_in_dim(
-            bb, nn, ss, axis=0))(buf, new.astype(buf.dtype), at)
-    fac = {k: _by_period(cache[k], nfp)
-           for k in ("k_u", "k_vt", "v_u", "v_vt", "tail")}
-    rings = _by_period(cache["ring"], n - nfp) if n > nfp else None
-    layers, stacks = _expert_stacks(p["layers"])
-
-    def period(x, inp):
-        lp, i, f, rg = inp
-        tails, new_rings = [], []
-        fi = wi = 0
-        for j, kind in enumerate(kinds):
-            lj = _at(lp, j)
-            h = T._norm(lj["attn_norm"], x, cfg)
-            q, k, v = _qkv(lj, h, cfg, pos[:, None], ropes[kind])
-            if kind == "window":
-                with jax.named_scope("dcom.window"):
-                    r = _at(rg, wi)
-                    r = {"k": upd(r["k"], k, pos % w),
-                         "v": upd(r["v"], v, pos % w)}
-                    a = _ring_attention(q, r["k"], r["v"], pos, cfg)
-                new_rings.append(r)
-                wi += 1
-            else:
-                t = _at(f["tail"], fi)
-                t = {"k": upd(t["k"], k, slot), "v": upd(t["v"], v, slot)}
-                layer_c = {key: f[key][fi]
-                           for key in ("k_u", "k_vt", "v_u", "v_vt")}
-                a = _lowrank_attention(q, layer_c, t, pos, frozen_len, cfg)
-                tails.append(t)
-                fi += 1
-            x = x + L.dense(lj["attn"]["wo"], a.astype(x.dtype))
-            x, _ = _expert_mlp(lj, x, cfg, stacks, i * n + j)
-        stack = lambda rows: jax.tree_util.tree_map(
-            lambda *a: jnp.stack(a), *rows) if rows else None
-        return x, (stack(tails), stack(new_rings))
-
-    x, (tails, new_rings) = L.xscan(
-        period, x, (_by_period(layers, n), jnp.arange(cfg.num_layers // n),
-                    fac, rings))
-    new_cache = dict(cache)
-    new_cache["tail"] = _flat_periods(tails)
-    if new_rings is not None:
-        new_cache["ring"] = _flat_periods(new_rings)
-    return T.logits_head(p, x, cfg)[:, 0], new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -927,55 +889,22 @@ def prefill_suffix_dkv(p: Params, cfg, tokens: Array, prefix: Params,
     without re-running the prefix forward OR its Lanczos factorization.
     """
     b, s = tokens.shape
-    nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    g = nh // kvh
-    scale = hd ** -0.5
     start = jnp.asarray(start, jnp.int32)
     slen = jnp.asarray(slen, jnp.int32)
-    x = p["embed"]["w"][tokens] * jnp.asarray(
-        cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0, cfg.jax_dtype)
+    x = _embed(p, cfg, tokens)
     positions = start[:, None] + jnp.arange(s)[None, :]
-    row = jnp.arange(s)
-    live_row = row[None, :] < slen[:, None]              # [B, S] real rows
-    causal = row[:, None] >= row[None, :]                # [S, S]
-    t_pre = prefix["k_u"].shape[2]
-    pre_valid = jnp.arange(t_pre)[None, :] < start[:, None]
+    live_row = jnp.arange(s)[None, :] < slen[:, None]    # [B, S] real rows
+    rope = L.rope_of(cfg, "full")
 
     def scan_fn(x, inp):
-        lp, ku, kvt, vu, vvt = inp
+        lp, c = inp
         h = T._norm(lp["attn_norm"], x, cfg)
-        q = L._split_heads(L.dense(lp["attn"]["wq"], h), nh)
-        k = L._split_heads(L.dense(lp["attn"]["wk"], h), kvh)
-        v = L._split_heads(L.dense(lp["attn"]["wv"], h), kvh)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
-        qg = q.reshape(b, s, kvh, g, hd).astype(jnp.float32)
-
-        # prefix scores through the cached factors
-        kvt4 = kvt.astype(jnp.float32).reshape(b, -1, kvh, hd)
-        inner = jnp.einsum("bskgd,brkd->bskgr", qg, kvt4)
-        sc_pre = jnp.einsum("bskgr,btr->bskgt", inner,
-                            ku.astype(jnp.float32)) * scale
-        sc_pre = jnp.where(pre_valid[:, None, None, None, :], sc_pre, -1e30)
-
-        # within-suffix causal scores (exact)
-        kf = k.astype(jnp.float32)
-        sc_suf = jnp.einsum("bskgd,btkd->bskgt", qg, kf) * scale
-        sc_suf = jnp.where(causal[None, :, None, None, :], sc_suf, -1e30)
-
-        pr = jax.nn.softmax(
-            jnp.concatenate([sc_pre, sc_suf], axis=-1), axis=-1)
-        p_pre, p_suf = pr[..., :t_pre], pr[..., t_pre:]
-        tmp = jnp.einsum("bskgt,btr->bskgr", p_pre,
-                         vu.astype(jnp.float32))
-        vvt4 = vvt.astype(jnp.float32).reshape(b, -1, kvh, hd)
-        out = jnp.einsum("bskgr,brkd->bskgd", tmp, vvt4)
-        out = out + jnp.einsum("bskgt,btkd->bskgd", p_suf,
-                               v.astype(jnp.float32))
-        out = out.reshape(b, s, nh * hd)
-        x = x + L.dense(lp["attn"]["wo"], out.astype(x.dtype))
-        x = x + L.mlp(lp["mlp"], T._norm(lp["mlp_norm"], x, cfg),
-                      cfg.activation)
+        q, k, v = _qkv(lp, h, cfg, positions, rope)
+        # the cached factors, then the suffix's own rows causally
+        a = _lowrank_attention(q, c, {"k": k, "v": v}, positions, start,
+                               cfg)
+        x = x + L.dense(lp["attn"]["wo"], a.astype(x.dtype))
+        x, _ = _mlp(lp, x, cfg, None, None)
 
         # suffix K/V become the slot's dense tail; pad rows zeroed so
         # later folds see exactly what a full prefill would have left
@@ -985,9 +914,7 @@ def prefill_suffix_dkv(p: Params, cfg, tokens: Array, prefix: Params,
         pad = [(0, 0), (0, tail_len - s), (0, 0), (0, 0)]
         return x, {"k": jnp.pad(tk, pad), "v": jnp.pad(tv, pad)}
 
-    x, tails = L.xscan(scan_fn, x,
-                       (p["layers"], prefix["k_u"], prefix["k_vt"],
-                        prefix["v_u"], prefix["v_vt"]))
+    x, tails = L.xscan(scan_fn, x, (p["layers"], prefix))
     x_last = jnp.take_along_axis(
         x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)
     return T.logits_head(p, x_last, cfg)[:, 0], tails

@@ -156,19 +156,34 @@ def test_the_stacked_call_is_the_layers_own(layer, batch):
     assert (np.asarray(got) == 0).all() == (batch == "all_away")
 
 
-def test_ring_wraps_around_past_the_window():
-    """At full rank (exact SVD) the decomposed path is the dense-KV window
-    path: prefill 24 rows into a 16-row ring, then decode 20 tokens, so
-    ring rows are overwritten more than once."""
-    cfg = F32
+#: a dense decoder at the same tiny widths, float32: one period ("full",)
+DENSE_F32 = get_arch("granite-3-2b").replace(
+    name="dense-tiny", num_layers=3, d_model=64, num_heads=4,
+    num_kv_heads=2, head_dim=16, d_ff=128, vocab=128, remat=False,
+    dtype="float32")
+
+
+@pytest.mark.parametrize("cfg", [F32, DENSE_F32], ids=["mellum", "dense"])
+def test_full_rank_decomposed_path_is_the_forward(cfg):
+    """At full rank (exact SVD) the decomposed path is the model's own
+    forward, for a model of mixed layer kinds and for a dense one: prefill
+    24 rows, then decode 20 tokens, so a 16-row window ring is overwritten
+    more than once."""
     p = _params(cfg)
     fns = api.model_fns(cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 44), 1, cfg.vocab)
-    full = fns.forward(p, cfg, toks)[0]
-    lg, cache, picks = DK.prefill_dkv(p, cfg, toks[:, :24], rank=24,
-                                      tail=32, exact=True)
-    assert cache["ring"]["k"].shape == (6, 2, 16, 2, 16)
-    assert cache["k_u"].shape[0] == 2 and int(picks.sum()) == 2 * 24 * 2 * 8
+    full = fns.forward(p, cfg, toks)
+    if isinstance(full, tuple):          # an expert model adds its aux loss
+        full = full[0]
+    lg, cache, *picks = DK.prefill_dkv(p, cfg, toks[:, :24], rank=24,
+                                       tail=32, exact=True)
+    if cfg.sliding_window:
+        assert cache["ring"]["k"].shape == (6, 2, 16, 2, 16)
+        assert cache["k_u"].shape[0] == 2
+        assert int(picks[0].sum()) == 2 * 24 * 2 * 8
+    else:
+        assert "ring" not in cache and picks == []
+        assert cache["k_u"].shape[0] == cfg.num_layers
     # float32 throughout: only summation order separates the two paths
     np.testing.assert_allclose(lg, full[:, 23], atol=1e-4)
     for t in range(24, 44):

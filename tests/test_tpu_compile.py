@@ -1,13 +1,17 @@
-"""Compile the served-path re-orthogonalization kernels for a TPU v5e.
+"""Compile the served path for a TPU v5e: the re-orthogonalization
+kernels, and the prefill and decode-block programs of the benchmark's
+models.
 
 Nothing runs: the TPU compiler compiles for a described (not attached)
 v5e chip, so these tests catch what interpret mode accepts and Mosaic
 refuses — blocks off the (8, 128) tiling, VMEM over the scoped limit, a
-program over the chip's HBM — at the shapes granite-3-2b's and
-deepseek-7b's (15 layers) prefill launches.  The topology is described
-inside a module fixture (never at import): only one process may load the
-TPU library, and the suite runs under several pytest-xdist workers.
+program over the chip's HBM, copies of weights — at the shapes of
+granite-3-2b's, deepseek-7b's (15 layers) and mellum2-12b's launches.
+The topology is described inside a module fixture (never at import):
+only one process may load the TPU library, and the suite runs under
+several pytest-xdist workers.
 """
+import collections
 import os
 import re
 
@@ -166,7 +170,7 @@ def test_mixed_decode_block_compiles_at_mellum_widths(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     text = compiled.as_text()
     assert "ragged-dot-none" in text
-    assert _expert_weight_copies(text, cfg) == []
+    assert _weight_copies(text, _expert_dims(params)) == {}
 
 
 #: an instruction of compiled HLO text: its name, result shape and opcode
@@ -174,18 +178,30 @@ _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?(\S+)\s*=\s*[a-z][a-z0-9]*"
                     r"\[([0-9,]*)\]\S*\s+([a-z][a-z0-9-]*)\(", re.M)
 
 
-def _expert_weight_copies(text: str, cfg):
-    """The instructions of a compiled program that make an array of expert
-    weights ([…, H, F] or […, F, H]) other than by naming it: anything but
-    a parameter, a tuple element or a bitcast is a copy of the weights."""
-    h, f = cfg.d_model, cfg.moe_d_ff
-    found = []
-    for name, dims, op in _INSTR.findall(text):
+def _weight_copies(text: str, shapes) -> collections.Counter:
+    """The instructions of a compiled program that make an array shaped
+    like a layer weight (trailing two dims in ``shapes``) other than by
+    naming it — anything but a parameter, a tuple element or a bitcast —
+    counted by (result dims, opcode)."""
+    found = collections.Counter()
+    for _, dims, op in _INSTR.findall(text):
         tail = tuple(int(d) for d in dims.split(",")[-2:] if d)
-        if tail in ((h, f), (f, h)) and op not in (
+        if tail in shapes and op not in (
                 "parameter", "get-tuple-element", "bitcast"):
-            found.append(f"{name} = [{dims}] {op}")
+            found[(dims, op)] += 1
     return found
+
+
+def _trailing_dims(tree):
+    """The trailing two dims of every stacked leaf (rank >= 3) of a tree
+    of layer weights."""
+    return {tuple(a.shape[-2:]) for a in jax.tree_util.tree_leaves(tree)
+            if a.ndim >= 3}
+
+
+def _expert_dims(params):
+    return _trailing_dims({w: params["layers"]["moe"][w]
+                           for w in ("w_gate", "w_up", "w_down")})
 
 
 @pytest.mark.parametrize("batch, prompt", [(1, 1536), (4, 3072)])
@@ -207,4 +223,116 @@ def test_mixed_prefill_compiles_at_mellum_widths(one_chip, batch, prompt):
                        ).lower(params, toks).compile()
     text = compiled.as_text()
     assert "ragged-dot-none" in text
-    assert _expert_weight_copies(text, cfg) == []
+    assert _weight_copies(text, _expert_dims(params)) == {}
+
+
+# -- the dense cells: granite-3-2b and deepseek-7b (15 layers) -------------
+
+#: per cell: (config, prefix rows of its cache: ``max_len`` less the tail)
+DENSE_CELLS = {"granite-3-2b": (GRANITE, 2048),
+               "deepseek-7b-15l": (DEEPSEEK.replace(num_layers=15), 1024)}
+
+#: the weight-shaped instructions, by (result dims, opcode), that each
+#: dense program compiled to when dense models had layer bodies of their
+#: own beside the layer-kind scan.  A program may not take on more: the
+#: whole-stack copies here are the decode block's known cost, not a
+#: budget to grow.
+DENSE_WEIGHT_SHAPED = {
+    ("granite-3-2b", "decode"): {
+        ("1,2048,2048", "dynamic-slice"): 2,
+        ("1,2048,2048", "fusion"): 1,
+        ("1,2048,512", "dynamic-slice"): 2,
+        ("1,2048,512", "fusion"): 2,
+        ("1,2048,8192", "dynamic-slice"): 2,
+        ("1,8192,2048", "dynamic-slice"): 1,
+        ("10,2048,512", "slice-done"): 4,
+        ("2048,2048", "fusion"): 1,
+        ("2048,8192", "fusion"): 2,
+        ("40,2048,2048", "copy"): 1,
+        ("40,2048,512", "copy"): 2,
+        ("40,2048,512", "custom-call"): 1,
+        ("8192,2048", "fusion"): 1,
+    },
+    ("granite-3-2b", "prefill"): {
+        ("1,2048,2048", "copy"): 1,
+        ("1,2048,2048", "dynamic-slice"): 2,
+        ("1,2048,2048", "fusion"): 1,
+        ("1,2048,512", "copy"): 1,
+        ("1,2048,512", "dynamic-slice"): 2,
+        ("1,2048,512", "fusion"): 1,
+        ("1,2048,8192", "dynamic-slice"): 2,
+        ("1,8192,2048", "dynamic-slice"): 1,
+        # [4 x 512 tokens, d_model] activations share granite's [2048, 2048]
+        ("2048,2048", "broadcast"): 1,
+        ("2048,2048", "convert"): 3,
+        ("2048,2048", "fusion"): 2,
+        ("2048,2048", "multiply"): 1,
+        ("2048,2048", "reshape"): 1,
+    },
+    ("deepseek-7b-15l", "decode"): {
+        ("1,11008,4096", "dynamic-slice"): 1,
+        ("1,4096,11008", "dynamic-slice"): 2,
+        ("1,4096,4096", "dynamic-slice"): 4,
+        ("1,4096,4096", "fusion"): 3,
+        ("11008,4096", "fusion"): 1,
+        ("15,4096,4096", "copy"): 3,
+        ("4096,11008", "fusion"): 2,
+        ("4096,4096", "fusion"): 1,
+    },
+    ("deepseek-7b-15l", "prefill"): {
+        ("1,11008,4096", "dynamic-slice"): 1,
+        ("1,4096,11008", "dynamic-slice"): 2,
+        ("1,4096,4096", "copy"): 2,
+        ("1,4096,4096", "dynamic-slice"): 4,
+        ("1,4096,4096", "fusion"): 2,
+    },
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("cell", sorted(DENSE_CELLS))
+def test_dense_programs_compile_at_cell_shapes(one_chip, cell, program):
+    """The fused 8-step decode block over 8 slots (the cell's prefix rows
+    at rank 64, a 128-row tail), and the prefill of four 512-token
+    prompts through the compiled Lanczos kernel: each fits one chip's
+    16 GB and makes no more weight-shaped arrays than in the table."""
+    from repro.engine import DecomposeEngine
+    from repro.models import api
+    from repro.models import decomposed_kv as DK
+    from repro.serving import greedy_sampler
+    cfg, rows = DENSE_CELLS[cell]
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    params = jax.tree_util.tree_map(sds, api.abstract_params(cfg))
+    if program == "decode":
+        b = SLOTS
+        cache = jax.tree_util.tree_map(sds, jax.eval_shape(
+            lambda: DK.init_cache(cfg, b, rows, RANK, tail=128)))
+        assert cache["k_u"].shape == (cfg.num_layers, b, rows, RANK)
+        assert "ring" not in cache
+
+        def run(p, t, c, pos, fl, n, stops, key, r0):
+            return DK.decode_block_dkv(p, cfg, t, c, pos, fl, n, stops, key,
+                                       r0, sampler=greedy_sampler,
+                                       max_block=8)
+
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+        compiled = jax.jit(run, donate_argnums=(2,)).lower(
+            params, i32(b), cache, i32(b), i32(b), i32(), i32(b, 2), key,
+            i32()).compile()
+    else:
+        engine = DecomposeEngine(EngineConfig(
+            backend="pallas", expansion=8, kv_rank=RANK,
+            kv_iters_extra=EngineConfig().kv_iters_extra))
+        toks = i32(4, 512)
+        compiled = jax.jit(lambda p, t: DK.prefill_dkv(
+            p, cfg, t, RANK, tail=128, engine=engine)).lower(
+            params, toks).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    got = _weight_copies(compiled.as_text(), _trailing_dims(params["layers"]))
+    table = DENSE_WEIGHT_SHAPED[(cell, program)]
+    over = {k: (n, table.get(k, 0)) for k, n in got.items()
+            if n > table.get(k, 0)}
+    assert over == {}, f"weight-shaped instructions (now, before): {over}"
