@@ -16,8 +16,12 @@ Implementation notes
   matches the paper's "orthogonalize against V/U" and is what their
   accelerator executes.  U/V buffers are zero-padded to [.., iters], so
   projecting against not-yet-filled columns is a no-op.
-* Internally fp32 regardless of input dtype (bf16 inputs upcast), matching
-  the fp32-accumulate behaviour of MXU/MAC hardware.
+* Lanczos vectors, coefficients and accumulation are fp32 regardless of
+  input dtype, matching the fp32-accumulate behaviour of MXU/MAC hardware.
+  A reaches the hooks in the dtype it was given: the jnp reference hooks
+  upcast it, and the Pallas kernel streams a bf16 A as it lies (an exact
+  split product, ``kernels/lanczos_reorth.py``).  Factors come out fp32
+  from :func:`decompose_f32`; :func:`decompose` casts them to x's dtype.
 * ``matvec``/``rmatvec``/``reorth`` are pluggable so the Pallas kernels in
   ``repro.kernels`` can replace the jnp reference implementations.
 """
@@ -75,16 +79,20 @@ def _reorth_cgs2_batched(z: Array, q: Array) -> Array:
     return z
 
 
+def _f32(a: Array) -> Array:
+    return a.astype(jnp.float32)
+
+
 DEFAULT_HOOKS = LanczosHooks(
-    right_step=lambda a, u, vbuf: _reorth_cgs2(a.T @ u, vbuf),
-    left_step=lambda a, v, ubuf: _reorth_cgs2(a @ v, ubuf),
+    right_step=lambda a, u, vbuf: _reorth_cgs2(_f32(a).T @ u, vbuf),
+    left_step=lambda a, v, ubuf: _reorth_cgs2(_f32(a) @ v, ubuf),
 )
 
 DEFAULT_BATCHED_HOOKS = BatchedLanczosHooks(
     right_step=lambda a, u, vbuf: _reorth_cgs2_batched(
-        jnp.einsum("bsh,bs->bh", a, u), vbuf),
+        jnp.einsum("bsh,bs->bh", _f32(a), u), vbuf),
     left_step=lambda a, v, ubuf: _reorth_cgs2_batched(
-        jnp.einsum("bsh,bh->bs", a, v), ubuf),
+        jnp.einsum("bsh,bh->bs", _f32(a), v), ubuf),
 )
 
 
@@ -181,10 +189,10 @@ def lanczos_bidiag_batched(a: Array, iters: int,
     Identical math to :func:`lanczos_bidiag` per batch element (same start
     vector when ``z0`` is None), but every inner step is ONE batched hook
     call, so kernel backends see the full batch per pass.  ``z0`` may be
-    [H] (broadcast over the batch) or [B, H].
+    [H] (broadcast over the batch) or [B, H].  ``a`` goes to the hooks in
+    its own dtype (a bf16 A is never copied to f32 here).
     """
     b_dim, s_dim, h_dim = a.shape
-    a32 = a.astype(jnp.float32)
     if z0 is None:
         key = jax.random.PRNGKey(0)
         z0 = jax.random.normal(key, (h_dim,), jnp.float32)
@@ -196,7 +204,7 @@ def lanczos_bidiag_batched(a: Array, iters: int,
     beta = jnp.zeros((b_dim, max(iters - 1, 1)), jnp.float32)
 
     v0, _ = _safe_normalize_batched(z0)
-    u0 = hooks.left_step(a32, v0, u_buf)   # U buffer all-zero ⇒ pure matvec
+    u0 = hooks.left_step(a, v0, u_buf)   # U buffer all-zero ⇒ pure matvec
     u0, a0 = _safe_normalize_batched(u0)
     u_buf = u_buf.at[..., 0].set(u0)
     v_buf = v_buf.at[..., 0].set(v0)
@@ -205,11 +213,11 @@ def lanczos_bidiag_batched(a: Array, iters: int,
     def body(j, carry):
         u_buf, v_buf, alpha, beta = carry
         u_prev = u_buf[..., j - 1]
-        z = hooks.right_step(a32, u_prev, v_buf)
+        z = hooks.right_step(a, u_prev, v_buf)
         z, b = _safe_normalize_batched(z)
         v_buf = v_buf.at[..., j].set(z)
         beta = beta.at[..., j - 1].set(b)
-        u = hooks.left_step(a32, z, u_buf)
+        u = hooks.left_step(a, z, u_buf)
         u, al = _safe_normalize_batched(u)
         u_buf = u_buf.at[..., j].set(u)
         alpha = alpha.at[..., j].set(al)
@@ -240,7 +248,8 @@ def decompose(x: Array, rank: int, iters: Optional[int] = None,
               hooks: Optional[LanczosHooks] = None,
               batched_hooks: Optional[BatchedLanczosHooks] = None,
               z0: Optional[Array] = None) -> LowRank:
-    """Batched activation decomposition: x [..., S, H] → LowRank.
+    """Batched activation decomposition: x [..., S, H] → LowRank in x's
+    dtype (the factors of :func:`decompose_f32`, cast).
 
     Each prompt's [S, H] slice is decomposed independently (paper §3.1:
     "we apply the decomposition on each prompt separately"), but the whole
@@ -250,11 +259,22 @@ def decompose(x: Array, rank: int, iters: Optional[int] = None,
     vmap fallback).  Prefer constructing a ``repro.engine.DecomposeEngine``,
     which also handles padding, outlier tracks, and backend selection.
     """
-    iters = rank if iters is None else iters
-    assert iters >= rank, "need at least `rank` Lanczos iterations"
     if batched_hooks is None:
         batched_hooks = (DEFAULT_BATCHED_HOOKS if hooks is None
                          else batch_hooks(hooks))
+    return decompose_f32(x, rank, iters=iters, batched_hooks=batched_hooks,
+                         z0=z0).astype(x.dtype)
+
+
+@partial(jax.jit, static_argnames=("rank", "iters", "batched_hooks"))
+def decompose_f32(x: Array, rank: int, iters: Optional[int] = None,
+                  batched_hooks: BatchedLanczosHooks = DEFAULT_BATCHED_HOOKS,
+                  z0: Optional[Array] = None) -> LowRank:
+    """:func:`decompose` with the factors left in fp32 whatever x's dtype,
+    for callers that combine them before they round (``U·Σ`` in
+    ``DecomposeEngine.decompose_kv``)."""
+    iters = rank if iters is None else iters
+    assert iters >= rank, "need at least `rank` Lanczos iterations"
     batch_shape = x.shape[:-2]
     flat = x.reshape((-1,) + x.shape[-2:])
     res = lanczos_bidiag_batched(flat, iters, z0=z0, hooks=batched_hooks)
@@ -262,4 +282,4 @@ def decompose(x: Array, rank: int, iters: Optional[int] = None,
     u = u.reshape(batch_shape + u.shape[1:])
     s = s.reshape(batch_shape + s.shape[1:])
     vt = vt.reshape(batch_shape + vt.shape[1:])
-    return LowRank(u.astype(x.dtype), s.astype(x.dtype), vt.astype(x.dtype))
+    return LowRank(u, s, vt)

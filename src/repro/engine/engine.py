@@ -87,8 +87,8 @@ def _sharded_decompose(mesh, batch_spec: P, rank: int, iters: int, hooks,
     dp = batch_spec[0] if len(batch_spec) else None
 
     def run(xf: Array, z0: Array):
-        return lz.decompose(xf, rank, iters=iters, batched_hooks=hooks,
-                            z0=z0)
+        return lz.decompose_f32(xf, rank, iters=iters, batched_hooks=hooks,
+                                z0=z0)
 
     if use_shard_map and dp is not None:
         in_specs = (P(dp, None, None), P())
@@ -159,11 +159,17 @@ class DecomposeEngine:
     # -- stage 1: batched Lanczos decomposition ---------------------------
     def decompose(self, x: Array, rank: int,
                   iters: Optional[int] = None) -> LowRank:
-        """x [..., S, H] → LowRank via the engine's backend.
+        """x [..., S, H] → LowRank in x's dtype via the engine's backend.
 
         One natively batched Lanczos run; kernel backends get zero-padding
         to the cached (S_pad, H_pad) plan and exact slice-back.
         """
+        return self._decompose_f32(x, rank, iters).astype(x.dtype)
+
+    def _decompose_f32(self, x: Array, rank: int,
+                       iters: Optional[int] = None) -> LowRank:
+        """:meth:`decompose` with fp32 factors.  x keeps its dtype into the
+        hooks: the kernel streams a bf16 A (padded in bf16) as it lies."""
         from ..kernels import ops
         s_dim, h_dim = x.shape[-2:]
         batch = 1
@@ -188,8 +194,8 @@ class DecomposeEngine:
         if self.config.mesh is not None:
             lr = self._decompose_sharded(xp, rank, iters, hooks, z0)
         else:
-            lr = lz.decompose(xp, rank, iters=iters,
-                              batched_hooks=hooks, z0=z0)
+            lr = lz.decompose_f32(xp, rank, iters=iters,
+                                  batched_hooks=hooks, z0=z0)
         if pad:
             lr = LowRank(lr.u[..., :s_dim, :], lr.core,
                          lr.vt[..., :h_dim])
@@ -264,14 +270,17 @@ class DecomposeEngine:
         switches to direct SVD — used when r approaches full rank, where
         floating-point Lanczos loses trailing directions (§2.3).  The
         requested rank caps at min(T, kvw) — a factorization cannot carry
-        more directions than the matrix has."""
+        more directions than the matrix has.  A kernel backend gets x in
+        the dtype prefill wrote (bf16 streams at half the bytes of an f32
+        copy); the factors stay fp32 until after ``scaled_u``."""
         rank = min(rank, *x.shape[-2:])
         if exact:
             lr = from_dense_svd(x.astype(jnp.float32), rank)
         else:
             iters = iters or min(rank + self.config.kv_iters_extra,
                                  min(x.shape[-2:]))
-            lr = self.decompose(x.astype(jnp.float32), rank, iters=iters)
+            a = x if self.backend.requires_padding else x.astype(jnp.float32)
+            lr = self._decompose_f32(a, rank, iters=iters)
         return lr.scaled_u().astype(x.dtype), lr.vt.astype(x.dtype)
 
     # -- stage 3: preserved-form consumption (paper §3.2) -----------------

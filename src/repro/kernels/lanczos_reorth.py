@@ -33,8 +33,21 @@ Two symmetric variants share one kernel body:
 * ``left`` : w = CGS2(A v, U) — output over rows of A (length S).
 
 Layout for the TPU compiler (Mosaic): every vector is a lane-major row
-[1, n], the products run on the MXU at full f32 precision, and z is stored
-as [f, 1, blk] so each grid step addresses its block by a leading-dim index.
+[1, n], and z is stored as [f, 1, blk] so each grid step addresses its
+block by a leading-dim index.  Products run on the MXU, by A's dtype:
+
+* float32 A: every product at ``Precision.HIGHEST``.
+* bfloat16 A (the K/V prefill wrote, streamed as written): pass 0 splits
+  the f32 vector x exactly into three bf16 parts, x1 = bf16(x),
+  x2 = bf16(x − x1), x3 = bf16(x − x1 − x2), stacks them as rows 0–2 of
+  an [8, n] left operand (rows 3–7 zero), makes ONE bf16 MXU product with
+  f32 accumulation, and sums the output rows smallest first,
+  (row 2 + row 1) + row 0.  Every bf16 × bf16 product is exact in f32,
+  so this makes the terms ``HIGHEST`` makes on the same A less those that
+  multiply A's low parts, which are zero for a bf16-valued A: the same
+  arithmetic in one pass instead of six, over half the bytes.  Passes 1–2,
+  Q, z, p1/p2 and the norms stay f32 at ``HIGHEST``.
+
 Compiled launches need the A block to tile: ``blk = H / f`` a multiple of
 128 lanes (:data:`LANE`) on the right step, ``blk = S / f`` a multiple of
 8 sublanes (:data:`SUBLANE`) on the left; ``kernels.ops.padded_dims``
@@ -74,6 +87,33 @@ def _row_dot_t(x, y):
                                preferred_element_type=jnp.float32)
 
 
+def _split3(x):
+    """f32 [1, n] → bf16 [8, n]: rows 0–2 hold x1 = bf16(x),
+    x2 = bf16(x − x1), x3 = bf16(x − x1 − x2), whose sum is x exactly;
+    rows 3–7 are zero (a whole sublane tile for the MXU)."""
+    x1 = x.astype(jnp.bfloat16).astype(jnp.float32)
+    r = x - x1
+    x2 = r.astype(jnp.bfloat16).astype(jnp.float32)
+    x3 = (r - x2).astype(jnp.bfloat16).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, x.shape[1]), 0)
+    parts = jnp.where(row == 0, x1,
+                      jnp.where(row == 1, x2, jnp.where(row == 2, x3, 0.0)))
+    return parts.astype(jnp.bfloat16)
+
+
+def _split_dot(x, a, right: bool):
+    """x · A (right) or x · Aᵀ (left) for a bf16 A: one bf16 MXU product
+    of x's exact three-part split, f32 accumulation, rows summed smallest
+    first → (1, blk)."""
+    parts = _split3(x)
+    if right:
+        out = jnp.dot(parts, a, preferred_element_type=jnp.float32)
+    else:
+        out = jax.lax.dot_general(parts, a, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+    return (out[2:3] + out[1:2]) + out[0:1]
+
+
 def _reorth_kernel(a_ref, x_ref, q_ref, z_ref, nrm_ref, p1, p2, *,
                    right: bool):
     """grid = (B, 3 passes, f blocks) — batch is the OUTERMOST grid dim, so
@@ -93,9 +133,12 @@ def _reorth_kernel(a_ref, x_ref, q_ref, z_ref, nrm_ref, p1, p2, *,
 
     @pl.when(p == 0)
     def _pass0():
-        a = a_ref[0].astype(jnp.float32)
         x = x_ref[0].astype(jnp.float32)
-        z = _row_dot(x, a) if right else _row_dot_t(x, a)  # (1, blk)
+        if a_ref.dtype == jnp.bfloat16:
+            z = _split_dot(x, a_ref[0], right)             # (1, blk)
+        else:
+            a = a_ref[0].astype(jnp.float32)
+            z = _row_dot(x, a) if right else _row_dot_t(x, a)
         z_ref[0, j] = z
         p1[...] += _row_dot(z, q)
 
@@ -112,12 +155,13 @@ def _reorth_kernel(a_ref, x_ref, q_ref, z_ref, nrm_ref, p1, p2, *,
         nrm_ref[0] += jnp.sum(z * z, axis=1, keepdims=True)
 
 
-def _vmem_limit(a_blk: int, x_len: int, q_blk: int, k: int, out_len: int
+def _vmem_limit(a_bytes: int, x_len: int, q_blk: int, k: int, out_len: int
                 ) -> int:
-    """Scoped VMEM for one launch: double-buffered A/x/Q/z blocks (rows pad
-    to 8 sublanes, k pads to whole lanes) plus headroom."""
+    """Scoped VMEM for one launch: double-buffered A block (``a_bytes`` at
+    A's itemsize) and f32 x/Q/z blocks (rows pad to 8 sublanes, k pads to
+    whole lanes) plus headroom."""
     k_pad = -(-k // LANE) * LANE
-    need = 4 * 2 * (a_blk + 8 * x_len + q_blk * k_pad + 8 * out_len)
+    need = 2 * (a_bytes + 4 * (8 * x_len + q_blk * k_pad + 8 * out_len))
     need = need + need // 4 + 2 * 2 ** 20
     if need > _VMEM_CAP:
         raise ValueError(f"re-orth launch needs {need} B of VMEM "
@@ -156,7 +200,8 @@ def _launch(a, x, q, *, right: bool, expansion: int, interpret: bool):
     a_spec = pl.BlockSpec(a_blk, a_block_index(right, f))
     x_len = s_dim if right else h_dim
     params = None if interpret else pltpu.CompilerParams(
-        vmem_limit_bytes=_vmem_limit(s_dim * h_dim // f, x_len, blk, k, n))
+        vmem_limit_bytes=_vmem_limit(s_dim * h_dim // f * a.dtype.itemsize,
+                                     x_len, blk, k, n))
     z, nrm = pl.pallas_call(
         functools.partial(_reorth_kernel, right=right),
         grid=(b_dim, 3, f),

@@ -314,3 +314,53 @@ def test_padded_z0_is_host_value():
     # and usable under an outer trace (the jitted dkv prefill case)
     out = jax.jit(lambda: jnp.asarray(_padded_z0(24, 32)) * 2.0)()
     np.testing.assert_allclose(np.asarray(out), z * 2.0)
+
+
+def test_decompose_kv_streams_bf16_into_the_kernel():
+    """bf16 K/V reach the kernel backend's hooks as bf16 (no f32 copy),
+    and the fp32 factors before the final cast match the same call on the
+    f32 upcast within 1e-6 relative: the split product makes the terms the
+    f32 path makes, so the two are one result."""
+    from repro.core.lanczos import BatchedLanczosHooks
+    eng = DecomposeEngine(EngineConfig(backend="pallas_interpret"))
+    real = eng._hooks
+    seen = []
+
+    def spy(step):
+        def run(a, x, q_buf):
+            seen.append(a.dtype)
+            return step(a, x, q_buf)
+        return run
+
+    eng._hooks = BatchedLanczosHooks(right_step=spy(real.right_step),
+                                     left_step=spy(real.left_step))
+    factors = []
+    inner = eng._decompose_f32
+
+    def keep(x, rank, iters=None):
+        lr = inner(x, rank, iters)
+        factors.append((x.dtype, lr))
+        return lr
+
+    eng._decompose_f32 = keep
+    # decaying spectrum, so each Lanczos direction is well separated
+    b, t, kvw, rank = 2, 48, 128, 4
+    keys = jax.random.split(jax.random.PRNGKey(11), 2)
+    left = jax.random.normal(keys[0], (b, t, 16), jnp.float32)
+    right = jax.random.normal(keys[1], (b, 16, kvw), jnp.float32)
+    x16 = jnp.einsum("btr,r,brh->bth", left, 0.5 ** jnp.arange(16.0),
+                     right).astype(jnp.bfloat16)
+    u16, vt16 = eng.decompose_kv(x16, rank)
+    assert u16.dtype == vt16.dtype == jnp.bfloat16
+    assert seen and set(seen) == {jnp.dtype(jnp.bfloat16)}
+    (dt16, lr16), = factors
+    assert dt16 == jnp.bfloat16 and lr16.u.dtype == jnp.float32
+    seen.clear()
+    u32, vt32 = eng.decompose_kv(x16.astype(jnp.float32), rank)
+    assert set(seen) == {jnp.dtype(jnp.float32)}
+    for got, want in ((lr16.scaled_u(), u32), (lr16.vt, vt32)):
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+    # the final cast rounds the fp32 U·Σ, not U, Σ and Vᵀ one by one
+    np.testing.assert_array_equal(
+        np.asarray(u16), np.asarray(lr16.scaled_u().astype(jnp.bfloat16)))

@@ -141,6 +141,101 @@ def test_reorth_batched_matches_reference(side, f):
     np.testing.assert_array_equal(np.asarray(nrm), np.asarray(nrm_all))
 
 
+def _reorth_case(side, b=3, s=64, h=256, k=8, seed=80):
+    """A [b, s, h] of bf16 values held in f32, the f32 vector x of the
+    step and an orthonormal Q [b, n, k] per batch element."""
+    right = side == "right"
+    n = h if right else s
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2 + b)
+    a = _mk(keys[0], (b, s, h), jnp.bfloat16).astype(jnp.float32)
+    x = _mk(keys[1], (b, s if right else h), jnp.float32)
+    q = jnp.stack([jnp.linalg.qr(_mk(keys[2 + i], (n, k), jnp.float32))[0]
+                   for i in range(b)])
+    return a, x, q
+
+
+@pytest.mark.parametrize("f", [1, 4, 8])
+@pytest.mark.parametrize("side", ["right", "left"])
+def test_reorth_bf16_a_matches_f32_path(side, f):
+    """A bf16 A takes pass 0's one-pass split product; on the same values
+    held in f32 the HIGHEST path computes the same terms, so z and ‖z‖²
+    agree to f32 rounding (1e-6 relative), not to bf16's."""
+    from repro.kernels import lanczos_reorth as LR
+    a32, x, q = _reorth_case(side)
+    fn = LR.reorth_right_batched if side == "right" \
+        else LR.reorth_left_batched
+    z16, n16 = fn(a32.astype(jnp.bfloat16), x, q, expansion=f,
+                  interpret=True)
+    z32, n32 = fn(a32, x, q, expansion=f, interpret=True)
+    z16, n16, z32, n32 = map(np.asarray, (z16, n16, z32, n32))
+    assert np.abs(z16 - z32).max() <= 1e-6 * np.abs(z32).max()
+    np.testing.assert_allclose(n16, n32, rtol=1e-6, atol=0)
+
+
+def _f32_only_kernel(a_ref, x_ref, q_ref, z_ref, nrm_ref, p1, p2, *,
+                     right: bool):
+    """The re-orth kernel body before bf16 A had a pass 0 of its own:
+    every A cast to f32 and every product at HIGHEST (the frozen oracle
+    for the f32 path)."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.lanczos_reorth import _row_dot, _row_dot_t
+    p = pl.program_id(1)
+    j = pl.program_id(2)
+
+    @pl.when((p == 0) & (j == 0))
+    def _init():
+        p1[...] = jnp.zeros_like(p1)
+        p2[...] = jnp.zeros_like(p2)
+        nrm_ref[...] = jnp.zeros_like(nrm_ref)
+
+    q = q_ref[0].astype(jnp.float32)
+
+    @pl.when(p == 0)
+    def _pass0():
+        a = a_ref[0].astype(jnp.float32)
+        x = x_ref[0].astype(jnp.float32)
+        z = _row_dot(x, a) if right else _row_dot_t(x, a)
+        z_ref[0, j] = z
+        p1[...] += _row_dot(z, q)
+
+    @pl.when(p == 1)
+    def _pass1():
+        z = z_ref[0, j] - _row_dot_t(p1[...], q)
+        z_ref[0, j] = z
+        p2[...] += _row_dot(z, q)
+
+    @pl.when(p == 2)
+    def _pass2():
+        z = z_ref[0, j] - _row_dot_t(p2[...], q)
+        z_ref[0, j] = z
+        nrm_ref[0] += jnp.sum(z * z, axis=1, keepdims=True)
+
+
+@pytest.mark.parametrize("f", [1, 4])
+@pytest.mark.parametrize("side", ["right", "left"])
+def test_reorth_f32_a_keeps_highest_path(side, f):
+    """An f32 A (the outlier track's base, any f32 caller) takes no split:
+    every product in the kernel is f32 at HIGHEST, and z and ‖z‖² are
+    bit-for-bit what the f32-only kernel body gives."""
+    from repro.kernels import lanczos_reorth as LR
+    a, x, q = _reorth_case(side)
+    right = side == "right"
+    jaxpr = jax.make_jaxpr(lambda a, x, q: LR._launch(
+        a, x, q, right=right, expansion=f, interpret=True))(a, x, q)
+    body = str(next(e for e in jaxpr.eqns
+                    if e.primitive.name == "pallas_call").params["jaxpr"])
+    assert "bf16" not in body
+    assert body.count("dot_general") == \
+        body.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") == 5
+    z, nrm = LR._launch(a, x, q, right=right, expansion=f, interpret=True)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(LR, "_reorth_kernel", _f32_only_kernel)
+        z_old, nrm_old = LR._launch(a, x, q, right=right, expansion=f,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(z_old))
+    np.testing.assert_array_equal(np.asarray(nrm), np.asarray(nrm_old))
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("k", [8, 16])
 @pytest.mark.parametrize("f", [4, 8])

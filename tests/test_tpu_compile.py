@@ -60,11 +60,11 @@ def one_chip(topo):
 
 
 def _compile_reorth(sharding, side: str, batch: int, s: int, f: int,
-                    kvw: int = KVW):
+                    kvw: int = KVW, a_dtype=jnp.float32):
     s_pad, h_pad = ops.padded_dims(s, kvw, f)
 
-    def sds(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     if side == "right":
         fn, x, q = LR.reorth_right_batched, sds(batch, s_pad), \
@@ -74,8 +74,14 @@ def _compile_reorth(sharding, side: str, batch: int, s: int, f: int,
             sds(batch, s_pad, K)
     compiled = jax.jit(lambda a, x, q: fn(a, x, q, expansion=f,
                                           interpret=False)).lower(
-        sds(batch, s_pad, h_pad), x, q).compile()
+        sds(batch, s_pad, h_pad, dtype=a_dtype), x, q).compile()
     return compiled, (s_pad, h_pad)
+
+
+#: A's dtype in each re-orth custom call of a compiled program
+_REORTH_A = re.compile(r"%reorth_(?:right|left)_batched\S* = .*?"
+                       r"custom-call\(.*?operand_layout_constraints="
+                       r"\{([a-z0-9]+)\[")
 
 
 @pytest.mark.parametrize("f", [4, 8, 16])
@@ -113,6 +119,34 @@ def test_reorth_compiles_at_deepseek_serve_shape(one_chip, side):
     assert (s_pad, h_pad) == (PROMPT, DEEPSEEK_KVW)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 4 * batch * s_pad * h_pad
+
+
+#: the cells' bf16 re-orth launches at f = 8, (batch, prompt rows, kv
+#: width): granite-3-2b's 40 layers x 4 prompts of 512 and x 1 of 2048,
+#: deepseek-7b's 15 x 4 of 512, mellum2-12b's 7 factorized layers x 4 of
+#: 3072, and a 576-row prompt, whose left-step A block is 72 bf16 rows (a
+#: whole number of 8-row tiles, not of 16-row bf16 ones)
+BF16_LAUNCHES = {"granite-4x512": (160, 512, KVW),
+                 "granite-1x2048": (40, 2048, KVW),
+                 "deepseek-4x512": (60, 512, DEEPSEEK_KVW),
+                 "mellum-4x3072": (28, 3072, KVW),
+                 "granite-1x576": (40, 576, KVW)}
+
+
+@pytest.mark.parametrize("launch", sorted(BF16_LAUNCHES))
+@pytest.mark.parametrize("side", ["right", "left"])
+def test_reorth_compiles_bf16_at_cell_launches(one_chip, side, launch):
+    """The K/V prefill wrote, streamed as bf16: the kernel's one-pass split
+    product compiles at every launch shape of the cells, and the launch
+    reads A at 2 bytes an element (no f32 copy among its arguments)."""
+    batch, s, kvw = BF16_LAUNCHES[launch]
+    compiled, (s_pad, h_pad) = _compile_reorth(one_chip, side, batch, s, 8,
+                                               kvw, jnp.bfloat16)
+    text = compiled.as_text()
+    assert _REORTH_A.findall(text) == ["bf16"]
+    a_bytes = 2 * batch * s_pad * h_pad          # an f32 A alone is twice
+    mem = compiled.memory_analysis()
+    assert a_bytes <= mem.argument_size_in_bytes < 2 * a_bytes
 
 
 # -- mellum2-12b (16 of 64 experts held): the expert layer and decode ------
@@ -329,6 +363,9 @@ def test_dense_programs_compile_at_cell_shapes(one_chip, cell, program):
             p, cfg, t, RANK, tail=128, engine=engine)).lower(
             params, toks).compile()
         assert "tpu_custom_call" in compiled.as_text()
+        # the re-orth kernel streams the bf16 K/V prefill wrote
+        a_dtypes = _REORTH_A.findall(compiled.as_text())
+        assert a_dtypes and set(a_dtypes) == {"bf16"}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     got = _weight_copies(compiled.as_text(), _trailing_dims(params["layers"]))
